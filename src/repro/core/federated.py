@@ -465,9 +465,8 @@ class RoundDispatch:
     call is split into explicit ``trace_lower`` and ``compile`` spans via
     AOT (``jit.lower(...).compile()``), the compiled program's roofline
     ledger (``roofline/hlo_walk.py`` over the lowered HLO, plus XLA's own
-    cost analysis through the version-compat shim) is emitted once, and
-    the cached executable serves every subsequent round under a blocking
-    ``execute`` span.  The AOT path compiles the SAME lowering the jit
+    cost analysis) is emitted once, and the cached executable serves
+    every subsequent round under a blocking ``execute`` span.  The AOT path compiles the SAME lowering the jit
     wrapper would, so round results are bit-identical either way
     (test-enforced by the no-op-sink parity test).
     """
@@ -483,12 +482,9 @@ class RoundDispatch:
         values = {"flops": counters["flops"],
                   "hbm_bytes": counters["hbm_bytes"],
                   "collective_bytes": counters["total_collective_bytes"]}
-        try:
-            ca = hlo_walk.xla_cost_analysis(self.compiled)
-            if ca and "flops" in ca:
-                values["xla_flops"] = float(ca["flops"])
-        except Exception:
-            pass  # cost_analysis is advisory; some backends refuse it
+        ca = hlo_walk.xla_cost_analysis(self.compiled)
+        if "flops" in ca:
+            values["xla_flops"] = float(ca["flops"])
         self.obs.ledger("roofline", values)
 
     def __call__(self, *args):

@@ -29,23 +29,21 @@ Four variants:
   keeps the 128-lane axis intact ((Z, block_n) -> (Z, groups, 128-mult)).
 * ``masked_scatter_acc_pallas`` — the top-k sparse-upload fold (wire v2):
   each client ships ``k`` compacted values (+ scale sidecar over the
-  compacted payload) and their int32 flat positions; the kernel
-  dequantizes the compacted payload tile-locally and scatters it into
-  the accumulator block by block.  TPU has no dynamic lane scatter, so
-  the scatter is a one-hot contraction: per grid block the kept indices
-  are compared against the block's position range
-  (``broadcasted_iota``) and the values matmul through the resulting
-  one-hot — the (k_tile, block_n) one-hot lives only in VMEM, and the
-  dense ``(Z, n_flat)`` f32 cohort copy never materializes anywhere.
-  The k axis is chunked at ``k_tile`` to bound the one-hot's VMEM
-  footprint (512 x 2048 f32 = 4 MiB).
+  compacted payload) and their int32 flat positions.  TPU has no dynamic
+  lane scatter, so the scatter is a one-hot contraction on a 2-D grid of
+  (N blocks, k tiles): each step compares one ``k_tile`` slice of the
+  indices against its block's positions (``broadcasted_iota``) and
+  contracts the weighted values with the resulting one-hot — the
+  (block_n, k_tile) one-hot lives only in VMEM, and the dense
+  ``(Z, n_flat)`` f32 cohort copy never materializes anywhere.
 
-Neither wrapper is ``jax.jit``-ed: both always run inside the already
-jitted round (or a jitted test harness), where an extra jit would only add
+No wrapper is ``jax.jit``-ed: each always runs inside the already jitted
+round (or a jitted test harness), where an extra jit would only add
 eager-dispatch overhead and a second compilation cache.
 
 VMEM budget: Z=32, block_n=2048, bf16 -> 128 KiB per input tile plus the
-mask/acc/out tiles; well under the ~16 MiB/core VMEM on v5e.
+mask/acc/out tiles; well under the 16 MiB of VMEM a kernel may use by
+default on v5e (Mosaic's scoped limit, Pallas TPU docs).
 """
 
 from __future__ import annotations
@@ -190,6 +188,11 @@ def masked_agg_acc_deq_pallas(acc: jax.Array, q: jax.Array,
     np_ = q.shape[1]
     grid = (np_ // block_n,)
     block_g = block_n // quant_block
+    # one (Z, block_g) scale tile per grid step as the two minor dims of a
+    # (blocks, Z, block_g) array: both equal the full dims, which Mosaic's
+    # (8, 128) block rule accepts for any block_g (a (Z, block_g) window of
+    # the (Z, groups) array would need block_g % 128 == 0)
+    scales = scales.reshape(z, grid[0], block_g).transpose(1, 0, 2)
 
     out = pl.pallas_call(
         _make_agg_acc_deq_kernel(quant_block),
@@ -197,7 +200,7 @@ def masked_agg_acc_deq_pallas(acc: jax.Array, q: jax.Array,
         in_specs=[
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
             pl.BlockSpec((z, block_n), lambda i: (0, i)),
-            pl.BlockSpec((z, block_g), lambda i: (0, i)),
+            pl.BlockSpec((None, z, block_g), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
             pl.BlockSpec((z, 1), lambda i: (0, 0)),
             pl.BlockSpec((z, 1), lambda i: (0, 0)),
@@ -210,39 +213,51 @@ def masked_agg_acc_deq_pallas(acc: jax.Array, q: jax.Array,
     return out[0, :n]
 
 
-# one-hot scatter contraction tile along the compacted-k axis: bounds the
-# (k_tile, block_n) one-hot to 512 x 2048 f32 = 4 MiB of VMEM
+# compacted-entry tile of the scatter fold's grid: bounds the per-step
+# (block_n, k_tile) one-hot to 2048 x 512 bf16 = 2 MiB of VMEM
 _SCATTER_K_TILE = 512
 
 
-def _make_scatter_acc_kernel(quant_block: int, block_n: int, k_tile: int):
-    def kernel(acc_ref, v_ref, s_ref, idx_ref, mask_ref, wm_ref, wr_ref,
-               out_ref):
-        i = pl.program_id(0)
-        z, k = v_ref.shape
-        g = v_ref[...].astype(jnp.float32).reshape(z, k // quant_block,
-                                                   quant_block)
-        v = (g * s_ref[...][..., None]).reshape(z, k)   # fused dequant
-        wm = wm_ref[...].astype(jnp.float32)            # (Z, 1)
-        wr = wr_ref[...].astype(jnp.float32)            # (Z, 1)
-        # NaN-device gating BEFORE the contraction: a poisoned row would
-        # spread NaN over the whole block through the matmul's 0-terms
-        v = jnp.where((wm > 0) | (wr > 0), v, 0.0)
-        rel = idx_ref[...] - i * block_n                # (Z, k) int32
-        mask = mask_ref[...]                            # (1, block_n)
-        total = jnp.zeros((1, block_n), jnp.float32)
-        for row in range(z):
-            w_l = jnp.where(mask, wm[row, 0], wr[row, 0])   # (1, block_n)
-            scat = jnp.zeros((block_n,), jnp.float32)
-            for j0 in range(0, k, k_tile):
-                j1 = min(j0 + k_tile, k)
-                cols = jax.lax.broadcasted_iota(jnp.int32,
-                                                (j1 - j0, block_n), 1)
-                onehot = (rel[row, j0:j1, None] == cols).astype(jnp.float32)
-                scat = scat + v[row, j0:j1] @ onehot
-            total = total + jnp.where(w_l > 0, scat[None, :], 0.0) * w_l
-        out_ref[...] = acc_ref[...] + total
-    return kernel
+def _bf16_top(x: jax.Array) -> jax.Array:
+    """f32 x with the low 16 bits cleared: its sign, exponent and leading
+    8 significand bits, a value bf16 holds exactly."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
+
+
+def _bf16_split3(x: jax.Array) -> jax.Array:
+    """f32 (R, K) -> bf16 (3R, K) rows [hi; mid; lo] with hi + mid + lo ==
+    x exactly (3 x 8 significand bits cover f32's 24): the one-hot
+    contraction then runs as one bf16 MXU pass and still selects every
+    value bit for bit.
+
+    The parts are cut by bit mask, not by an f32 -> bf16 -> f32 round
+    trip: the TPU compiler may keep such a round trip in f32 (excess
+    precision), which leaves hi inexact and mid and lo zero."""
+    hi = _bf16_top(x)
+    r = x - hi
+    mid = _bf16_top(r)
+    return jnp.concatenate([hi, mid, r - mid], axis=0).astype(jnp.bfloat16)
+
+
+def _scatter_acc_kernel(acc_ref, lhs_ref, idx_ref, mask_ref, out_ref):
+    i, t = pl.program_id(0), pl.program_id(1)
+    block_n = out_ref.shape[1]
+
+    @pl.when(t == 0)
+    def _():
+        out_ref[...] = acc_ref[...]
+
+    rel = idx_ref[...] - i * block_n                      # (1, k_tile)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (block_n, rel.shape[1]), 0)
+    onehot = (rel == pos).astype(jnp.bfloat16)            # (block_n, k_tile)
+    part = jax.lax.dot_general(                           # (8, block_n)
+        lhs_ref[...], onehot, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    in_m = (part[0:1] + part[1:2]) + part[2:3]            # w_m-weighted
+    out_m = (part[3:4] + part[4:5]) + part[5:6]           # w_rest-weighted
+    out_ref[...] += jnp.where(mask_ref[...], in_m, out_m)
 
 
 def masked_scatter_acc_pallas(acc: jax.Array, values: jax.Array,
@@ -256,15 +271,21 @@ def masked_scatter_acc_pallas(acc: jax.Array, values: jax.Array,
     (Z, k/quant_block) at flat positions indices (Z, k) int32.
 
     ``acc`` is aliased to the output (in-place update).  ``values`` may
-    be int8/bf16/f32; ``scales=None`` means no sidecar (a ones sidecar is
-    synthesized so one kernel body serves every wire dtype).  ``k`` must
-    be a ``quant_block`` multiple (``comm.topk_count`` rounds up to a
-    lane multiple, which any valid ``quant_block`` divides).  Per-row
-    indices must be distinct (``top_k`` guarantees it) and inside
-    ``[0, N)``; the weight at each target position is selected by the
-    mask there (w_m inside M, w_rest outside), zero weights gate the
-    value, and a row with both weights zero (NaN/padding device) is
-    zeroed before the contraction.
+    be int8/bf16/f32; ``scales=None`` means no sidecar.  ``k`` must be a
+    ``quant_block`` multiple (``comm.topk_count`` rounds up to a lane
+    multiple, which any valid ``quant_block`` divides).  Per-row indices
+    must be distinct (``top_k`` guarantees it) and inside ``[0, N)``;
+    the weight at each target position is selected by the mask there
+    (w_m inside M, w_rest outside), and zero weights gate the value
+    before any multiply (NaN/padding devices).
+
+    The compacted payload is dequantized and weighted once, outside the
+    grid, into two f32 streams (w_m- and w_rest-weighted, each gated by
+    its own weight) over all ``Z * k`` entries, split into exact bf16
+    parts.  The grid runs over (N blocks, k tiles): each step contracts
+    one ``k_tile`` slice of the streams with the one-hot of its indices
+    against the block's positions and adds the mask-selected sum into the
+    resident accumulator block.  Work is O(Z * k * N).
     """
     if acc.dtype != jnp.float32:
         raise ValueError(f"accumulator must be f32, got {acc.dtype}")
@@ -274,33 +295,41 @@ def masked_scatter_acc_pallas(acc: jax.Array, values: jax.Array,
                          f"quant_block={quant_block}")
     if indices.shape != (z, k):
         raise ValueError(f"indices shape {indices.shape} != {(z, k)}")
-    if scales is None:
-        scales = jnp.ones((z, k // quant_block), jnp.float32)
+    v = values.astype(jnp.float32)
+    if scales is not None:
+        v = v * jnp.repeat(scales, quant_block, axis=1,
+                           total_repeat_length=k)
+    lhs = jnp.concatenate([                 # (8, Z*k): w_m, w_rest, 0 rows
+        _bf16_split3((jnp.where(w[:, None] > 0, v, 0.0)
+                      * w[:, None]).reshape(1, z * k))
+        for w in (w_m.astype(jnp.float32), w_rest.astype(jnp.float32))]
+        + [jnp.zeros((2, z * k), jnp.bfloat16)])
+    idx = indices.astype(jnp.int32).reshape(1, z * k)
+    k_tile = min(_SCATTER_K_TILE, -(-z * k // 128) * 128)
+    k_pad = (-z * k) % k_tile
+    if k_pad:                           # index -1 matches no position
+        lhs = jnp.pad(lhs, ((0, 0), (0, k_pad)))
+        idx = jnp.pad(idx, ((0, 0), (0, k_pad)), constant_values=-1)
     n = acc.shape[0]
     pad = (-n) % block_n
     if pad:
         acc = jnp.pad(acc, (0, pad))
         mask = jnp.pad(mask, (0, pad))
     np_ = acc.shape[0]
-    grid = (np_ // block_n,)
+    grid = (np_ // block_n, idx.shape[1] // k_tile)
 
     out = pl.pallas_call(
-        _make_scatter_acc_kernel(quant_block, block_n,
-                                 min(k, _SCATTER_K_TILE)),
+        _scatter_acc_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_n), lambda i: (0, i)),
-            pl.BlockSpec((z, k), lambda i: (0, 0)),
-            pl.BlockSpec((z, k // quant_block), lambda i: (0, 0)),
-            pl.BlockSpec((z, k), lambda i: (0, 0)),
-            pl.BlockSpec((1, block_n), lambda i: (0, i)),
-            pl.BlockSpec((z, 1), lambda i: (0, 0)),
-            pl.BlockSpec((z, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, block_n), lambda i, t: (0, i)),
+            pl.BlockSpec((8, k_tile), lambda i, t: (0, t)),
+            pl.BlockSpec((1, k_tile), lambda i, t: (0, t)),
+            pl.BlockSpec((1, block_n), lambda i, t: (0, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((1, block_n), lambda i, t: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, np_), jnp.float32),
         input_output_aliases={0: 0},
         interpret=interpret,
-    )(acc[None, :], values, scales, indices.astype(jnp.int32),
-      mask[None, :], w_m[:, None], w_rest[:, None])
+    )(acc[None, :], lhs, idx, mask[None, :])
     return out[0, :n]

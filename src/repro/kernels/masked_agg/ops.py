@@ -13,16 +13,14 @@ the wire payload + per-group scales directly (``masked_agg_acc_deq_ref``
 is its CPU/oracle form).  ``masked_agg_tree`` below keeps the PR 2
 per-leaf path (one launch per leaf) as the parity engine.
 
-Backend selection (``use_pallas``): the Pallas kernel targets TPU; on CPU
-(this container) the XLA reference path runs instead — set
+Backend selection (``use_pallas``): the Pallas kernels run on TPU and
+nowhere else; on any other backend the XLA reference path runs — set
 ``force_pallas_interpret=True`` to exercise the kernel body in interpret
-mode (tests do), or ``REPRO_MASKED_AGG=ref|pallas`` to override the
-automatic choice.
+mode (tests do).
 """
 
 from __future__ import annotations
 
-import os
 from typing import Any
 
 import jax
@@ -42,9 +40,6 @@ Tree = Any
 
 def use_pallas() -> bool:
     """True when the Pallas kernel (not the XLA reference) should run."""
-    override = os.environ.get("REPRO_MASKED_AGG", "")
-    if override in ("ref", "pallas"):
-        return override == "pallas"
     return jax.default_backend() == "tpu"
 
 

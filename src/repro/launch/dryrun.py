@@ -34,7 +34,7 @@ from repro.core.adapters import LMAdapter
 from repro.launch import sharding, steps
 from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as tfm
-from repro.roofline import analysis
+from repro.roofline import analysis, hw
 
 
 def _abstract_params(cfg: ModelConfig):
@@ -115,7 +115,8 @@ def lower_one(arch: str, shape: InputShape, *, multi_pod: bool,
             cache_abs, sharding.cache_specs(cache_abs, cfg, mesh), mesh)
     rec = analysis.make_record(
         arch=cfg.name, shape=shape, mesh_name="2x16x16" if multi_pod
-        else "16x16", chips=chips, cost=cost, mem=mem, hlo_text=hlo, cfg=cfg,
+        else "16x16", chips=chips, device_kind=hw.TARGET_KIND, cost=cost,
+        mem=mem, hlo_text=hlo, cfg=cfg,
         longctx_variant=longctx, param_bytes_chip=p_bytes,
         cache_bytes_chip=c_bytes)
     d = rec.to_dict()

@@ -12,11 +12,8 @@ import jax
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax >= 0.5 wants explicit axis_types; 0.4.x has no AxisType at all
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kw = {} if axis_type is None else \
-        {"axis_types": (axis_type.Auto,) * len(axes)}
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

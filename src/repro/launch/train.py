@@ -20,6 +20,7 @@ import argparse
 import json
 import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,23 @@ from repro.core.federated import FederatedTrainer, rounds_to_target
 from repro.data import federated as fed_data
 from repro.data.synthetic import synthetic_cifar, synthetic_lm
 from repro.obs import telemetry as obslib
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache goes to ``.jax_cache/`` at
+    the root of this checkout: a fixed path, so every later run from the
+    same checkout finds what earlier runs compiled.  Call it before the
+    first compile; importing this module sets nothing.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def build_trainer(args, telemetry=None) -> tuple:
@@ -213,6 +231,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    use_compile_cache()
 
     # the driver's prints always route through a telemetry stdout sink
     # (line formats are bit-identical — the sink prints log events

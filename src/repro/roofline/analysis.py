@@ -7,10 +7,11 @@ Sources:
   reduce-scatter / all-to-all / collective-permute ops (an upper bound on
   per-chip bytes moved; documented in EXPERIMENTS.md).
 
-Terms (seconds, per step, per chip):
-    compute    = HLO_FLOPs / PEAK_FLOPS_BF16
-    memory     = HLO_bytes / HBM_BW
-    collective = collective_bytes / ICI_LINK_BW
+Terms (seconds, per step, per chip), against the peaks of the record's
+``device_kind`` (``roofline/hw.py``):
+    compute    = HLO_FLOPs / flops_bf16
+    memory     = HLO_bytes / hbm_bw
+    collective = collective_bytes / ici_link_bw
 """
 
 from __future__ import annotations
@@ -73,6 +74,7 @@ class RooflineRecord:
     shape: str
     mesh: str
     chips: int
+    device_kind: str               # the chip whose peaks the terms use
     flops_per_chip: float          # HLO, per device, per step
     bytes_per_chip: float
     coll_bytes_per_chip: float
@@ -87,18 +89,19 @@ class RooflineRecord:
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_chip / hw.PEAK_FLOPS_BF16
+        return self.flops_per_chip / hw.peaks(self.device_kind).flops_bf16
 
     @property
     def t_memory(self) -> float:
         """Analytic HBM traffic (weights + activations + caches) / HBM bw.
         The HLO byte proxy (``bytes_per_chip``) is kept as a diagnostic but
         over-materializes on the CPU backend (weak fusion)."""
-        return self.hbm_analytic_per_chip / hw.HBM_BW
+        return self.hbm_analytic_per_chip / hw.peaks(self.device_kind).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes_per_chip / hw.ICI_LINK_BW
+        return (self.coll_bytes_per_chip
+                / hw.peaks(self.device_kind).ici_link_bw)
 
     @property
     def bottleneck(self) -> str:
@@ -172,7 +175,7 @@ def model_flops(cfg, shape) -> float:
 
 
 def make_record(*, arch: str, shape, mesh_name: str, chips: int,
-                cost: Dict, mem, hlo_text: str, cfg,
+                device_kind: str, cost: Dict, mem, hlo_text: str, cfg,
                 longctx_variant: bool = False,
                 param_bytes_chip: float = 0.0,
                 cache_bytes_chip: float = 0.0) -> RooflineRecord:
@@ -186,6 +189,7 @@ def make_record(*, arch: str, shape, mesh_name: str, chips: int,
         cache_bytes_per_chip=cache_bytes_chip,
         hbm_analytic_per_chip=hbm,
         arch=arch, shape=shape.name, mesh=mesh_name, chips=chips,
+        device_kind=device_kind,
         flops_per_chip=float(walk["flops"]),
         bytes_per_chip=float(walk["hbm_bytes"]),
         coll_bytes_per_chip=float(walk["total_collective_bytes"]),

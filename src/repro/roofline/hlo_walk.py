@@ -350,20 +350,9 @@ def analyze(text: str) -> Dict:
 
 
 def xla_cost_analysis(compiled) -> Dict:
-    """XLA's own cost analysis of a compiled executable, normalized.
-
-    ``compiled.cost_analysis()`` returns a per-device *list* of dicts on
-    some jax versions and a plain dict on others; this shim always
-    returns the first device's dict (empty if the backend refuses the
-    query), so consumers — the telemetry roofline ledger, tests on both
-    CI jax matrix legs — never branch on the jax version.  Remember the
-    number it reports is loop-UNAWARE (while bodies counted once); use
-    :func:`analyze` for trip-count-corrected costs.
+    """XLA's own cost analysis of a compiled executable, as a plain dict
+    (``compiled.cost_analysis()`` returns one dict for the executable).
+    The number it reports is loop-UNAWARE (while bodies counted once);
+    use :func:`analyze` for trip-count-corrected costs.
     """
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    return dict(compiled.cost_analysis())

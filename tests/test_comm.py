@@ -507,6 +507,40 @@ def test_scatter_fold_kernel_matches_ref(with_scales):
                                rtol=1e-6, atol=1e-6)
 
 
+def test_scatter_bf16_split_is_exact():
+    """The scatter kernel contracts each f32 term as three bf16 parts;
+    they must sum back to the term bit for bit, at any sign and at any
+    magnitude whose parts stay normal (subnormals flush to zero), or the
+    one-hot selection rounds the folded values."""
+    from repro.kernels.masked_agg import kernel as K
+    rng = np.random.default_rng(5)
+    x = np.concatenate([rng.normal(size=4096) * 10.0 ** rng.integers(
+        -20, 20, size=4096), [0.0, -0.0, 1.0 + 2 ** -23, -3.4e38]])
+    x = jnp.asarray(x.astype(np.float32))[None, :]
+    parts = K._bf16_split3(x)
+    assert parts.dtype == jnp.bfloat16 and parts.shape == (3, x.shape[1])
+    p = np.asarray(parts.astype(jnp.float32), np.float64)
+    np.testing.assert_array_equal(p[0] + p[1] + p[2],
+                                  np.asarray(x[0], np.float64))
+
+
+def test_scatter_fold_kernel_tiles_k_and_n():
+    """Several k tiles (Z*k = 1920 entries padded to 4 x 512), positions
+    colliding across clients, and an N that is no block multiple: the
+    grid's k axis must add every tile into the resident block once."""
+    from repro.kernels.masked_agg import ops as agg_ops
+    acc, vals, scales, idx, mask, w_m, w_r = _scatter_case(
+        14, n=1000, z=5, k=384, quant_block=128)
+    w_r = w_r.at[2].set(0.0)            # a simple client: M only
+    ref = agg_ops.masked_scatter_acc_ref(acc, vals, scales, idx, mask,
+                                         w_m, w_r, quant_block=128)
+    ker = agg_ops.masked_scatter_acc_pallas(acc, vals, scales, idx, mask,
+                                            w_m, w_r, quant_block=128,
+                                            block_n=256, interpret=True)
+    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
+                               rtol=1e-6, atol=1e-6)
+
+
 def test_scatter_fold_gates_nan_rows():
     """A NaN row at weight 0 (both masks) must leave the accumulator
     untouched — the kernel gates BEFORE the multiply."""

@@ -5,6 +5,7 @@ command line (or be explicitly exempted below) so the config and the
 driver cannot drift apart silently."""
 
 import dataclasses
+from pathlib import Path
 
 import pytest
 
@@ -223,3 +224,35 @@ def test_cli_flags_construct_a_valid_config():
         variance_reduction=args.variance_reduction,
         state_store_backend=args.state_store_backend)
     fed.validate()
+
+
+# ---------------------------------------------------------------------------
+# compile cache placement (launch/train.py use_compile_cache)
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    """Without JAX_COMPILATION_CACHE_DIR the cache goes to a fixed,
+    gitignored directory at the root of the checkout."""
+    import jax
+    from repro.launch import train
+    repo = Path(__file__).resolve().parents[1]
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = train.use_compile_cache()
+        assert path == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().splitlines()
+
+
+def test_compile_cache_left_to_the_environment(monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and the
+    helper sets nothing."""
+    import jax
+    from repro.launch import train
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert train.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before

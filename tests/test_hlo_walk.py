@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.roofline import hlo_walk
+from repro.roofline import hlo_walk, hw
 
 
 def _compile_text(fn, *args):
@@ -38,8 +38,7 @@ def test_scan_multiplies_by_trip_count():
     # allow small over/under from loop bookkeeping fusions
     assert abs(got - expect) / expect < 0.05, (got, expect)
     # sanity: XLA's own cost analysis misses the trip count (the reason
-    # this walker exists); the shim normalizes the per-device-list vs
-    # plain-dict return across jax versions
+    # this walker exists)
     ca = hlo_walk.xla_cost_analysis(jax.jit(f).lower(a, w).compile())
     assert ca["flops"] < 0.3 * expect
 
@@ -88,3 +87,13 @@ def test_hbm_bytes_scale_with_tensor_size():
     got = hlo_walk.analyze(txt)["hbm_bytes"]
     # one read + one write of 4MB, give or take bookkeeping
     assert 0.5 * 8e6 < got < 4 * 8e6, got
+
+
+def test_peaks_are_keyed_by_device_kind():
+    """v5e reports itself as "TPU v5 lite"; its published peaks are in the
+    table, and a kind with no entry raises instead of borrowing them."""
+    v5e = hw.peaks("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bw) == (197e12, 819e9)
+    assert hw.peaks(hw.TARGET_KIND) is v5e
+    with pytest.raises(KeyError, match="no published peaks"):
+        hw.peaks("cpu")

@@ -110,9 +110,15 @@ def test_option_ii_oracle_single_client_populations():
                      (x_flat - flatten.pack(layout, y_s)) * inv, 0.0)
     dc_c = (x_flat - flatten.pack(layout, y_c)) * inv
 
+    # y is recomputed outside the round jit, where XLA fuses the local
+    # SGD steps differently: y agrees to a few f32 ulps of the parameter
+    # magnitude, and dc scales that by 1/(K*lr).  A wrong key derivation
+    # moves dc by O(|dc|), some 1e5 times this tolerance.
     rows = tr.cv_store.to_array()
-    assert float(jnp.max(jnp.abs(rows[0] - dc_s))) == 0.0
-    assert float(jnp.max(jnp.abs(rows[1] - dc_c))) == 0.0
+    tol = (4 * float(jnp.finfo(jnp.float32).eps)
+           * float(jnp.max(jnp.abs(x_flat))) * inv)
+    assert float(jnp.max(jnp.abs(rows[0] - dc_s))) <= tol
+    assert float(jnp.max(jnp.abs(rows[1] - dc_c))) <= tol
     # server update: c += (1/N) * sum_i dc_i (raw sum, never normalized
     # by cohort weights — dc_s is zero outside M so the masked fold's
     # w_out gating changes nothing elementwise)
