@@ -13,7 +13,8 @@ four variants of the SAME training run:
   singleton's early-return path): what every un-instrumented caller
   pays.  **Gate: < 2% over raw.**
 * ``on_null``  — telemetry enabled with a ``NullSink``: full event
-  assembly (spans, phases, counters, ledgers) without I/O.
+  assembly (spans with their profiler annotations and compile counts,
+  counters, ledgers) without I/O.
   **Gate: < 5% over raw.**
 * ``on_jsonl`` — telemetry enabled with a ``JsonlSink`` to a temp file:
   the run-log configuration CI uploads.  **Gate: < 5% over raw.**  The
@@ -82,7 +83,8 @@ def raw_round(tr: FederatedTrainer) -> Dict[str, float]:
     data_s = tr._gather(simple_ids)
     data_c = tr._gather(complex_ids)
     key = jax.random.PRNGKey(tr.fed.seed * 100003 + tr.server.round)
-    new_complex, new_simple_host, metrics = tr._round_fn(
+    # no SCAFFOLD and no error feedback here: their outputs are None
+    new_complex, new_simple_host, metrics, _, _ = tr._round_fn(
         tr.server.complex, tr.server.simple_host, data_s, data_c, key,
         tr._flat_mask_arg())
     tr.server = ServerState(complex=new_complex,

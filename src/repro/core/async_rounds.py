@@ -73,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import aggregate, comm, federated, flatten
+from repro.obs.scopes import stage
 
 STALENESS_SCHEMES = ("poly", "none")
 
@@ -273,9 +274,10 @@ class AsyncRoundEngine:
             # model the client really trained from.
             agg_init, agg_fold, agg_finalize = make_agg(flat_mask)
             rs, rc = jax.random.split(rng)
-            bcasts_c = decode_versions(versions)
-            bcasts_s = (decode_versions(versions_host)
-                        if algo == "decouple" else bcasts_c)
+            with stage("wire"):
+                bcasts_c = decode_versions(versions)
+                bcasts_s = (decode_versions(versions_host)
+                            if algo == "decouple" else bcasts_c)
             sc_s = sc_c = None
             if scaffold_on:
                 # the option-II delta's x is whatever broadcast the chunk
@@ -296,7 +298,8 @@ class AsyncRoundEngine:
             if delta_mode:
                 up_s = federated.WireUploadCtx(wire, layout, k_top_s, ef_s)
                 up_c = federated.WireUploadCtx(wire, layout, k_top_c, ef_c)
-            state = agg_init(template)
+            with stage("fold"):
+                state = agg_init(template)
             (state, loss_s, valid_s, rows_s,
              efrows_s) = federated.stream_population(
                 state, version_select(bcasts_s), train_simple, data_s, rs,
@@ -314,20 +317,23 @@ class AsyncRoundEngine:
                 version_idx=idx_c, staleness_w=w_c, real_mask=real_c,
                 scaffold=sc_c, upload=up_c)
             cv_out = None
-            if scaffold_on:
-                cv_out = (cv_global + state.cv_acc / float(fed.n_devices),
-                          rows_s, rows_c)
-            ef_out = (efrows_s, efrows_c) if ef_on else None
-            new_complex, new_host = agg_finalize(state, template=template)
-            # publish: roll the new round model into the version stack
-            new_versions = jnp.concatenate(
-                [flatten.pack(layout, new_complex)[None], versions[:-1]],
-                axis=0)
             new_versions_host = None
-            if algo == "decouple":
-                new_versions_host = jnp.concatenate(
-                    [flatten.pack(layout, new_host)[None],
-                     versions_host[:-1]], axis=0)
+            with stage("finalize"):
+                if scaffold_on:
+                    cv_out = (cv_global
+                              + state.cv_acc / float(fed.n_devices),
+                              rows_s, rows_c)
+                new_complex, new_host = agg_finalize(state,
+                                                     template=template)
+                # publish: roll the new round model into the version stack
+                new_versions = jnp.concatenate(
+                    [flatten.pack(layout, new_complex)[None],
+                     versions[:-1]], axis=0)
+                if algo == "decouple":
+                    new_versions_host = jnp.concatenate(
+                        [flatten.pack(layout, new_host)[None],
+                         versions_host[:-1]], axis=0)
+            ef_out = (efrows_s, efrows_c) if ef_on else None
             metrics = {"loss_simple": loss_s, "loss_complex": loss_c,
                        "n_valid": valid_s + valid_c}
             return (new_complex, new_host, new_versions,
@@ -450,12 +456,6 @@ class AsyncRoundEngine:
             tr.total_bytes += down + up
             metrics = {k: float(v) for k, v in metrics.items()}
             if obs.enabled:
-                federated.emit_round_phases(obs, populations=[
-                    ("simple", tr.k_simple, self.chunk_s,
-                     self.n_chunks_s, s_s),
-                    ("complex", tr.k_complex, self.chunk_c,
-                     self.n_chunks_c, s_c)],
-                    bytes_down=down, wire=tr.fed.comm_dtype)
                 self._emit_async_health(s_s, s_c)
                 tr._emit_round_health(
                     metrics, down=down, up=up,

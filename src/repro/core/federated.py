@@ -102,6 +102,7 @@ from repro.configs.base import FedConfig
 from repro.core import aggregate, client_state, comm, flatten, masking
 from repro.core import sampling, state_store
 from repro.obs import telemetry as obslib
+from repro.obs.scopes import stage
 from repro.optim.sgd import sgd_update
 
 Tree = Any
@@ -345,6 +346,43 @@ def stream_population(state, get_src, train_fn, data, key, agg_fold, *,
             return v
         return jnp.where(scaffold.pop_mask[None], v, 0.0)
 
+    def _encode_upload(x_flat, y_flat, ef_i, keys_i, valid):
+        """Wire v2: encode the chunk's deltas ``y - x`` (plus each
+        client's EF residual ``ef_i``) -> the fold's
+        :class:`aggregate.SparseChunk` and the new residual rows."""
+        spec_w = upload.spec
+        d = (y_flat.astype(jnp.float32)
+             - x_flat.astype(jnp.float32)[None])
+        d_in = d + ef_i if ef_on else d
+        enc_keys = jax.vmap(
+            lambda kk: jax.random.fold_in(kk, _WIRE_KEY_TAG))(keys_i)
+        if spec_w.is_sparse:
+            buf = jax.vmap(lambda v, kk: comm.sparse_encode(
+                spec_w, v, upload.k_top, key=kk))(d_in, enc_keys)
+            sp = aggregate.SparseChunk(x_flat.astype(jnp.float32),
+                                       buf.payload, buf.scales,
+                                       buf.indices)
+            if ef_on:
+                dec = jax.vmap(lambda b: comm.sparse_decode_values(
+                    spec_w, b))(buf)
+                r_new = jax.vmap(
+                    lambda v, ix, dv: v.at[ix].add(-dv))(
+                        d_in, buf.indices, dec)
+        else:
+            buf = jax.vmap(lambda v, kk: comm.encode(
+                spec_w, v, key=kk))(d_in, enc_keys)
+            sp = aggregate.SparseChunk(x_flat.astype(jnp.float32),
+                                       buf.payload, buf.scales, None)
+            if ef_on:
+                r_new = d_in - jax.vmap(
+                    lambda b: comm.decode(spec_w, b))(buf)
+        ef_out = None
+        if ef_on:
+            # r' = (d + r) - decode(encode(d + r)); NaN clients keep
+            # their residual row, like cv rows
+            ef_out = jnp.where(valid[:, None], r_new, ef_i)
+        return sp, ef_out
+
     def fold_chunk(carry, xs):
         state, loss_sum, valid_sum = carry
         if is_async:
@@ -353,75 +391,56 @@ def stream_population(state, get_src, train_fn, data, key, agg_fold, *,
             data_i, keys_i, real_i = xs[:3]
             idx_i = None
         src = get_src(idx_i)
-        if scaffold is None:
-            trained, losses = jax.vmap(train_fn)(
-                tile(src), data_i, keys_i)
-        else:
-            cv_i = xs[cv_pos]
-            corr = _mask_pop(scaffold.c_global[None] - cv_i)
-            trained, losses = jax.vmap(train_fn)(
-                tile(src), data_i, keys_i, corr)
-        valid = real_i
-        if skip_nan:
-            valid = valid & jax.vmap(masking.tree_isfinite)(trained)
-        fold_valid = (valid.astype(jnp.float32) * w_i if is_async
-                      else valid)
+        with stage("local_sgd"):
+            if scaffold is None:
+                trained, losses = jax.vmap(train_fn)(
+                    tile(src), data_i, keys_i)
+            else:
+                cv_i = xs[cv_pos]
+                corr = _mask_pop(scaffold.c_global[None] - cv_i)
+                trained, losses = jax.vmap(train_fn)(
+                    tile(src), data_i, keys_i, corr)
+            valid = real_i
+            if skip_nan:
+                valid = valid & jax.vmap(masking.tree_isfinite)(trained)
+        with stage("fold"):
+            fold_valid = (valid.astype(jnp.float32) * w_i if is_async
+                          else valid)
         # x is the decoded broadcast this chunk trained on (async: its
         # selected stale version), y the trained result — shared by the
-        # SCAFFOLD delta and the wire-v2 delta encode
+        # SCAFFOLD delta and the wire-v2 delta encode, so the packing
+        # belongs to the wire when there is an upload to encode
         x_flat = y_flat = None
         if scaffold is not None or upload is not None:
             pack_layout = (scaffold.layout if scaffold is not None
                            else upload.layout)
-            x_flat = flatten.pack(pack_layout, src)
-            y_flat = flatten.pack_stacked(pack_layout, trained)
+            with stage("fold" if upload is None else "wire"):
+                x_flat = flatten.pack(pack_layout, src)
+                y_flat = flatten.pack_stacked(pack_layout, trained)
         rows_out = ef_out = None
         fold_kw = {}
         if scaffold is not None:
-            # option II: dc = (x - y)/(K*lr) - c on the trained slice
-            dc = _mask_pop((x_flat[None] - y_flat) * scaffold.inv_k_lr
-                           - scaffold.c_global[None])
-            fold_kw["cv_chunk"] = dc
-            # NaN clients fold at weight 0 (dc gated in the kernel) AND
-            # keep their previous row — a NaN row must never persist
-            rows_out = jnp.where(valid[:, None], cv_i + dc, cv_i)
+            with stage("fold"):
+                # option II: dc = (x - y)/(K*lr) - c on the trained slice
+                dc = _mask_pop((x_flat[None] - y_flat) * scaffold.inv_k_lr
+                               - scaffold.c_global[None])
+                fold_kw["cv_chunk"] = dc
+                # NaN clients fold at weight 0 (dc gated in the kernel)
+                # AND keep their previous row — a NaN row must never
+                # persist
+                rows_out = jnp.where(valid[:, None], cv_i + dc, cv_i)
         if upload is None:
-            state = agg_fold(state, trained, is_simple, fold_valid,
-                             **fold_kw)
+            with stage("fold"):
+                state = agg_fold(state, trained, is_simple, fold_valid,
+                                 **fold_kw)
         else:
-            spec_w = upload.spec
-            d = (y_flat.astype(jnp.float32)
-                 - x_flat.astype(jnp.float32)[None])
             ef_i = xs[ef_pos] if ef_on else None
-            d_in = d + ef_i if ef_on else d
-            enc_keys = jax.vmap(
-                lambda kk: jax.random.fold_in(kk, _WIRE_KEY_TAG))(keys_i)
-            if spec_w.is_sparse:
-                buf = jax.vmap(lambda v, kk: comm.sparse_encode(
-                    spec_w, v, upload.k_top, key=kk))(d_in, enc_keys)
-                sp = aggregate.SparseChunk(x_flat.astype(jnp.float32),
-                                           buf.payload, buf.scales,
-                                           buf.indices)
-                if ef_on:
-                    dec = jax.vmap(lambda b: comm.sparse_decode_values(
-                        spec_w, b))(buf)
-                    r_new = jax.vmap(
-                        lambda v, ix, dv: v.at[ix].add(-dv))(
-                            d_in, buf.indices, dec)
-            else:
-                buf = jax.vmap(lambda v, kk: comm.encode(
-                    spec_w, v, key=kk))(d_in, enc_keys)
-                sp = aggregate.SparseChunk(x_flat.astype(jnp.float32),
-                                           buf.payload, buf.scales, None)
-                if ef_on:
-                    r_new = d_in - jax.vmap(
-                        lambda b: comm.decode(spec_w, b))(buf)
-            if ef_on:
-                # r' = (d + r) - decode(encode(d + r)); NaN clients keep
-                # their residual row, like cv rows
-                ef_out = jnp.where(valid[:, None], r_new, ef_i)
-            state = agg_fold(state, None, is_simple, fold_valid,
-                             sparse_chunk=sp, **fold_kw)
+            with stage("wire"):
+                sp, ef_out = _encode_upload(x_flat, y_flat, ef_i, keys_i,
+                                            valid)
+            with stage("fold"):
+                state = agg_fold(state, None, is_simple, fold_valid,
+                                 sparse_chunk=sp, **fold_kw)
         loss_sum = loss_sum + jnp.sum(jnp.where(real_i, losses, 0.0))
         valid_sum = valid_sum + jnp.sum(valid)
         return (state, loss_sum, valid_sum), (rows_out, ef_out)
@@ -499,39 +518,6 @@ class RoundDispatch:
             self._emit_roofline()
         with obs.span("execute"):
             return jax.block_until_ready(self.compiled(*args))
-
-
-def emit_round_phases(obs: obslib.Telemetry, *, populations,
-                      bytes_down: float, wire: str) -> None:
-    """Emit one round's logical phase spans:
-    ``broadcast -> train-chunk[t] -> fold -> finalize``.
-
-    These are *point* spans (``dur_s=None``): the round is one fused jit,
-    so the phases are real program structure with real attributes but
-    their wall time lives in the enclosing ``execute`` span — see
-    ``obs/telemetry.py``.  ``populations`` is a sequence of
-    ``(name, k, chunk, n_chunks, staleness)`` where ``staleness`` is
-    ``None`` for the synchronous engine or the per-chunk staleness
-    schedule (in rounds) for the async engine; chunk indices ``t`` run
-    over the round's global fold stream (simple chunks first, then
-    complex — the scan order).
-    """
-    if not obs.enabled:
-        return
-    obs.point_span("broadcast", wire=wire, bytes_down=bytes_down)
-    t = 0
-    n_folds = 0
-    for name, k, chunk, n_chunks, staleness in populations:
-        for i in range(n_chunks):
-            attrs = {"population": name, "chunk_size": chunk,
-                     "clients": max(min(chunk, k - i * chunk), 0)}
-            if staleness is not None:
-                attrs["staleness"] = int(staleness[i])
-            obs.point_span(f"train-chunk[{t}]", **attrs)
-            t += 1
-        n_folds += n_chunks
-    obs.point_span("fold", n_folds=n_folds)
-    obs.point_span("finalize")
 
 
 # ---------------------------------------------------------------------------
@@ -903,11 +889,12 @@ class FederatedTrainer:
             # the server -> client broadcast crosses the wire: clients
             # train on the DECODED copy, so the round carries the real
             # quantization error (identity for the f32 wire)
-            bc_complex = comm.broadcast_roundtrip(wire, layout,
-                                                  complex_params)
-            src_simple = (comm.broadcast_roundtrip(wire, layout,
-                                                   simple_host)
-                          if algo == "decouple" else bc_complex)
+            with stage("wire"):
+                bc_complex = comm.broadcast_roundtrip(wire, layout,
+                                                      complex_params)
+                src_simple = (comm.broadcast_roundtrip(wire, layout,
+                                                       simple_host)
+                              if algo == "decouple" else bc_complex)
             sc_s = sc_c = None
             if scaffold_on:
                 # simple clients train (and correct) only the M slice:
@@ -927,7 +914,8 @@ class FederatedTrainer:
             if delta_mode:
                 up_s = WireUploadCtx(wire, layout, k_top_s, ef_s)
                 up_c = WireUploadCtx(wire, layout, k_top_c, ef_c)
-            state = agg_init(complex_params)
+            with stage("fold"):
+                state = agg_init(complex_params)
             state, loss_s, valid_s, rows_s, efrows_s = stream_population(
                 state, lambda _: src_simple, train_simple, data_s, rs,
                 agg_fold, k=self.k_simple, chunk=chunk_s,
@@ -941,17 +929,19 @@ class FederatedTrainer:
                 skip_nan=fed.skip_nan_devices, real_mask=real_c,
                 scaffold=sc_c, upload=up_c)
             cv_out = None
-            if scaffold_on:
-                # server control variate: c += (1/N) * sum_i dc_i — the
-                # RAW second accumulator (group weighting already rode
-                # w_in/w_out through the fold), over ALL N devices
-                # (non-participants contribute 0), per Karimireddy eq. 5
-                new_cv_global = (cv_global
-                                 + state.cv_acc / float(fed.n_devices))
-                cv_out = (new_cv_global, rows_s, rows_c)
+            with stage("finalize"):
+                if scaffold_on:
+                    # server control variate: c += (1/N) * sum_i dc_i —
+                    # the RAW second accumulator (group weighting already
+                    # rode w_in/w_out through the fold), over ALL N
+                    # devices (non-participants contribute 0), per
+                    # Karimireddy eq. 5
+                    new_cv_global = (cv_global
+                                     + state.cv_acc / float(fed.n_devices))
+                    cv_out = (new_cv_global, rows_s, rows_c)
+                new_complex, new_simple_host = agg_finalize(
+                    state, template=complex_params)
             ef_out = (efrows_s, efrows_c) if ef_on else None
-            new_complex, new_simple_host = agg_finalize(
-                state, template=complex_params)
             metrics = {"loss_simple": loss_s,
                        "loss_complex": loss_c,
                        "n_valid": valid_s + valid_c}
@@ -1108,11 +1098,6 @@ class FederatedTrainer:
             self.total_bytes_up += up
             metrics = {k: float(v) for k, v in metrics.items()}
             if obs.enabled:
-                (chunk_s, n_s), (chunk_c, n_c) = self._geometry()
-                emit_round_phases(obs, populations=[
-                    ("simple", self.k_simple, chunk_s, n_s, None),
-                    ("complex", self.k_complex, chunk_c, n_c, None)],
-                    bytes_down=down, wire=self.fed.comm_dtype)
                 self._emit_round_health(
                     metrics, down=down, up=up,
                     k_real=plan.n_real_simple + plan.n_real_complex)

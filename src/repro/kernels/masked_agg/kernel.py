@@ -87,6 +87,7 @@ def masked_agg_pallas(x: jax.Array, mask: jax.Array, w_m: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, np_), x.dtype),
+        name="masked_agg",
         interpret=interpret,
     )(x, mask[None, :], w_m[:, None], w_rest[:, None])
     return out[0, :n]
@@ -136,6 +137,7 @@ def masked_agg_acc_pallas(acc: jax.Array, x: jax.Array, mask: jax.Array,
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, np_), jnp.float32),
         input_output_aliases={0: 0},
+        name="masked_agg_acc",
         interpret=interpret,
     )(acc[None, :], x, mask[None, :], w_m[:, None], w_rest[:, None])
     return out[0, :n]
@@ -208,6 +210,7 @@ def masked_agg_acc_deq_pallas(acc: jax.Array, q: jax.Array,
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, np_), jnp.float32),
         input_output_aliases={0: 0},
+        name="masked_agg_acc_deq",
         interpret=interpret,
     )(acc[None, :], q, scales, mask[None, :], w_m[:, None], w_rest[:, None])
     return out[0, :n]
@@ -330,6 +333,7 @@ def masked_scatter_acc_pallas(acc: jax.Array, values: jax.Array,
         out_specs=pl.BlockSpec((1, block_n), lambda i, t: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, np_), jnp.float32),
         input_output_aliases={0: 0},
+        name="masked_scatter_acc",
         interpret=interpret,
     )(acc[None, :], lhs, idx, mask[None, :])
     return out[0, :n]

@@ -6,7 +6,9 @@ wrote — see :mod:`repro.obs.telemetry` for the schema.  Output sections:
 
 * **run** — the ``run_config`` ledger (algorithm, cohort geometry, wire).
 * **rounds** — count, median/total wall clock per phase from the timed
-  spans, and the first round's compile-vs-execute split.
+  spans, the first round's compile-vs-execute split, and the backend
+  compiles each ``round`` span counted (a round after the first that
+  compiles is a recompile).
 * **comm** — bytes/round (down, up) and cumulative totals from the
   ``comm_bytes`` ledgers, exactly the trainer's measured accounting.
 * **client health** — NaN-excluded device total, weight-0 padding slots,
@@ -70,6 +72,9 @@ def summarize(events: List[Dict[str, Any]],
                           if s.get("name") == "round"
                           and s.get("round") is not None})
     compile_s = sum(durs.get("compile", []))
+    compiles_by_round = {s["round"]: int(s["compiles"]) for s in spans
+                         if s.get("name") == "round"
+                         and s.get("compiles") is not None}
     trace_lower_s = sum(durs.get("trace_lower", []))
     execute_med = _median(durs.get("execute", []))
 
@@ -142,6 +147,9 @@ def summarize(events: List[Dict[str, Any]],
             "n_rounds": len(rounds_seen) or len(comm),
             "phase_wall": phase_wall,
             "compile_s": compile_s,
+            "compiles": sum(compiles_by_round.values()),
+            "compiling_rounds": {r: c for r, c in compiles_by_round.items()
+                                 if c},
             "trace_lower_s": trace_lower_s,
             "execute_median_s": execute_med,
         },
@@ -194,6 +202,9 @@ def render(summary: Dict[str, Any]) -> str:
     add(f"  rounds: {r['n_rounds']}")
     add(f"  compile (first round): {_fmt_s(r['compile_s'])} "
         f"(trace+lower {_fmt_s(r['trace_lower_s'])})")
+    add(f"  backend compiles: {r['compiles']}"
+        + "".join(f"  round {k}: {v}"
+                  for k, v in r["compiling_rounds"].items()))
     add(f"  execute median: {_fmt_s(r['execute_median_s'])}")
     for name, w in r["phase_wall"].items():
         add(f"  span {name}: n={w['n']} median={_fmt_s(w['median_s'])} "

@@ -7,19 +7,19 @@ the round loop, invisible to the async and quantization machinery.  This
 is the one instrumentation substrate everything reports through:
 
 * **Events** are plain JSON-ready dicts (no classes on the hot path, no
-  dependencies beyond the stdlib — this module never imports jax).  Four
+  dependencies beyond the stdlib — this module never loads jax).  Four
   kinds:
 
-  - ``span``    — one phase of a round, in a tree addressed by ``path``
-                  (e.g. ``round/execute/train-chunk[2]``).  ``dur_s`` is
-                  wall seconds for host-measured spans and ``None`` for
-                  *logical* spans: the round is ONE fused jit, so the
-                  phases inside it (broadcast → train-chunk[t] → fold →
-                  finalize) are real structure with real attributes
-                  (staleness, fold weight, wire dtype) but their wall
-                  time is only measurable at the host boundary — it is
-                  attributed to the enclosing ``execute`` span, never
-                  invented per phase.
+  - ``span``    — one timed host phase of a round, in a tree addressed
+                  by ``path`` (e.g. ``round/execute``); ``dur_s`` is its
+                  wall seconds.  The round's device work is ONE fused
+                  jit: its stages (local SGD, wire, fold, finalize) are
+                  not host spans but tags on the compiled program's ops
+                  (``obs/scopes.py``), timed from a device trace.  Once
+                  jax is loaded, an enabled span is also a profiler
+                  ``TraceAnnotation`` of the same name (so it lies on
+                  the device trace's clock) and carries ``compiles``,
+                  the XLA backend compiles that ran inside it.
   - ``counter`` — one named scalar (client-health: NaN-excluded devices,
                   weight-0 padding, version-cache hits/misses).
   - ``ledger``  — one named dict of related values (per-round comm
@@ -51,6 +51,7 @@ imports them.
 from __future__ import annotations
 
 import json
+import sys
 import time
 from typing import Any, Dict, IO, Iterable, List, Optional, Sequence
 
@@ -178,11 +179,23 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _jax_hooks():
+    """``(TraceAnnotation, compile_count)`` once jax is loaded, else
+    ``None``: a span never loads jax itself."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+    from repro.obs.scopes import compile_count
+    return TraceAnnotation, compile_count
+
+
 class _Span:
     """A timed phase: enters the telemetry's span stack (its name becomes
     a path segment for everything emitted inside) and emits one ``span``
-    event with measured ``dur_s`` on exit."""
-    __slots__ = ("_tel", "name", "attrs", "_t0")
+    event with measured ``dur_s`` on exit.  With jax loaded it also holds
+    a profiler annotation of its name open and counts the backend
+    compiles between entry and exit (``compiles``)."""
+    __slots__ = ("_tel", "name", "attrs", "_t0", "_hooks", "_ann", "_c0")
 
     def __init__(self, tel: "Telemetry", name: str,
                  attrs: Optional[Dict[str, Any]]):
@@ -192,6 +205,12 @@ class _Span:
 
     def __enter__(self):
         self._tel._stack.append(self.name)
+        self._hooks = _jax_hooks()
+        if self._hooks is not None:
+            annotation, compile_count = self._hooks
+            self._c0 = compile_count()
+            self._ann = annotation(self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -199,8 +218,12 @@ class _Span:
         dur = time.perf_counter() - self._t0
         tel = self._tel
         tel._stack.pop()
+        fields = {}
+        if self._hooks is not None:
+            self._ann.__exit__(*exc)
+            fields["compiles"] = self._hooks[1]() - self._c0
         tel._emit("span", self.name, path=tel._path(self.name),
-                  dur_s=dur, attrs=self.attrs)
+                  dur_s=dur, attrs=self.attrs, **fields)
         return False
 
 
@@ -272,19 +295,6 @@ class Telemetry:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, attrs or None)
-
-    def point_span(self, name: str, **attrs):
-        """A *logical* span: structure + attributes, ``dur_s=None``.
-
-        Used for the phases inside the fused round jit (broadcast /
-        train-chunk[t] / fold / finalize): they are real stages of the
-        executed program, but their wall time is only measurable at the
-        host boundary, so none is invented — the enclosing ``execute``
-        span owns the clock."""
-        if not self.enabled:
-            return
-        self._emit("span", name, path=self._path(name), dur_s=None,
-                   attrs=attrs or None)
 
     def counter(self, name: str, value, **attrs) -> None:
         """One named scalar observation (client health lives here)."""

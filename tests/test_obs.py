@@ -1,7 +1,8 @@
 """Structured telemetry layer (repro/obs): event registry semantics,
-round-phase span trees from both engines, client-health counters,
-byte-ledger reconciliation against the trainer's accounting, the
-no-op-sink bit-parity contract, and the JSONL -> report pipeline."""
+the timed span trees of both engines, spans on the profiler's trace and
+their compile counts, client-health counters, byte-ledger
+reconciliation against the trainer's accounting, the no-op-sink
+bit-parity contract, and the JSONL -> report pipeline."""
 
 import json
 
@@ -77,14 +78,20 @@ def test_span_paths_nest():
     with tel.span("outer"):
         with tel.span("inner", tag=3):
             tel.counter("c", 1)
-        tel.point_span("logical")
-    paths = [e.get("path") for e in mem.of_kind("span")]
-    # spans emit on exit: inner closes first, then the logical point
-    # span, then outer
-    assert paths == ["outer/inner", "outer/logical", "outer"]
+        with tel.span("sibling"):
+            pass
+    spans = mem.of_kind("span")
+    # spans emit on exit: inner closes first, then its sibling, then outer
+    assert [e["path"] for e in spans] == \
+        ["outer/inner", "outer/sibling", "outer"]
+    # every span is timed, and the outer one holds both inner ones
+    assert all(e["dur_s"] >= 0 for e in spans)
+    outer = mem.named("outer")[0]
+    assert outer["dur_s"] >= sum(e["dur_s"] for e in spans[:2])
     inner = mem.named("inner")[0]
-    assert inner["dur_s"] >= 0 and inner["tag"] == 3
-    assert mem.named("logical")[0]["dur_s"] is None
+    assert inner["tag"] == 3
+    # jax is loaded here, so each span counts the compiles inside it
+    assert [e["compiles"] for e in spans] == [0, 0, 0]
     assert mem.named("c")[0]["value"] == 1
     # seq is emission order
     assert [e["seq"] for e in mem.events] == list(range(len(mem.events)))
@@ -97,7 +104,8 @@ def test_disabled_telemetry_emits_nothing():
         tel.counter("c", 1)
         tel.ledger("l", {"a": 1})
         tel.log("hi")
-        tel.point_span("p")
+        with tel.span("y"):
+            pass
     assert mem.events == []
     assert not obslib.NOOP.enabled  # the module singleton stays disabled
 
@@ -119,30 +127,29 @@ def test_sync_two_round_span_tree_and_ledgers():
     tr.run_round()
     tr.run_round()
 
-    # k=4 per population at chunk 2 -> 2 chunks each, 4 folds/round
-    want_phases = (["round/sample_gather", "round/execute",
-                    "round/broadcast"]
-                   + [f"round/train-chunk[{t}]" for t in range(4)]
-                   + ["round/fold", "round/finalize", "round"])
+    # the host's timed phases; the round jit's stages are device tags
+    want_phases = ["round/sample_gather", "round/execute", "round"]
     for r in (0, 1):
-        paths = [e["path"] for e in mem.of_kind("span")
+        spans = [e for e in mem.of_kind("span")
                  if e["round"] == r and e["name"] not in
                  ("trace_lower", "compile")]
-        assert paths == want_phases, (r, paths)
-    # the compile split happens exactly once, on the first round
+        assert [e["path"] for e in spans] == want_phases, (r, spans)
+        assert all(e["dur_s"] > 0 for e in spans)
+        rnd, execute = spans[-1], spans[1]
+        assert rnd["dur_s"] >= execute["dur_s"]
+    # the compile split happens exactly once, on the first round, and
+    # only the first round compiles
     assert [e["round"] for e in mem.named("trace_lower")] == [0]
     assert [e["round"] for e in mem.named("compile")] == [0]
+    assert mem.named("compile")[0]["compiles"] >= 1
+    assert [e["compiles"] > 0 for e in mem.named("round")] == [True, False]
     # and the roofline ledger rides the compiled first round (the toy
     # adapter has no matmuls, so assert on memory traffic, not flops)
     roof = mem.named("roofline")
     assert len(roof) == 1 and roof[0]["values"]["hbm_bytes"] > 0
 
-    # chunk attributes: population split in scan order, staleness absent
-    chunks0 = [e for e in mem.of_kind("span")
-               if e["round"] == 0 and e["name"].startswith("train-chunk")]
-    assert [c["population"] for c in chunks0] == \
-        ["simple", "simple", "complex", "complex"]
-    assert all("staleness" not in c for c in chunks0)
+    # a synchronous round has no staleness to report
+    assert mem.named("staleness_hist") == []
 
     # client health: clean run, no exclusions, chunk 2 divides k=4
     assert [e["value"] for e in mem.named("nan_excluded_devices")] == [0, 0]
@@ -204,15 +211,23 @@ def test_async_lag1_span_tree_and_health():
     rounds = [e for e in mem.named("round")]
     assert [e["engine"] for e in rounds] == ["async", "async"]
     assert [e["lag"] for e in rounds] == [1, 1]
+    # the same timed host phases as the synchronous engine
+    for r in (0, 1):
+        spans = [e for e in mem.of_kind("span")
+                 if e["round"] == r and e["name"] not in
+                 ("trace_lower", "compile")]
+        assert [e["path"] for e in spans] == \
+            ["round/sample_gather", "round/execute", "round"]
+        assert all(e["dur_s"] > 0 for e in spans)
+    assert [e["compiles"] > 0 for e in rounds] == [True, False]
 
     # staleness histogram matches the fold schedule exactly:
-    # round 0 clamps to all-fresh; round 1 has one 1-stale chunk
+    # round 0 clamps to all-fresh; round 1 has one 1-stale chunk (the
+    # first of the fold stream: the simple population's first chunk)
     hists = [e["values"] for e in mem.named("staleness_hist")]
     assert hists == [{"0": 4}, {"0": 3, "1": 1}]
-    # and the first train-chunk of round 1 carries that staleness attr
-    chunks1 = [e for e in mem.of_kind("span")
-               if e["round"] == 1 and e["name"].startswith("train-chunk")]
-    assert [c["staleness"] for c in chunks1] == [1, 0, 0, 0]
+    assert [list(s) for s in tr.async_engine.schedule(1)] == \
+        [[1, 0], [0, 0]]
 
     # version-cache counters: round 0 all misses (8 clients); round 1
     # the stale chunk's clients (chunk=2) re-use their held version
@@ -228,6 +243,130 @@ def test_async_lag1_span_tree_and_health():
     assert led[-1]["cum_total"] == tr.total_bytes
     # the stale chunk saved exactly its clients' downloads in round 1
     assert led[1]["down"] == led[0]["down"] - 2 * tr.per_simple_bytes
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def test_spans_are_profiler_annotations(tmp_path):
+    """Two profiled rounds: the program's spans are host events of the
+    ``.xplane.pb``, ``execute`` inside ``round``, and the second round
+    compiled nothing."""
+    import glob
+    mem = obslib.MemorySink()
+    tr = _make_trainer(obslib.Telemetry([mem]))
+    with jax.profiler.trace(str(tmp_path)):
+        tr.run_round()
+        tr.run_round()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    for name in ("round", "sample_gather", "execute"):
+        assert len(events.get(name, [])) == 2, (name, sorted(events))
+    for (r0, r1), (x0, x1) in zip(sorted(events["round"]),
+                                  sorted(events["execute"])):
+        assert r0 <= x0 < x1 <= r1
+    assert [e["compiles"] for e in mem.named("round")][1] == 0
+
+
+def test_spans_without_jax_stay_plain(tmp_path):
+    """In a process that never loaded jax, an enabled span neither
+    imports it nor carries ``compiles``."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    code = (
+        "import sys\n"
+        "from repro.obs import telemetry as t\n"
+        "m = t.MemorySink()\n"
+        "with t.Telemetry([m]).span('round'):\n"
+        "    pass\n"
+        "e, = m.events\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "assert 'compiles' not in e and e['dur_s'] >= 0, e\n")
+    src = Path(__file__).resolve().parents[1] / "src"
+    env = dict(os.environ, PYTHONPATH=str(src))
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, env=env)
+    assert proc.returncode == 0, proc.stderr
+
+
+# ---------------------------------------------------------------------------
+# Device stages: tags on the round jit's ops
+# ---------------------------------------------------------------------------
+
+def _stage_counts(hlo: str) -> dict:
+    import re
+    counts: dict = {}
+    for stage in re.findall(r'fedhen_scope="(\w+)"', hlo):
+        counts[stage] = counts.get(stage, 0) + 1
+    return counts
+
+
+def _stripped(hlo: str) -> list:
+    """Compiled HLO text without metadata, frontend attributes or the
+    instructions' own names: what the executable computes."""
+    import re
+    names: dict = {}
+    out = []
+    for ln in hlo.splitlines():
+        if not re.match(r"\s*(ROOT )?%|ENTRY |\}", ln):
+            continue
+        ln = re.sub(r", (frontend_attributes|metadata)=\{[^{}]*\}", "", ln)
+        ln = re.sub(r"%([\w.-]+)",
+                    lambda m: "%" + names.setdefault(m.group(1),
+                                                     f"v{len(names)}"), ln)
+        out.append(ln)
+    return out
+
+
+@pytest.mark.parametrize("fed_kw", [
+    {}, {"async_lag": 1}, {"comm_dtype": "int8"},
+    {"variance_reduction": "scaffold"}],
+    ids=["sync", "async", "int8", "scaffold"])
+def test_round_stages_tag_the_compiled_round(fed_kw):
+    """Every engine's round carries the stages it runs; the wire stage
+    holds ops only where the wire is not the identity."""
+    hlo = _make_trainer(None, **fed_kw).lower_round().compile().as_text()
+    counts = _stage_counts(hlo)
+    want = {"local_sgd", "fold", "finalize"}
+    if fed_kw.get("comm_dtype") == "int8":
+        want.add("wire")
+    assert set(counts) == want, counts
+
+
+@pytest.mark.parametrize("async_lag", [0, 1])
+def test_stage_tags_leave_the_compiled_round_unchanged(async_lag,
+                                                      monkeypatch):
+    """With the stages made no-ops, the compiled round is the same
+    instruction for instruction once metadata is stripped: the tags
+    change no fusion and no schedule."""
+    import contextlib
+    from repro.core import federated
+    tagged = _make_trainer(None, async_lag=async_lag).lower_round()
+    tagged = tagged.compile().as_text()
+    no_stage = lambda name: contextlib.nullcontext()
+    monkeypatch.setattr(federated, "stage", no_stage)
+    monkeypatch.setattr(async_rounds, "stage", no_stage)
+    plain = _make_trainer(None, async_lag=async_lag).lower_round()
+    plain = plain.compile().as_text()
+    assert _stage_counts(tagged) and _stage_counts(plain) == {}
+    assert _stripped(tagged) == _stripped(plain)
+
+
+def test_stage_names_are_the_four():
+    from repro.obs import scopes
+    assert scopes.STAGES == ("local_sgd", "wire", "fold", "finalize")
+    with pytest.raises(ValueError, match="unknown stage"):
+        with scopes.stage("train"):
+            pass
 
 
 # ---------------------------------------------------------------------------
@@ -282,6 +421,9 @@ def test_jsonl_roundtrip_and_report(tmp_path):
     assert summary["comm"]["cum_total"] == tr.total_bytes
     assert summary["health"]["nan_excluded_devices"] == 0
     assert summary["rounds"]["compile_s"] > 0
+    # only the first round compiled
+    assert summary["rounds"]["compiles"] >= 1
+    assert list(summary["rounds"]["compiling_rounds"]) == [0]
     # eval ledgers feed the trajectory; acc metrics count as reached
     # at-or-ABOVE the target, so an unreachable ceiling stays None
     summary_t = obs_report.summarize(events, target=1e9,
@@ -289,7 +431,7 @@ def test_jsonl_roundtrip_and_report(tmp_path):
     assert summary_t["progress"]["rounds_to_target"] is None
     rendered = obs_report.render(summary)
     for needle in ("telemetry run report", "-- rounds --", "-- comm --",
-                   "-- client health --"):
+                   "-- client health --", "backend compiles: "):
         assert needle in rendered
     # the CLI entry point renders the same file without error
     assert "rounds: 2" in obs_report.report_path(path)

@@ -4,8 +4,12 @@ Interpret mode runs each kernel's body on the CPU but not the TPU
 compiler's rules (block tiling, scoped VMEM, vector shapes).  These tests
 compile every fold kernel for a v5e chip that is described, not attached,
 at the real flat width of the paper's model (``n_flat = 11,175,936``) and
-a 5-client chunk, and require the Pallas kernel in the compiled program.
-Nothing runs.
+a 5-client chunk, and require the Pallas kernel in the compiled program,
+named after the kernel.  Nothing runs.
+
+A tiny round of the paper's model compiled for the same chip keeps the
+round's stage tags (``obs/scopes.py``) on the ops the TPU profiler
+reports.
 
 The topology is described inside a module fixture, never while a module
 is imported: only one process at a time may load the TPU library.
@@ -15,11 +19,13 @@ Also here: ``chip_smoke.py`` refuses to run without a TPU.
 
 import functools
 import importlib.util
+import re
 import sys
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -73,13 +79,91 @@ def _fold_case(name, spec):
              spec((Z, K_TOP), jnp.int32), mask, w, w))
 
 
+KERNEL_NAMES = {"acc_f32": "masked_agg_acc", "acc_bf16": "masked_agg_acc",
+                "oneshot": "masked_agg", "acc_deq": "masked_agg_acc_deq",
+                "scatter_int8": "masked_scatter_acc"}
+
+
+def _custom_calls(hlo: str) -> list:
+    """Instruction names of the Mosaic kernels in compiled HLO text."""
+    return re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
+                      r'"tpu_custom_call"', hlo)
+
+
 @pytest.mark.parametrize("name", ["acc_f32", "acc_bf16", "oneshot",
                                   "acc_deq", "scatter_int8"])
 def test_fold_kernel_compiles_for_v5e(one_chip, name):
     spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     fn, args = _fold_case(name, spec)
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    calls = _custom_calls(compiled.as_text())
+    # the kernel's own name, not the enclosing function's (``_lambda_``)
+    assert [c.rsplit(".", 1)[0] for c in calls] == [KERNEL_NAMES[name]]
+
+
+def _computations(hlo: str) -> dict:
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and " = " in line:
+            comps[name].append(line.strip())
+    return comps
+
+
+def test_round_stage_tags_survive_the_v5e_compile(one_chip, monkeypatch):
+    """A tiny round of the paper's model (PreActResNet18-GN at its
+    widths, 8x8 images, four clients of one step), lowered on shapes
+    placed on the described chip with the Pallas fold: every fusion that
+    holds a convolution is tagged ``local_sgd``, the fold kernel is
+    tagged ``fold`` and named ``masked_agg_acc``, and finalize tags ops
+    of its own."""
+    from repro.configs.base import FedConfig
+    from repro.core.adapters import ResNetAdapter
+    from repro.core.federated import FederatedTrainer
+    from repro.kernels.masked_agg import ops as agg_ops
+    rng = np.random.default_rng(0)
+    data = [{"images": jnp.asarray(rng.normal(size=(2, 8, 8, 3)),
+                                   jnp.float32),
+             "labels": jnp.asarray(rng.integers(0, 10, 2), jnp.int32)}
+            for _ in range(4)]
+    fed = FedConfig(n_devices=4, n_simple=2, participation=1.0,
+                    local_epochs=1, lr=0.1, batch_size=2,
+                    algorithm="fedhen", seed=0, cohort_chunk=0)
+    tr = FederatedTrainer(ResNetAdapter(), fed, data)
+    plan = tr._sample_plan()
+    args = tr._round_args(plan, tr._gather(plan.simple_ids),
+                          tr._gather(plan.complex_ids),
+                          jax.random.PRNGKey(0))
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        args)
+    monkeypatch.setattr(agg_ops, "use_pallas", lambda: True)
+    hlo = jax.jit(tr._make_round_fn()).lower(*shapes).compile().as_text()
+
+    comps = _computations(hlo)
+    tag = 'fedhen_scope="{}"'.format
+    conv_comps = {n for n, lines in comps.items()
+                  if any(" convolution(" in ln for ln in lines)}
+    conv_fusions = [ln for lines in comps.values() for ln in lines
+                    if " fusion(" in ln and re.search(
+                        r"calls=%([\w.-]+)", ln).group(1) in conv_comps]
+    assert conv_fusions
+    assert all(tag("local_sgd") in ln for ln in conv_fusions), \
+        [ln[:120] for ln in conv_fusions if tag("local_sgd") not in ln]
+    kernels = [ln for lines in comps.values() for ln in lines
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels and all(tag("fold") in ln for ln in kernels)
+    assert all(ln.startswith(("%masked_agg_acc.", "ROOT %masked_agg_acc."))
+               for ln in kernels)
+    assert tag("finalize") in hlo
+    # an f32 wire is the identity: the wire stage holds no op
+    assert tag("wire") not in hlo
+    # no op carries two stages
+    assert not re.search(r'fedhen_scope="\w+"[^\n]*fedhen_scope=', hlo)
 
 
 def test_chip_smoke_refuses_cpu(capsys, monkeypatch):
