@@ -11,21 +11,23 @@ per-device NaN exclusion (Appendix A).
 
 **Streaming contract.**  A round is one jit (inputs donated): each
 population (simple, then complex) is split into chunks of
-``FedConfig.cohort_chunk`` clients, and ``lax.scan`` runs the vmap'd client
-trainer chunk by chunk, folding each trained chunk into running masked
+``FedConfig.cohort_chunk`` clients, and ``lax.scan`` trains the clients
+chunk by chunk, folding each trained chunk into running masked
 aggregation sums (``aggregate.streaming_fold``, the ``masked_agg`` kernel's
 contract) that are normalized once at the end of the round
-(``aggregate.streaming_finalize``).  Device memory is therefore O(chunk),
-not O(k) — cohorts of hundreds of clients stream through a fixed-size
-working set.  ``cohort_chunk=0`` trains each population in a single chunk
-(the old whole-cohort vmap); ``cohort_chunk="auto"`` derives the chunk from
-the flat layout's per-client byte footprint against
-``FedConfig.agg_memory_budget_mb`` (``flatten.auto_cohort_chunk`` — the
-resolved value is ``FederatedTrainer.cohort_chunk``).  Populations the
-chunk size does not divide are padded with zero-validity clients (wrapped
-data, weight 0), so padding can never change the aggregate; per-client RNG
-keys are derived by ``fold_in(population_key, client_index)``, so the
-round's result is invariant to the chunking up to float summation order.
+(``aggregate.streaming_finalize``).  Within a chunk the clients train one
+after another (``lax.map``), each from the same broadcast.  Device memory
+is therefore O(chunk), not O(k), and the training activations O(one
+client), so cohorts of hundreds of clients stream through a fixed-size
+working set.  ``cohort_chunk=0`` trains each population in a single
+chunk; ``cohort_chunk="auto"`` derives the chunk from the flat layout's
+per-client byte footprint against ``FedConfig.agg_memory_budget_mb``
+(``flatten.auto_cohort_chunk`` — the resolved value is
+``FederatedTrainer.cohort_chunk``).  Populations the chunk size does not
+divide are padded with zero-validity clients (wrapped data, weight 0), so
+padding can never change the aggregate; per-client RNG keys are derived
+by ``fold_in(population_key, client_index)``, so the round's result is
+invariant to the chunking up to float summation order.
 On the production mesh the chunk axis is sharded over ``data``/``pod``
 (see launch/), making the per-chunk fold an all-reduce: the communication
 the paper saves.
@@ -336,9 +338,17 @@ def stream_population(state, get_src, train_fn, data, key, agg_fold, *,
     ef_pos = len(xs) - 1
     is_simple = jnp.full((chunk,), is_simple_flag)
 
-    def tile(tree):
-        return jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (chunk,) + x.shape), tree)
+    def train_chunk(src, *per_client):
+        """The chunk's clients trained one after another from ``src``;
+        ``per_client`` holds the data, keys and (SCAFFOLD) corrections,
+        stacked ``(chunk, ...)``, and so do the trained trees and losses.
+        Vmapped over per-client weights, a convolution would become a
+        grouped convolution over the client axis, which the TPU lays out
+        apart from the ops around it and copies every activation between
+        the two layouts; a dense client's matmuls gain nothing from the
+        batch either, and in sequence one client's activations are live
+        at a time."""
+        return jax.lax.map(lambda xs: train_fn(src, *xs), per_client)
 
     def _mask_pop(v):
         """Zero a (Z, n_flat) cv vector outside the population's slice."""
@@ -393,13 +403,11 @@ def stream_population(state, get_src, train_fn, data, key, agg_fold, *,
         src = get_src(idx_i)
         with stage("local_sgd"):
             if scaffold is None:
-                trained, losses = jax.vmap(train_fn)(
-                    tile(src), data_i, keys_i)
+                trained, losses = train_chunk(src, data_i, keys_i)
             else:
                 cv_i = xs[cv_pos]
                 corr = _mask_pop(scaffold.c_global[None] - cv_i)
-                trained, losses = jax.vmap(train_fn)(
-                    tile(src), data_i, keys_i, corr)
+                trained, losses = train_chunk(src, data_i, keys_i, corr)
             valid = real_i
             if skip_nan:
                 valid = valid & jax.vmap(masking.tree_isfinite)(trained)
@@ -523,6 +531,14 @@ class RoundDispatch:
 # ---------------------------------------------------------------------------
 # Round functions
 # ---------------------------------------------------------------------------
+
+@jax.jit
+def _stack_clients(*datasets: Batch) -> Batch:
+    """The clients' datasets stacked on a leading axis, in one device
+    call: eager ``jnp.stack`` first copies each client's arrays to add
+    the axis, so the cohort's data would sit on the device twice."""
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *datasets)
+
 
 class FederatedTrainer:
     """Drives T rounds of any of the three algorithms (paper protocol)."""
@@ -964,8 +980,7 @@ class FederatedTrainer:
         return plan.simple_ids, plan.complex_ids
 
     def _gather(self, ids) -> Batch:
-        datasets = [self.client_data[i] for i in ids]
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *datasets)
+        return _stack_clients(*[self.client_data[i] for i in ids])
 
     # -- public API ----------------------------------------------------------
 

@@ -9,9 +9,9 @@ puts the stage in the ops' ``op_name`` (HLO dumps, profiler UIs), and
 profiler prints in each XLA op's event name.  A reader of a device trace
 sums op times by that text.  The stages (:data:`STAGES`):
 
-* ``local_sgd`` — the vmapped client trainers (forward, backward, the
-  optimizer step), SCAFFOLD's gradient correction and the NaN-device
-  finiteness test;
+* ``local_sgd`` — the chunk's client trainers, one after another
+  (forward, backward, the optimizer step), SCAFFOLD's gradient
+  correction and the NaN-device finiteness test;
 * ``wire`` — the broadcast's encode/decode trip (the async engine's
   version decode) and the wire-v2 upload's pack, encode, decode and
   error-feedback residual;

@@ -1,14 +1,17 @@
 """FedHeN core: masking, aggregation (Alg. 1), algorithms end-to-end."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import FedConfig, LayerSpec, ModelConfig
-from repro.core import aggregate, masking
-from repro.core.adapters import LMAdapter
-from repro.core.federated import FederatedTrainer, rounds_to_target
+from repro.core import aggregate, async_rounds, masking
+from repro.core.adapters import LMAdapter, ResNetAdapter
+from repro.core.federated import (FederatedTrainer, make_client_trainer,
+                                  rounds_to_target, stream_population)
 from repro.data.synthetic import synthetic_lm
 from repro.data.federated import dirichlet_split, iid_split
 
@@ -355,6 +358,110 @@ def test_flat_round_hlo_has_fewer_masked_agg_reductions():
     # the non-fold reduces (loss, clipping, validity) are identical in both
     # programs; the fold's per-leaf launches are the difference
     assert n_tree - n_flat >= n_leaves - 2, (n_flat, n_tree, n_leaves)
+
+
+# ---------------------------------------------------------------------------
+# A chunk's clients train one after another
+# ---------------------------------------------------------------------------
+
+def _image_clients(n_clients, n=2, seed=0):
+    """PreActResNet18 clients of ``n`` 8x8 images each."""
+    rng = np.random.default_rng(seed)
+    return [{"images": jnp.asarray(rng.normal(size=(n, 8, 8, 3)),
+                                   jnp.float32),
+             "labels": jnp.asarray(rng.integers(0, 10, n), jnp.int32)}
+            for _ in range(n_clients)]
+
+
+def _assert_chunk_matches_clients_alone(adapter, loss, clients, fed,
+                                        rtol, atol):
+    """Train ``clients`` as one chunk through ``stream_population``, then
+    each alone with ``make_client_trainer`` from the same broadcast and
+    per-client key: the trained trees and the mean loss agree."""
+    z = len(clients)
+    params = adapter.init(jax.random.PRNGKey(0))
+    train = make_client_trainer(getattr(adapter, loss), fed)
+    key = jax.random.PRNGKey(1)
+
+    @jax.jit
+    def chunk(params, data):
+        # the fold hands back the stacked trained trees it was given
+        state = jax.tree.map(lambda x: jnp.zeros((z,) + x.shape, x.dtype),
+                             params)
+        trained, mean_loss, n_valid, _, _ = stream_population(
+            state, lambda _: params, train, data, key,
+            lambda _, trained, *a, **kw: trained, k=z, chunk=z, n_chunks=1,
+            is_simple_flag=loss == "loss_simple", skip_nan=True)
+        return trained, mean_loss, n_valid
+
+    trained, mean_loss, n_valid = chunk(
+        params, jax.tree.map(lambda *x: jnp.stack(x), *clients))
+    assert float(n_valid) == z
+    losses = []
+    for i, data in enumerate(clients):
+        want, loss_i = jax.jit(train)(params, data,
+                                      jax.random.fold_in(key, i))
+        losses.append(float(loss_i))
+        assert not all(np.array_equal(w, p0) for w, p0 in zip(
+            jax.tree.leaves(want), jax.tree.leaves(params)))
+        for got, w in zip(jax.tree.leaves(trained), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(got[i]), np.asarray(w),
+                                       rtol=rtol, atol=atol)
+    np.testing.assert_allclose(float(mean_loss), np.mean(losses),
+                               rtol=1e-6)
+
+
+@pytest.mark.parametrize("loss", ["loss_simple", "loss_side"])
+def test_sequential_clients_match_each_client_alone(loss):
+    """Each convolutional client of a chunk equals that client trained
+    alone."""
+    _assert_chunk_matches_clients_alone(
+        ResNetAdapter(), loss, _image_clients(2),
+        FedConfig(local_epochs=1, lr=0.1, batch_size=2),
+        rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("loss", ["loss_simple", "loss_side"])
+def test_dense_clients_map_in_sequence(loss):
+    """A decoder LM's clients train one after another too: each client of
+    a chunk of three (two SGD steps each) equals that client trained
+    alone."""
+    data = synthetic_lm(12, 16, TINY.vocab_size, seed=1)
+    _assert_chunk_matches_clients_alone(
+        LMAdapter(TINY), loss, iid_split(data, 3, seed=2),
+        FedConfig(local_epochs=1, lr=0.1, batch_size=2),
+        rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("engine", [
+    pytest.param({"variance_reduction": "none"}, id="none"),
+    pytest.param({"variance_reduction": "scaffold"}, id="scaffold"),
+    pytest.param({"async_lag": 0}, id="async-lag0"),
+    pytest.param({"async_lag": 1}, id="async-lag1"),
+    pytest.param({"async_lag": 1, "comm_dtype": "int8"},
+                 id="async-lag1-int8"),
+])
+def test_conv_clients_map_in_sequence(engine):
+    """The round the paper's model lowers, in chunks of two clients,
+    holds no grouped convolution, in either engine, on SCAFFOLD's branch
+    and behind the int8 wire: vmapped over per-client weights every
+    convolution would group over the client axis."""
+    fed_kw = dict(engine)
+    lag0 = fed_kw.get("async_lag") == 0
+    data = _image_clients(4)
+    fed = FedConfig(n_devices=4, n_simple=2, participation=1.0,
+                    local_epochs=1, lr=0.1, batch_size=2, algorithm="fedhen",
+                    seed=0, cohort_chunk=2, **fed_kw)
+    tr = FederatedTrainer(ResNetAdapter(), fed, data)
+    assert tr.k_simple == tr.k_complex == tr.cohort_chunk == 2
+    if lag0:
+        lowered = async_rounds.AsyncRoundEngine(tr, lag=0).lower_round()
+    else:
+        assert (tr.async_engine is not None) == ("async_lag" in fed_kw)
+        lowered = tr.lower_round()
+    hlo = lowered.as_text()
+    groups = re.findall(r"(?:feature|batch)_group_count = (\d+)", hlo)
+    assert groups and set(groups) == {"1"}, sorted(set(groups))
 
 
 # ---------------------------------------------------------------------------

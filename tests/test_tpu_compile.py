@@ -9,7 +9,8 @@ named after the kernel.  Nothing runs.
 
 A tiny round of the paper's model compiled for the same chip keeps the
 round's stage tags (``obs/scopes.py``) on the ops the TPU profiler
-reports.
+reports, and its convolutions plain: one client's batch, two spatial
+window dimensions.
 
 The topology is described inside a module fixture, never while a module
 is imported: only one process at a time may load the TPU library.
@@ -114,13 +115,12 @@ def _computations(hlo: str) -> dict:
     return comps
 
 
-def test_round_stage_tags_survive_the_v5e_compile(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def tiny_round_hlo(one_chip):
     """A tiny round of the paper's model (PreActResNet18-GN at its
-    widths, 8x8 images, four clients of one step), lowered on shapes
-    placed on the described chip with the Pallas fold: every fusion that
-    holds a convolution is tagged ``local_sgd``, the fold kernel is
-    tagged ``fold`` and named ``masked_agg_acc``, and finalize tags ops
-    of its own."""
+    widths, 8x8 images, four clients of one step, chunks of two),
+    compiled on shapes placed on the described chip with the Pallas
+    fold: its HLO text."""
     from repro.configs.base import FedConfig
     from repro.core.adapters import ResNetAdapter
     from repro.core.federated import FederatedTrainer
@@ -141,9 +141,17 @@ def test_round_stage_tags_survive_the_v5e_compile(one_chip, monkeypatch):
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         args)
-    monkeypatch.setattr(agg_ops, "use_pallas", lambda: True)
-    hlo = jax.jit(tr._make_round_fn()).lower(*shapes).compile().as_text()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(agg_ops, "use_pallas", lambda: True)
+        return jax.jit(tr._make_round_fn()).lower(*shapes).compile().as_text()
 
+
+def test_round_stage_tags_survive_the_v5e_compile(tiny_round_hlo):
+    """In the tiny round compiled for the chip every fusion that holds a
+    convolution is tagged ``local_sgd``, the fold kernel is tagged
+    ``fold`` and named ``masked_agg_acc``, and finalize tags ops of its
+    own."""
+    hlo = tiny_round_hlo
     comps = _computations(hlo)
     tag = 'fedhen_scope="{}"'.format
     conv_comps = {n for n, lines in comps.items()
@@ -164,6 +172,19 @@ def test_round_stage_tags_survive_the_v5e_compile(one_chip, monkeypatch):
     assert tag("wire") not in hlo
     # no op carries two stages
     assert not re.search(r'fedhen_scope="\w+"[^\n]*fedhen_scope=', hlo)
+
+
+def test_round_convolutions_are_plain_on_v5e(tiny_round_hlo):
+    """Each chunk's clients train one after another, so every convolution
+    of the tiny round compiles with a window over the image's two spatial
+    dimensions.  Vmapped over per-client weights, the TPU lowers the
+    grouped convolution with the client axis as a third, dilated window
+    dimension (``size=3x3x2 ... lhs_dilate=1x1x2``) and copies the
+    activations between that layout and the elementwise ops' one."""
+    windows = re.findall(r" convolution\([^\n]*?window=\{size=([\dx]+)",
+                         tiny_round_hlo)
+    assert windows
+    assert all(len(w.split("x")) == 2 for w in windows), sorted(set(windows))
 
 
 def test_chip_smoke_refuses_cpu(capsys, monkeypatch):
