@@ -70,7 +70,15 @@ def input_specs(cfg: ModelConfig, shape: InputShape,
         tok_shape = tok_shape + (cfg.n_codebooks,)
     specs["tokens"] = jax.ShapeDtypeStruct(tok_shape, jnp.int32)
 
-    if cfg.frontend is not None and shape.kind != "decode":
+    if cfg.cross_attention and shape.kind != "decode":
+        # the conditioning is the cross-attention source, not part of the
+        # sequence; decode reads its K/V from the cache
+        specs["cond"] = jax.ShapeDtypeStruct(
+            (b, cfg.frontend.n_tokens, cfg.frontend.d_in),
+            jnp.dtype(cfg.compute_dtype))
+        specs["cond_mask"] = jax.ShapeDtypeStruct(
+            (b, cfg.frontend.n_tokens), jnp.bool_)
+    elif cfg.frontend is not None and shape.kind != "decode":
         # frontend embeddings occupy the head of the sequence; the token part
         # shrinks so total length stays seq_len (handled by the step fns)
         specs["extra_embeds"] = jax.ShapeDtypeStruct(

@@ -99,10 +99,16 @@ class ModelConfig:
     # -- layer pattern -----------------------------------------------------
     pattern: Tuple[LayerSpec, ...] = (LayerSpec(),)
     window: int = 4096        # sliding window for local attention layers
+    # positions: "rope" rotates q/k in every attention layer; "sin" adds
+    # Audiocraft's sinusoidal absolute embedding (cos then sin, period
+    # ``rope_theta``) to the summed token embeddings
+    positions: str = "rope"
     rope_theta: float = 10000.0
     attn_logit_softcap: float = 0.0   # gemma-2 style; 0 disables
     final_logit_softcap: float = 0.0
     norm_eps: float = 1e-6
+    norm: str = "rms"         # "rms" (gemma-style scale) | "layer" (+ bias)
+    embed_scale: bool = True  # multiply token embeddings by sqrt(d_model)
     tie_embeddings: bool = True
     use_qk_norm: bool = False
     d_rnn: int = 0            # RG-LRU width (0 -> d_model)
@@ -111,8 +117,18 @@ class ModelConfig:
     # -- MoE / modality ----------------------------------------------------
     moe: Optional[MoEConfig] = None
     mlp_glu: bool = True      # gated (3-matrix) vs plain (2-matrix) MLP
-    n_codebooks: int = 1      # musicgen: parallel EnCodec codebooks
+    gelu_exact: bool = False  # erf GELU (torch's default) vs the tanh form
+    # musicgen: parallel EnCodec codebooks.  More than one puts them in
+    # the delay pattern: codebook k runs k steps late, the gaps hold a
+    # special token (id ``vocab_size``, one more embedding row per
+    # codebook), the loss leaves out every label that is that token, and
+    # each codebook has its own untied head over unscaled summed embeddings
+    n_codebooks: int = 1
     frontend: Optional[StubFrontend] = None
+    # the frontend's embeddings, projected (with bias) to d_model, are the
+    # source of a cross-attention sub-block in every layer instead of
+    # tokens prepended to the sequence
+    cross_attention: bool = False
 
     # -- xLSTM -------------------------------------------------------------
     mlstm_proj_factor: float = 2.0
@@ -140,6 +156,16 @@ class ModelConfig:
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads != 0:
             raise ValueError("n_heads must be divisible by n_kv_heads")
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"unknown norm {self.norm!r}")
+        if self.positions not in ("rope", "sin"):
+            raise ValueError(f"unknown positions {self.positions!r}")
+        if self.cross_attention and self.frontend is None:
+            raise ValueError("cross_attention needs a frontend (its source)")
+        if self.n_codebooks > 1 and (self.tie_embeddings or self.embed_scale):
+            raise ValueError("parallel codebooks take untied heads and "
+                             "unscaled embeddings (tie_embeddings=False, "
+                             "embed_scale=False)")
 
     # Derived quantities -------------------------------------------------
 
@@ -150,6 +176,21 @@ class ModelConfig:
     @property
     def resolved_d_rnn(self) -> int:
         return self.d_rnn if self.d_rnn else self.d_model
+
+    @property
+    def delay_pattern(self) -> bool:
+        """Parallel codebooks run in MusicGen's delay pattern."""
+        return self.n_codebooks > 1
+
+    @property
+    def embed_rows(self) -> int:
+        """Rows of each embedding table: the vocabulary, plus the delay
+        pattern's special token."""
+        return self.vocab_size + int(self.delay_pattern)
+
+    @property
+    def norm_params(self) -> int:
+        return self.d_model * (2 if self.norm == "layer" else 1)
 
     @property
     def period(self) -> int:
@@ -187,27 +228,36 @@ class ModelConfig:
 
     def param_count(self) -> int:
         """Analytical parameter count of the complex model."""
-        d, v = self.d_model, self.vocab_size
-        hd = self.resolved_head_dim
-        total = v * d * self.n_codebooks          # embeddings
-        if not self.tie_embeddings:
-            total += v * d * self.n_codebooks
-        if self.frontend is not None:
-            total += self.frontend.d_in * d       # projector
+        total = self._shared_params()
         for i in range(self.n_layers):
             total += self._layer_params(self.layer_spec(i))
-        total += d                                 # final norm
-        total += d                                 # exit norm (FedHeN head)
+        total += self.norm_params                  # final norm
+        return total
+
+    def _shared_params(self) -> int:
+        """Embeddings, heads, frontend projector and the exit norm: what
+        the simple and the complex model both hold outside the layers."""
+        d, v = self.d_model, self.vocab_size
+        total = self.embed_rows * d * self.n_codebooks   # embeddings
+        if not self.tie_embeddings:
+            total += v * d * self.n_codebooks     # untied heads
+        if self.frontend is not None:
+            total += self.frontend.d_in * d       # projector
+            if self.cross_attention:
+                total += d                        # its bias
+        total += self.norm_params                  # exit norm (FedHeN head)
         return total
 
     def _layer_params(self, spec: LayerSpec) -> int:
         d = self.d_model
         hd = self.resolved_head_dim
         n = 0
+        attn = (2 * d * self.n_heads * hd          # Wq, Wo
+                + 2 * d * self.n_kv_heads * hd)    # Wk, Wv
+        if self.cross_attention:
+            n += attn + self.norm_params           # cross-attention + norm
         if spec.mixer in (ATTN_GLOBAL, ATTN_LOCAL):
-            n += d * self.n_heads * hd             # Wq
-            n += 2 * d * self.n_kv_heads * hd      # Wk, Wv
-            n += self.n_heads * hd * d             # Wo
+            n += attn
         elif spec.mixer == RGLRU:
             dr = self.resolved_d_rnn
             n += 2 * d * dr + dr * d               # in/gate/out proj
@@ -225,7 +275,7 @@ class ModelConfig:
             n += 4 * nh * dh * dh                  # recurrent (block-diag)
             dff = int(d * self.slstm_ff_factor)
             n += 2 * d * dff                       # post FFN
-        n += 2 * d                                 # pre norms (mixer + mlp)
+        n += 2 * self.norm_params                  # pre norms (mixer + mlp)
         mats = 3 if self.mlp_glu else 2            # (gate,) up, down
         if spec.mlp == MLP_DENSE:
             n += mats * d * self.d_ff
@@ -254,13 +304,9 @@ class ModelConfig:
 
     def simple_param_count(self) -> int:
         """Analytical parameter count of the FedHeN simple subnet."""
-        d, v = self.d_model, self.vocab_size
-        total = v * d * self.n_codebooks
-        if self.frontend is not None:
-            total += self.frontend.d_in * d
+        total = self._shared_params()
         for i in range(self.resolved_exit_layer):
             total += self._layer_params(self.layer_spec(i))
-        total += d                                 # exit norm
         return total
 
     def with_overrides(self, **kw) -> "ModelConfig":

@@ -20,6 +20,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import masking
@@ -78,11 +79,15 @@ class ResNetAdapter:
 # ---------------------------------------------------------------------------
 
 class LMAdapter:
-    """Any ModelConfig from the zoo.  Batch: tokens (B, S+1) [, extra_embeds].
+    """Any ModelConfig from the zoo.  Batch: tokens (B, S+1)
+    [, extra_embeds | cond, cond_mask].
 
-    For multi-codebook (musicgen) tokens are (B, S+1, n_codebooks) and the
-    loss averages codebook CEs; for VLM, ``extra_embeds`` are prepended and
-    the loss covers text positions only.
+    For multi-codebook (musicgen) tokens are (B, S+1, n_codebooks) in the
+    delay pattern and the loss averages codebook CEs, each codebook's CE
+    its mean over the labels that are not the special token.  For VLM,
+    ``extra_embeds`` are prepended and the loss covers text positions
+    only; with cross-attention ``cond`` (B, N, d_in) is the conditioning
+    that every layer attends to, ``cond_mask`` (B, N) its padding.
     """
 
     def __init__(self, cfg: ModelConfig, policy: Policy = NO_POLICY,
@@ -97,6 +102,28 @@ class LMAdapter:
     def subnet_mask(self, params: Tree) -> Tree:
         return masking.transformer_subnet_mask(params, self.cfg)
 
+    def geometry(self, data: Batch, batch_size: int) -> Dict[str, Any]:
+        """The client model's shape on one client's dataset ``data``
+        (leading axis: its sequences) at SGD batches of ``batch_size``,
+        for the run's ``run_config`` ledger.  Loss tokens count the labels
+        the loss covers, summed over codebooks."""
+        cfg = self.cfg
+        tokens = data["tokens"]
+        seq = tokens.shape[1] - 1
+        labels = np.asarray(tokens[:, 1:])
+        if cfg.delay_pattern:
+            per_seq = np.mean(np.sum(labels != cfg.vocab_size,
+                                     axis=tuple(range(1, labels.ndim))))
+        else:
+            per_seq = seq
+        out = {"model_layers": cfg.n_layers,
+               "model_exit_layer": cfg.resolved_exit_layer,
+               "model_codebooks": cfg.n_codebooks, "model_seq": seq,
+               "model_loss_tokens_per_step": float(batch_size * per_seq)}
+        if cfg.cross_attention:
+            out["model_cond_tokens"] = data["cond"].shape[1]
+        return out
+
     # -- loss plumbing -----------------------------------------------------
 
     def _inputs(self, batch: Batch):
@@ -104,6 +131,13 @@ class LMAdapter:
         inputs, labels = tokens[:, :-1], tokens[:, 1:]
         extra = batch.get("extra_embeds")
         return inputs, labels, extra
+
+    def _forward(self, params, batch, inputs, **kw):
+        return tfm.forward(params, self.cfg, inputs,
+                           extra_embeds=batch.get("extra_embeds"),
+                           cond=batch.get("cond"),
+                           cond_mask=batch.get("cond_mask"),
+                           policy=self.policy, **kw)
 
     def _head_loss(self, params, h, labels, extra, head, chunk: int = 256):
         """CE between head logits and labels.
@@ -126,18 +160,17 @@ class LMAdapter:
             chunk = s
 
         def chunk_nll_sum(h_c, lab_c):
-            logits = tfm.logits_from_hidden(params, self.cfg, h_c, head,
-                                            self.policy)
-            if self.cfg.n_codebooks > 1:
-                per = [common.softmax_cross_entropy_sum(logits[..., c, :],
-                                                        lab_c[..., c])
-                       for c in range(self.cfg.n_codebooks)]
-                return sum(per) / len(per)
-            return common.softmax_cross_entropy_sum(logits, lab_c)
+            return tfm.head_nll(params, self.cfg, h_c, lab_c, head,
+                                self.policy)
 
-        n_tok = b * s
+        def mean(total):
+            if isinstance(total, dict):     # delay pattern: per codebook
+                return jnp.mean(total["nll"]
+                                / jnp.maximum(total["count"], 1.0))
+            return total / (b * s)
+
         if s <= 2 * chunk or s % chunk:
-            return chunk_nll_sum(h, labels) / n_tok
+            return mean(chunk_nll_sum(h, labels))
 
         nc = s // chunk
         h_c = h.reshape(b, nc, chunk, -1).transpose(1, 0, 2, 3)
@@ -147,52 +180,59 @@ class LMAdapter:
         @jax.checkpoint
         def body(acc, xs):
             hc, lc = xs
-            return acc + chunk_nll_sum(hc, lc), None
+            return jax.tree.map(jnp.add, acc, chunk_nll_sum(hc, lc)), None
 
-        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
-                                (h_c, lab_c))
-        return total / n_tok
+        zero = jax.tree.map(jnp.zeros_like,
+                            jax.eval_shape(chunk_nll_sum, h_c[0], lab_c[0]))
+        total, _ = jax.lax.scan(body, zero, (h_c, lab_c))
+        return mean(total)
 
     def loss_complex(self, params: Tree, batch: Batch) -> jax.Array:
         inputs, labels, extra = self._inputs(batch)
-        _, final_h, aux = tfm.forward(params, self.cfg, inputs,
-                                      extra_embeds=extra, policy=self.policy,
-                                      remat=self.remat)
+        _, final_h, aux = self._forward(params, batch, inputs,
+                                        remat=self.remat)
         loss = self._head_loss(params, final_h, labels, extra, "final")
         return loss + aux["load_balance"] + aux["router_z"]
 
     def loss_simple(self, params: Tree, batch: Batch) -> jax.Array:
         inputs, labels, extra = self._inputs(batch)
         exit_h = tfm.forward_simple(params, self.cfg, inputs,
-                                    extra_embeds=extra, policy=self.policy,
-                                    remat=self.remat)
+                                    extra_embeds=extra,
+                                    cond=batch.get("cond"),
+                                    cond_mask=batch.get("cond_mask"),
+                                    policy=self.policy, remat=self.remat)
         return self._head_loss(params, exit_h, labels, extra, "exit")
 
     def loss_side(self, params: Tree, batch: Batch) -> jax.Array:
         """f(w_c) + f([w_c]_M) — one forward pass, two heads."""
         inputs, labels, extra = self._inputs(batch)
-        exit_h, final_h, aux = tfm.forward(params, self.cfg, inputs,
-                                           extra_embeds=extra,
-                                           policy=self.policy,
-                                           remat=self.remat)
+        exit_h, final_h, aux = self._forward(params, batch, inputs,
+                                             remat=self.remat)
         loss = (self._head_loss(params, final_h, labels, extra, "final")
                 + self._head_loss(params, exit_h, labels, extra, "exit"))
         return loss + aux["load_balance"] + aux["router_z"]
 
     def evaluate(self, params: Tree, batch: Batch) -> Dict[str, jax.Array]:
         inputs, labels, extra = self._inputs(batch)
-        exit_h, final_h, _ = tfm.forward(params, self.cfg, inputs,
-                                         extra_embeds=extra,
-                                         policy=self.policy)
+        exit_h, final_h, _ = self._forward(params, batch, inputs)
         out = {}
         for head, h in (("complex", final_h), ("simple", exit_h)):
-            logits = tfm.logits_from_hidden(
-                params, self.cfg, h, "final" if head == "complex" else "exit",
-                self.policy)
+            name = "final" if head == "complex" else "exit"
+            if self.cfg.delay_pattern:
+                # every codebook, over the labels the loss covers
+                logits = tfm.logits_from_hidden(params, self.cfg, h, name,
+                                                self.policy)
+                valid = labels != self.cfg.vocab_size
+                hits = jnp.argmax(logits, -1) == labels
+                out[f"acc_{head}"] = (jnp.sum(hits & valid)
+                                      / jnp.maximum(jnp.sum(valid), 1))
+                out[f"loss_{head}"] = self._head_loss(params, h, labels,
+                                                      extra, name)
+                continue
+            logits = tfm.logits_from_hidden(params, self.cfg, h, name,
+                                            self.policy)
             if extra is not None:
                 logits = logits[:, extra.shape[1]:]
-            lab = labels[..., 0] if self.cfg.n_codebooks > 1 else labels
-            lg = logits[..., 0, :] if self.cfg.n_codebooks > 1 else logits
-            out[f"acc_{head}"] = _acc(lg, lab)
-            out[f"loss_{head}"] = _ce(lg, lab)
+            out[f"acc_{head}"] = _acc(logits, labels)
+            out[f"loss_{head}"] = _ce(logits, labels)
         return out

@@ -765,7 +765,8 @@ class FederatedTrainer:
     def _emit_run_config(self) -> None:
         """One ``run_config`` ledger at construction: the static facts a
         run report leads with (cohort geometry, engine, wire, per-round
-        wire cost)."""
+        wire cost, and the client model's shape where the adapter gives
+        one)."""
         fed = self.fed
         (chunk_s, n_s), (chunk_c, n_c) = self._geometry()
         values = {
@@ -794,6 +795,9 @@ class FederatedTrainer:
                 "ef_store_bytes": self.ef_store.nbytes,
             })
         values.update(aggregate.engine_attrs(self.engine_spec))
+        geometry = getattr(self.adapter, "geometry", None)
+        if geometry is not None:
+            values.update(geometry(self.client_data[0], fed.batch_size))
         self.obs.ledger("run_config", values)
 
     def _emit_round_health(self, metrics: Dict[str, float], *,

@@ -28,9 +28,16 @@ def _const_mask(tree: Tree, value: bool) -> Tree:
     return jax.tree.map(lambda _: jnp.asarray(value), tree)
 
 
+# top-level subtrees the simple model uses whole: the embeddings, the
+# frontend projector (prepended tokens or the cross-attention source), and
+# the exit head: its norm and the output heads it shares with the final
+# head (the embedding where tied, ``unembed`` where not)
+SIMPLE_SHARED = ("embed", "frontend_proj", "exit_norm", "unembed")
+
+
 def transformer_subnet_mask(params: Tree, cfg: ModelConfig) -> Tree:
-    """M for the decoder zoo: embedding + frontend projector + blocks[:K]
-    + exit head (exit_norm [+ tied unembedding via the embedding])."""
+    """M for the decoder zoo: :data:`SIMPLE_SHARED` + blocks[:K] (their
+    cross-attention included)."""
     mask: Dict[str, Tree] = {}
     for name, sub in params.items():
         if name == "periods":
@@ -45,9 +52,9 @@ def transformer_subnet_mask(params: Tree, cfg: ModelConfig) -> Tree:
         elif name == "rem":
             # remainder layers sit at the tail -> never in the prefix subnet
             mask[name] = _const_mask(sub, False)
-        elif name in ("embed", "frontend_proj", "exit_norm"):
+        elif name in SIMPLE_SHARED:
             mask[name] = _const_mask(sub, True)
-        else:  # final_norm, unembed (untied)
+        else:  # final_norm
             mask[name] = _const_mask(sub, False)
     return mask
 
@@ -96,9 +103,9 @@ def extract_simple(params: Tree, cfg: ModelConfig) -> Tree:
     for name, sub in params.items():
         if name == "periods":
             out[name] = tuple(jax.tree.map(lambda x: x[:kp], s) for s in sub)
-        elif name in ("embed", "frontend_proj", "exit_norm"):
+        elif name in SIMPLE_SHARED:
             out[name] = sub
-        # rem / final_norm / unembed are complex-only
+        # rem / final_norm are complex-only
     return out
 
 

@@ -25,16 +25,20 @@ import numpy as np
 
 from repro import configs
 from repro.checkpoint.checkpoint import restore_tree
+from repro.data.synthetic import synthetic_conditioning
 from repro.models import transformer as tfm
 
 
 def generate(params, cfg, prompts: jax.Array, gen: int, *,
              adaptive_threshold: float = 0.0, temperature: float = 0.0,
-             rng=None):
-    """prompts: (B, S[, NC]).  Returns (tokens, stats)."""
+             rng=None, cond=None, cond_mask=None):
+    """prompts: (B, S[, NC]); ``cond``/``cond_mask``: the conditioning of
+    a cross-attention model, whose K/V prefill caches for every decoded
+    token.  Returns (tokens, stats)."""
     b, s = prompts.shape[0], prompts.shape[1]
     total = s + gen
-    logits, cache = tfm.prefill(params, cfg, prompts, cache_len=total)
+    logits, cache = tfm.prefill(params, cfg, prompts, cond=cond,
+                                cond_mask=cond_mask, cache_len=total)
     last = logits[:, -1]
 
     step = jax.jit(lambda c, t, p: tfm.decode_step(
@@ -106,11 +110,16 @@ def main(argv=None):
              if cfg.n_codebooks > 1 else (args.batch, args.prompt_len))
     prompts = jax.random.randint(jax.random.PRNGKey(args.seed + 1), shape,
                                  0, cfg.vocab_size)
+    cond = {}
+    if cfg.cross_attention:
+        cond = {k: jnp.asarray(v) for k, v in synthetic_conditioning(
+            args.batch, cfg.frontend.n_tokens, cfg.frontend.d_in,
+            seed=args.seed).items()}
 
     t0 = time.time()
     tokens, stats = generate(params, cfg, prompts, args.gen,
                              adaptive_threshold=args.adaptive_threshold,
-                             temperature=args.temperature)
+                             temperature=args.temperature, **cond)
     dt = time.time() - t0
     n_new = args.batch * args.gen
     print(f"arch={cfg.name} batch={args.batch} prompt={args.prompt_len} "

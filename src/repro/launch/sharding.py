@@ -159,7 +159,7 @@ def _leaf_param_spec(keys: Tuple[str, ...], shape: Tuple[int, ...],
     def ok(i, axis=m):
         return _div(mesh, body[i], axis)
 
-    in_mixer = "mixer" in keys
+    in_mixer = "mixer" in keys or "cross" in keys   # cross: same layout
     in_experts = "experts" in keys
     in_embed = "embed" in keys
 
@@ -194,8 +194,8 @@ def _leaf_param_spec(keys: Tuple[str, ...], shape: Tuple[int, ...],
         if ok(1):
             spec = (None, m, None)
     elif name == "w" and parent == "unembed":
-        if ok(1):
-            spec = (None, m)
+        if ok(len(body) - 1):                          # ([NC,] D, V)
+            spec = (None,) * (len(body) - 1) + (m,)
     elif in_experts and name in ("gate", "up"):        # (E, D, F)
         if cfg.shard_experts_2d and ok(0) and _div(mesh, body[2], "data"):
             spec = (m, None, "data")

@@ -238,6 +238,8 @@ def make_prefill_step(cfg: ModelConfig, policy: Policy = NO_POLICY, *,
     def prefill_step(params: Tree, batch: Dict[str, jax.Array]):
         logits, cache = tfm.prefill(params, cfg, batch["tokens"],
                                     extra_embeds=batch.get("extra_embeds"),
+                                    cond=batch.get("cond"),
+                                    cond_mask=batch.get("cond_mask"),
                                     policy=policy,
                                     window_override=window_override,
                                     cache_len=cache_len)

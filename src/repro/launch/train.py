@@ -32,7 +32,8 @@ from repro.configs.base import FedConfig
 from repro.core.adapters import LMAdapter, ResNetAdapter
 from repro.core.federated import FederatedTrainer, rounds_to_target
 from repro.data import federated as fed_data
-from repro.data.synthetic import synthetic_cifar, synthetic_lm
+from repro.data.synthetic import (synthetic_cifar, synthetic_conditioning,
+                                  synthetic_lm)
 from repro.obs import telemetry as obslib
 
 
@@ -89,7 +90,14 @@ def build_trainer(args, telemetry=None) -> tuple:
         test = synthetic_lm(64, args.seq_len, cfg.vocab_size,
                             seed=args.seed + 999,
                             n_codebooks=cfg.n_codebooks)
-        test_batch = {"tokens": jnp.asarray(test["tokens"])}
+        if cfg.cross_attention:
+            fe = cfg.frontend
+            data.update(synthetic_conditioning(
+                args.data_points, fe.n_tokens, fe.d_in, seed=args.seed))
+            test.update(synthetic_conditioning(
+                64, fe.n_tokens, fe.d_in, seed=args.seed + 999))
+        test_batch = {k: jnp.asarray(v) for k, v in test.items()
+                      if k != "labels"}
         adapter = LMAdapter(cfg)
 
     split = (fed_data.iid_split if fed.iid else

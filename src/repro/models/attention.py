@@ -12,6 +12,10 @@ Three execution regimes, all sharing the same parameters:
 * ``pallas`` — the sliding-window flash kernel in ``repro/kernels`` is the
   TPU target; this module is also its reference semantics.
 
+Cross-attention (musicgen) attends from the decoder stream to a fixed
+source, the projected conditioning: no causal mask, a key-padding mask
+over the source tokens, and K/V that a decode cache computes once.
+
 Shapes: hidden (B, S, D); q (B, S, H, Dh); k/v (B, S, Kh, Dh).
 """
 
@@ -221,8 +225,9 @@ def _project_qkv(p, h_in, cfg: ModelConfig, positions):
     if cfg.use_qk_norm:
         q = common.apply_rmsnorm(p["q_norm"], q, cfg.norm_eps)
         k = common.apply_rmsnorm(p["k_norm"], k, cfg.norm_eps)
-    q = common.apply_rope(q, positions, cfg.rope_theta)
-    k = common.apply_rope(k, positions, cfg.rope_theta)
+    if cfg.positions == "rope":
+        q = common.apply_rope(q, positions, cfg.rope_theta)
+        k = common.apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -341,3 +346,37 @@ def apply_attention_decode(p: dict, h_in: jax.Array, cache: dict,
     out = _merge_gqa(out)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(out.dtype))
     return out, new_cache
+
+
+# ---------------------------------------------------------------------------
+# Cross-attention (to a fixed source: the projected conditioning)
+# ---------------------------------------------------------------------------
+
+def init_cross_attention(key, cfg: ModelConfig) -> dict:
+    d, h, dh = cfg.d_model, cfg.n_heads, cfg.resolved_head_dim
+    dt = cfg.jnp_param_dtype()
+    kq, kk, kv, ko = jax.random.split(key, 4)
+    return {
+        "wq": common.dense_init(kq, (d, h, dh), dt, fan_in=d),
+        "wk": common.dense_init(kk, (d, h, dh), dt, fan_in=d),
+        "wv": common.dense_init(kv, (d, h, dh), dt, fan_in=d),
+        "wo": common.dense_init(ko, (h, dh, d), dt, fan_in=h * dh),
+    }
+
+
+def cross_kv(p: dict, src: jax.Array):
+    """K/V of the source (B, N, D) -> each (B, N, H, Dh): what a decode
+    cache holds for the whole generation."""
+    k = jnp.einsum("bnd,dhk->bnhk", src, p["wk"].astype(src.dtype))
+    v = jnp.einsum("bnd,dhk->bnhk", src, p["wv"].astype(src.dtype))
+    return k, v
+
+
+def apply_cross_attention(p: dict, h_in: jax.Array, k: jax.Array,
+                          v: jax.Array, src_mask: jax.Array) -> jax.Array:
+    """h_in (B, S, D) attends to source K/V (B, N, H, Dh); ``src_mask``
+    (B, N) bool leaves out the source's padding.  -> (B, S, D)."""
+    q = jnp.einsum("bsd,dhk->bshk", h_in, p["wq"].astype(h_in.dtype))
+    out = _attend(_split_gqa(q, k.shape[2]), k, v, src_mask[:, None, :], 0.0)
+    out = _merge_gqa(out)
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(out.dtype))

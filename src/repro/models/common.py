@@ -66,6 +66,37 @@ def apply_rmsnorm(p: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# LayerNorm (with bias) and the config's choice of norm
+# ---------------------------------------------------------------------------
+
+def init_layernorm(d: int, dtype) -> dict:
+    return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
+
+
+def apply_layernorm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
+    dtype = x.dtype
+    x = x.astype(jnp.float32)
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+    x = (x - mean) * jax.lax.rsqrt(var + eps)
+    out = x * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
+    return out.astype(dtype)
+
+
+def init_norm(cfg, d: int, dtype) -> dict:
+    """The config's norm: ``cfg.norm`` is "rms" or "layer"."""
+    if cfg.norm == "layer":
+        return init_layernorm(d, dtype)
+    return init_rmsnorm(d, dtype)
+
+
+def apply_norm(cfg, p: dict, x: jax.Array) -> jax.Array:
+    if cfg.norm == "layer":
+        return apply_layernorm(p, x, cfg.norm_eps)
+    return apply_rmsnorm(p, x, cfg.norm_eps)
+
+
+# ---------------------------------------------------------------------------
 # GroupNorm (paper footnote 1: replaces BatchNorm in all ResNets)
 # ---------------------------------------------------------------------------
 
@@ -124,6 +155,18 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def sinusoidal_embedding(positions: jax.Array, dim: int,
+                         max_period: float) -> jax.Array:
+    """Audiocraft's absolute position embedding: ``[cos(p w), sin(p w)]``
+    with ``w_i = max_period ** (-i / (dim / 2 - 1))``.  positions: (S,)
+    -> (S, dim) float32."""
+    half = dim // 2
+    freqs = max_period ** -(jnp.arange(half, dtype=jnp.float32)
+                            / (half - 1))
+    phase = positions.astype(jnp.float32)[:, None] * freqs
+    return jnp.concatenate([jnp.cos(phase), jnp.sin(phase)], axis=-1)
+
+
 # ---------------------------------------------------------------------------
 # Token embedding / unembedding
 # ---------------------------------------------------------------------------
@@ -147,15 +190,19 @@ def apply_unembedding(p: dict, h: jax.Array) -> jax.Array:
 # Losses
 # ---------------------------------------------------------------------------
 
-def softmax_cross_entropy_sum(logits: jax.Array, labels: jax.Array
+def softmax_cross_entropy_sum(logits: jax.Array, labels: jax.Array,
+                              mask: Optional[jax.Array] = None
                               ) -> jax.Array:
-    """Sum (not mean) of per-position NLL; sharding-friendly (see below)."""
+    """Sum (not mean) of per-position NLL, over the positions where
+    ``mask`` is true if given; sharding-friendly (see below)."""
     m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
     shifted = (logits - m).astype(jnp.float32)
     logz = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1)) \
         + m[..., 0].astype(jnp.float32)
     onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=logits.dtype)
     gold = jnp.sum(logits * onehot, axis=-1).astype(jnp.float32)
+    if mask is not None:
+        return jnp.sum(jnp.where(mask, logz - gold, 0.0))
     return jnp.sum(logz - gold)
 
 

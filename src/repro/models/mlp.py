@@ -40,13 +40,14 @@ def init_mlp(key, cfg: ModelConfig, d_ff: Optional[int] = None) -> dict:
     return p
 
 
-def apply_mlp(p: dict, x: jax.Array, policy: Policy = NO_POLICY) -> jax.Array:
+def apply_mlp(p: dict, x: jax.Array, policy: Policy = NO_POLICY, *,
+              gelu_exact: bool = False) -> jax.Array:
     u = jnp.einsum("...d,df->...f", x, p["up"].astype(x.dtype))
     if "gate" in p:
         g = jnp.einsum("...d,df->...f", x, p["gate"].astype(x.dtype))
-        h = jax.nn.gelu(g) * u
+        h = jax.nn.gelu(g, approximate=not gelu_exact) * u
     else:
-        h = jax.nn.gelu(u)
+        h = jax.nn.gelu(u, approximate=not gelu_exact)
     h = policy.constrain(h, ("batch", "seq", "ffn"))
     return jnp.einsum("...f,fd->...d", h, p["down"].astype(x.dtype))
 

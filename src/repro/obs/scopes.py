@@ -26,6 +26,21 @@ untagged.  The tags are metadata: the compiled program is the same,
 instruction for instruction, as without them, and they are there with
 telemetry on or off.
 
+**Parts.**  Inside a stage, :func:`part` tags the ops of one part of a
+client model with a second attribute, ``fedhen_part="<part>"``, set in
+``models/``.  The parts (:data:`PARTS`):
+
+* ``self_attn`` — an attention mixer with its pre-norm;
+* ``cross_attn`` — the cross-attention sub-block with its norm, and the
+  projection of the conditioning that feeds it;
+* ``ffn`` — the feed-forward (dense or MoE) sub-block with its norm;
+* ``heads`` — the output norm, the output heads and the loss over them.
+
+The attribute is set where the forward pass is traced; JAX's
+differentiation transposes each op inside the same metadata, so the
+backward pass's ops carry the part of the forward op they come from.
+Parts never nest, and like stages they change no instruction.
+
 **Compiles.**  :func:`compile_count` is the process's count of XLA
 backend compiles (JAX's ``/jax/core/compile/backend_compile_duration``
 monitoring event), which an enabled telemetry span reads at entry and
@@ -42,6 +57,7 @@ import jax
 from jax.experimental.xla_metadata import set_xla_metadata
 
 STAGES = ("local_sgd", "wire", "fold", "finalize")
+PARTS = ("self_attn", "cross_attn", "ffn", "heads")
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
@@ -52,6 +68,15 @@ def stage(name: str):
     if name not in STAGES:
         raise ValueError(f"unknown stage {name!r}; stages are {STAGES}")
     with jax.named_scope(name), set_xla_metadata(fedhen_scope=name):
+        yield
+
+
+@contextlib.contextmanager
+def part(name: str):
+    """Tag every op traced inside with the client-model part ``name``."""
+    if name not in PARTS:
+        raise ValueError(f"unknown part {name!r}; parts are {PARTS}")
+    with set_xla_metadata(fedhen_part=name):
         yield
 
 

@@ -478,3 +478,84 @@ def test_engine_spec_rejects_bad_combinations():
     from repro.core import comm
     with pytest.raises(ValueError, match="int8 wire requires the flat"):
         aggregate.EngineSpec(engine="tree", wire=comm.WireSpec("int8", 128))
+
+
+# ---------------------------------------------------------------------------
+# Index set M of the decoder zoo: heads the exit head uses are inside M
+# ---------------------------------------------------------------------------
+
+def test_untied_heads_fold_over_simple_clients_too():
+    """MusicGen's untied codebook heads serve the exit head, so they lie
+    in M: one round of one simple and one complex client through
+    FederatedTrainer folds the heads (and the conditioning projection,
+    and the first layer's cross-attention) as the mean over BOTH
+    clients, and everything outside M as the complex client's alone —
+    the one-shot masked mean of the clients the round trained."""
+    from repro import configs
+    from repro.configs.base import FedConfig
+    from repro.core.adapters import LMAdapter
+    from repro.core.federated import FederatedTrainer, make_client_trainer
+    from repro.data.synthetic import synthetic_conditioning, synthetic_lm
+
+    cfg = configs.get_reduced("musicgen-large").with_overrides(
+        n_layers=2, d_model=32, n_heads=2, n_kv_heads=2, head_dim=16,
+        d_ff=64, vocab_size=16)
+    adapter = LMAdapter(cfg)
+    fe = cfg.frontend
+    data = synthetic_lm(4, 8, cfg.vocab_size, seed=1,
+                        n_codebooks=cfg.n_codebooks)
+    data.update(synthetic_conditioning(4, fe.n_tokens, fe.d_in, seed=1))
+    shards = [{k: jnp.asarray(v[2 * i:2 * i + 2]) for k, v in data.items()
+               if k != "labels"} for i in range(2)]
+    fed = FedConfig(n_devices=2, n_simple=1, participation=1.0,
+                    local_epochs=1, lr=0.5, batch_size=2, seed=4,
+                    algorithm="fedhen")
+    tr = FederatedTrainer(adapter, fed, shards)
+    w0 = tr.server.complex
+    mask = adapter.subnet_mask(w0)
+    assert all(bool(m) for m in jax.tree.leaves(mask["unembed"])) and \
+        all(bool(m) for m in jax.tree.leaves(mask["frontend_proj"]))
+    cross = mask["periods"][0]["cross"]["wq"]
+    assert cross.ravel().tolist() == [True, False]
+
+    rs, rc = jax.random.split(jax.random.PRNGKey(fed.seed * 100003))
+    simple = make_client_trainer(adapter.loss_simple, fed)(
+        w0, shards[0], jax.random.fold_in(rs, 0))[0]
+    complex_ = make_client_trainer(adapter.loss_side, fed)(
+        w0, shards[1], jax.random.fold_in(rc, 0))[0]
+    moved = np.abs(np.asarray(simple["unembed"]["w"])
+                   - np.asarray(w0["unembed"]["w"])).max()
+    assert moved > 1e-4                    # the simple client trains heads
+    tr.run_round()
+    cohort = jax.tree.map(lambda a, b: jnp.stack([a, b]), simple, complex_)
+    want = aggregate.fedhen_server_update(
+        cohort, jnp.asarray([True, False]), jnp.ones(2, bool), mask)
+    jax.tree.map(lambda g, w: np.testing.assert_allclose(
+        np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-6),
+        tr.server.complex, want)
+
+
+@pytest.mark.parametrize("name", [
+    n for n in __import__("repro.configs", fromlist=["ARCH_NAMES"]).ARCH_NAMES
+    if n != "musicgen-large"])
+def test_registry_masks_unchanged(name):
+    """Every other registry config ties its output to the embedding, so M
+    is what it always was: the embedding, the frontend projector, the
+    first K periods of layers and the exit norm; never the tail layers
+    or the final norm."""
+    from repro import configs
+    from repro.core.adapters import LMAdapter
+    cfg = configs.get_reduced(name)
+    assert cfg.tie_embeddings and not cfg.cross_attention
+    params = jax.eval_shape(LMAdapter(cfg).init, jax.random.PRNGKey(0))
+    mask = masking.transformer_subnet_mask(params, cfg)
+    whole = {k for k, v in mask.items()
+             if k not in ("periods", "rem")
+             and all(bool(m) for m in jax.tree.leaves(v))}
+    assert whole == {"embed", "exit_norm"} | (
+        {"frontend_proj"} if cfg.frontend is not None else set())
+    prefix = np.arange(cfg.n_periods) < cfg.exit_period
+    for m in jax.tree.leaves(mask["periods"]):
+        assert m.ravel().tolist() == prefix.tolist()
+    assert not any(bool(m) for m in jax.tree.leaves(
+        (mask["rem"], mask["final_norm"])))

@@ -24,7 +24,14 @@ def _batch(cfg, b=2, s=16, seed=0):
     key = jax.random.PRNGKey(seed)
     batch = {}
     s_tok = s
-    if cfg.frontend is not None:
+    if cfg.cross_attention:
+        fe = cfg.frontend
+        batch["cond"] = jax.random.normal(
+            jax.random.fold_in(key, 1), (b, fe.n_tokens, fe.d_in),
+            jnp.dtype(cfg.compute_dtype))
+        batch["cond_mask"] = jnp.arange(fe.n_tokens)[None, :] < \
+            jnp.arange(1, b + 1)[:, None]
+    elif cfg.frontend is not None:
         s_tok = s - cfg.frontend.n_tokens
         batch["extra_embeds"] = jax.random.normal(
             jax.random.fold_in(key, 1),
@@ -50,8 +57,11 @@ def test_reduced_forward_and_fedhen_step(name):
     # forward shapes
     inputs = batch["tokens"][:, :-1]
     exit_h, final_h, _ = tfm.forward(params, cfg, inputs,
-                                     extra_embeds=batch.get("extra_embeds"))
-    s_total = inputs.shape[1] + (cfg.frontend.n_tokens if cfg.frontend else 0)
+                                     extra_embeds=batch.get("extra_embeds"),
+                                     cond=batch.get("cond"),
+                                     cond_mask=batch.get("cond_mask"))
+    s_total = inputs.shape[1] + (cfg.frontend.n_tokens if "extra_embeds"
+                                 in batch else 0)
     assert final_h.shape == (2, s_total, cfg.d_model)
     assert exit_h.shape == final_h.shape
     logits = tfm.logits_from_hidden(params, cfg, final_h, "final")
@@ -96,7 +106,7 @@ EXPECTED_PARAMS = {  # (low, high) bounds in billions, generous
     "llava-next-34b": (30.0, 40.0),
     "kimi-k2-1t-a32b": (950.0, 1150.0),
     "gemma3-4b": (3.0, 5.0),
-    "musicgen-large": (1.5, 2.8),
+    "musicgen-large": (3.0, 3.6),         # 3.3B with cross-attention
     "minitron-8b": (7.0, 10.0),
 }
 
@@ -110,6 +120,39 @@ def test_full_config_param_counts(name):
     # FedHeN subnet is a strict, nontrivial sub-network
     s = cfg.simple_param_count()
     assert 0 < s < cfg.param_count()
+
+
+def test_musicgen_large_counts_the_published_block():
+    """48 layers of self-attention, cross-attention (each 4 x 2048^2) and
+    a 2 x 2048 x 8192 FFN with three LayerNorms, 4 embeddings of 2049
+    rows, 4 untied heads, the 768 -> 2048 conditioning projection with
+    its bias, the final and exit LayerNorms: the published 3.3B; the
+    simple model holds 24 layers and everything outside the layers but
+    the final norm.  The built tree counts the same."""
+    cfg = configs.get_config("musicgen-large")
+    layer = 2 * 4 * 2048 ** 2 + 2 * 2048 * 8192 + 3 * 2 * 2048
+    outside = (4 * 2049 * 2048 + 4 * 2048 * 2048 + 768 * 2048 + 2048
+               + 2 * 2048)
+    assert cfg.param_count() == 48 * layer + outside + 2 * 2048 \
+        == 3_256_961_024
+    assert cfg.simple_param_count() == 24 * layer + outside
+    shapes = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes)) \
+        == cfg.param_count()
+
+
+@pytest.mark.parametrize("override", [{"tie_embeddings": True},
+                                      {"embed_scale": True}])
+def test_parallel_codebooks_refuse_the_generic_heads(override):
+    """Parallel codebooks always run MusicGen's delay pattern with untied
+    heads over unscaled embeddings; a config asking otherwise is
+    refused, not silently given another model."""
+    cfg = configs.get_reduced("musicgen-large")
+    assert cfg.n_codebooks > 1 and cfg.delay_pattern
+    assert not cfg.with_overrides(n_codebooks=1, **override).delay_pattern
+    with pytest.raises(ValueError, match="parallel codebooks"):
+        cfg.with_overrides(**override)
 
 
 def test_moe_active_params():

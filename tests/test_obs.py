@@ -361,11 +361,47 @@ def test_stage_tags_leave_the_compiled_round_unchanged(async_lag,
     assert _stripped(tagged) == _stripped(plain)
 
 
+def _musicgen_trainer():
+    from repro import configs
+    from repro.core.adapters import LMAdapter
+    from repro.data.synthetic import synthetic_conditioning, synthetic_lm
+    cfg = configs.get_reduced("musicgen-large")
+    fe = cfg.frontend
+    data = synthetic_lm(4, 8, cfg.vocab_size, n_codebooks=cfg.n_codebooks)
+    data.update(synthetic_conditioning(4, fe.n_tokens, fe.d_in))
+    shards = [{k: jnp.asarray(v[2 * i:2 * i + 2]) for k, v in data.items()
+               if k != "labels"} for i in range(2)]
+    fed = FedConfig(n_devices=2, n_simple=1, participation=1.0,
+                    local_epochs=1, lr=0.1, batch_size=2,
+                    algorithm="fedhen", seed=0)
+    return FederatedTrainer(LMAdapter(cfg), fed, shards)
+
+
+def test_part_tags_leave_the_compiled_round_unchanged(monkeypatch):
+    """With the client model's parts made no-ops, the compiled round of
+    MusicGen's block is the same instruction for instruction once
+    metadata is stripped; with them every part tags ops."""
+    import contextlib
+    import re
+    from repro.models import transformer
+    tagged = _musicgen_trainer().lower_round().compile().as_text()
+    monkeypatch.setattr(transformer, "part",
+                        lambda name: contextlib.nullcontext())
+    plain = _musicgen_trainer().lower_round().compile().as_text()
+    parts = set(re.findall(r'fedhen_part="(\w+)"', tagged))
+    assert parts == {"self_attn", "cross_attn", "ffn", "heads"}
+    assert "fedhen_part" not in plain
+    assert _stripped(tagged) == _stripped(plain)
+
+
 def test_stage_names_are_the_four():
     from repro.obs import scopes
     assert scopes.STAGES == ("local_sgd", "wire", "fold", "finalize")
     with pytest.raises(ValueError, match="unknown stage"):
         with scopes.stage("train"):
+            pass
+    with pytest.raises(ValueError, match="unknown part"):
+        with scopes.part("local_sgd"):
             pass
 
 

@@ -10,7 +10,8 @@ named after the kernel.  Nothing runs.
 A tiny round of the paper's model compiled for the same chip keeps the
 round's stage tags (``obs/scopes.py``) on the ops the TPU profiler
 reports, and its convolutions plain: one client's batch, two spatial
-window dimensions.
+window dimensions.  A tiny round of MusicGen's block keeps the tags of
+the client model's parts on forward and backward ops alike.
 
 The topology is described inside a module fixture, never while a module
 is imported: only one process at a time may load the TPU library.
@@ -185,6 +186,58 @@ def test_round_convolutions_are_plain_on_v5e(tiny_round_hlo):
                          tiny_round_hlo)
     assert windows
     assert all(len(w.split("x")) == 2 for w in windows), sorted(set(windows))
+
+
+@pytest.fixture(scope="module")
+def tiny_musicgen_round_hlo(one_chip):
+    """A tiny round of MusicGen's block (the registry's reduced config:
+    cross-attention, four codebooks in the delay pattern), two clients of
+    one step, compiled for the chip with the Pallas fold: its HLO."""
+    from repro import configs
+    from repro.configs.base import FedConfig
+    from repro.core.adapters import LMAdapter
+    from repro.core.federated import FederatedTrainer
+    from repro.data.synthetic import synthetic_conditioning, synthetic_lm
+    from repro.kernels.masked_agg import ops as agg_ops
+    cfg = configs.get_reduced("musicgen-large").with_overrides(n_codebooks=4)
+    fe = cfg.frontend
+    data = synthetic_lm(4, 16, cfg.vocab_size, n_codebooks=4)
+    data.update(synthetic_conditioning(4, fe.n_tokens, fe.d_in))
+    shards = [{k: jnp.asarray(v[2 * i:2 * i + 2]) for k, v in data.items()
+               if k != "labels"} for i in range(2)]
+    fed = FedConfig(n_devices=2, n_simple=1, participation=1.0,
+                    local_epochs=1, lr=0.1, batch_size=2,
+                    algorithm="fedhen", seed=0, cohort_chunk=0)
+    tr = FederatedTrainer(LMAdapter(cfg), fed, shards)
+    plan = tr._sample_plan()
+    args = tr._round_args(plan, tr._gather(plan.simple_ids),
+                          tr._gather(plan.complex_ids),
+                          jax.random.PRNGKey(0))
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        args)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(agg_ops, "use_pallas", lambda: True)
+        return jax.jit(tr._make_round_fn()).lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("part", ["self_attn", "cross_attn", "ffn",
+                                  "heads"])
+def test_round_part_tags_survive_the_v5e_compile(tiny_musicgen_round_hlo,
+                                                 part):
+    """In the tiny MusicGen round compiled for the chip, each part of the
+    client model tags ops of the forward pass and of the backward pass
+    (whose source op names JAX writes as ``transpose(jvp(...))``), inside
+    the ``local_sgd`` stage; no op carries two parts."""
+    hlo = tiny_musicgen_round_hlo
+    tagged = [ln for ln in hlo.splitlines()
+              if f'fedhen_part="{part}"' in ln and " = " in ln]
+    assert tagged
+    assert all('fedhen_scope="local_sgd"' in ln for ln in tagged)
+    backward = [ln for ln in tagged if "transpose(jvp(" in ln]
+    forward = [ln for ln in tagged if "jvp(" in ln and ln not in backward]
+    assert backward and forward, (len(backward), len(forward))
+    assert not re.search(r'fedhen_part="\w+"[^\n]*fedhen_part=', hlo)
 
 
 def test_chip_smoke_refuses_cpu(capsys, monkeypatch):
