@@ -1,25 +1,30 @@
-"""Jit'd wrapper for the flash attention kernel with backend dispatch.
+"""Backend dispatch for the causal flash attention kernel.
 
-On TPU the Pallas kernel runs; elsewhere the XLA chunked implementation
-(models/attention.py) serves the same contract.  ``interpret=True``
-exercises the kernel body on CPU (tests / debugging).
+``models/attention.py`` asks :func:`use_pallas` and :func:`block_for`
+whether the kernel takes a train/prefill call and calls
+:func:`flash_attention` (re-exported from ``kernel.py``) when it does;
+every other call keeps the XLA path there.  The kernel runs on TPU and
+nowhere else: on another backend ``use_pallas`` is false, and tests
+exercise the kernel body with ``interpret=True``.
 """
 
 from __future__ import annotations
 
 import jax
 
-from repro.kernels.flash_attention.kernel import flash_attention_pallas
-from repro.models.attention import chunked_causal_attention
+from repro.kernels.flash_attention.kernel import flash_attention
+
+__all__ = ["block_for", "flash_attention", "use_pallas"]
+
+BLOCKS = (512, 256, 128)   # largest first
 
 
-def flash_attention(q, k, v, *, window: int = 0, softcap: float = 0.0,
-                    force_pallas_interpret: bool = False):
-    if force_pallas_interpret:
-        return flash_attention_pallas(q, k, v, window=window,
-                                      softcap=softcap, interpret=True)
-    if jax.default_backend() == "tpu":
-        return flash_attention_pallas(q, k, v, window=window,
-                                      softcap=softcap)
-    return chunked_causal_attention(q, k, v, window=window,
-                                    softcap_val=softcap)
+def use_pallas() -> bool:
+    """True when the Pallas kernel (not the XLA path) should run."""
+    return jax.default_backend() == "tpu"
+
+
+def block_for(s: int) -> int:
+    """The q- and k-block for sequence length ``s``: the largest of
+    :data:`BLOCKS` that divides it, or 0 when none does."""
+    return next((b for b in BLOCKS if s % b == 0), 0)

@@ -1,8 +1,9 @@
-"""Pure-jnp oracle for the sliding-window flash attention kernel.
+"""Pure-jnp oracle for the causal flash attention kernel.
 
 Semantics identical to ``models/attention.chunked_causal_attention`` but
-restated independently (naive O(S^2) masked softmax) so the kernel test has
-an oracle that shares no code with either implementation.
+restated independently (naive O(S^2) masked softmax in f32) so the kernel
+test has an oracle that shares no code with either implementation.  Heads
+first, like the kernel.
 """
 
 from __future__ import annotations
@@ -11,24 +12,18 @@ import jax
 import jax.numpy as jnp
 
 
-def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                        window: int = 0,
-                        softcap: float = 0.0) -> jax.Array:
-    """q: (B, S, H, Dh); k/v: (B, S, Kh, Dh); causal (+ window) -> like q."""
-    b, s, h, dh = q.shape
-    kh = k.shape[2]
-    g = h // kh
-    qg = q.reshape(b, s, kh, g, dh).astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, kf) * dh ** -0.5
-    if softcap:
-        logits = jnp.tanh(logits / softcap) * softcap
+def flash_attention_ref(q: jax.Array, k: jax.Array,
+                        v: jax.Array) -> jax.Array:
+    """q: (B, H, S, Dh); k/v: (B, Kh, S, Dh); causal -> like q."""
+    b, h, s, dh = q.shape
+    kh = k.shape[1]
+    qg = q.reshape(b, kh, h // kh, s, dh).astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    logits = jnp.einsum("bkgqd,bksd->bkgqs", qg, k.astype(jnp.float32),
+                        precision=hi) * dh ** -0.5
     pos = jnp.arange(s)
-    mask = pos[:, None] >= pos[None, :]
-    if window:
-        mask &= (pos[:, None] - pos[None, :]) < window
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
+    logits = jnp.where(pos[:, None] >= pos[None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, vf)
-    return out.reshape(b, s, h, dh).astype(q.dtype)
+    out = jnp.einsum("bkgqs,bksd->bkgqd", probs, v.astype(jnp.float32),
+                     precision=hi)
+    return out.reshape(b, h, s, dh).astype(q.dtype)

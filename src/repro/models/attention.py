@@ -1,22 +1,28 @@
 """GQA attention: global/sliding-window, RoPE, softcap, KV caches, decode.
 
-Three execution regimes, all sharing the same parameters:
+Two execution regimes, sharing the same parameters:
 
-* ``train/prefill`` — chunked causal attention.  Queries are processed in
-  chunks of ``q_chunk`` via ``lax.scan`` so the score matrix is
-  O(chunk x keys) rather than O(S^2) memory.  Local layers slice only the
-  ``chunk + window`` keys they can see, so their FLOPs are O(S * window).
+* ``train/prefill`` — causal attention over the whole sequence.  On a TPU
+  a call the flash kernel takes (``kernels/flash_attention``: global
+  causal, no softcap, S a multiple of 128, no mesh) runs it: q/k/v are
+  projected straight into its heads-first (B, H, S, Dh) layout, no score
+  tile leaves VMEM forward or backward, and blocks above the diagonal are
+  skipped.  Every other call runs chunked causal attention in XLA:
+  queries in chunks of ``q_chunk`` via ``lax.scan``, so the score matrix
+  is O(chunk x keys) rather than O(S^2) memory; local layers slice only
+  the ``chunk + window`` keys they can see, so their FLOPs are
+  O(S * window).  Under a ``seq2d`` policy, ``chunk2d_attention`` shards
+  the query chunks over the mesh.
 * ``decode`` — one query token against a KV cache.  Local layers keep a
   ring-buffer cache of size ``window`` (RoPE is applied at write time, so
   ring rotation is harmless); global layers keep the full ``S`` cache.
-* ``pallas`` — the sliding-window flash kernel in ``repro/kernels`` is the
-  TPU target; this module is also its reference semantics.
 
 Cross-attention (musicgen) attends from the decoder stream to a fixed
 source, the projected conditioning: no causal mask, a key-padding mask
 over the source tokens, and K/V that a decode cache computes once.
 
-Shapes: hidden (B, S, D); q (B, S, H, Dh); k/v (B, S, Kh, Dh).
+Shapes: hidden (B, S, D); q (B, S, H, Dh); k/v (B, S, Kh, Dh) — heads
+first, (B, H, S, Dh), on the flash kernel's path.
 """
 
 from __future__ import annotations
@@ -28,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.flash_attention import ops as flash_ops
 from repro.models import common
 from repro.models.common import Policy, NO_POLICY
 
@@ -218,17 +225,34 @@ def chunk2d_attention(q, k, v, *, window: int = 0, softcap_val: float = 0.0,
 # Full layer application
 # ---------------------------------------------------------------------------
 
-def _project_qkv(p, h_in, cfg: ModelConfig, positions):
-    q = jnp.einsum("bsd,dhk->bshk", h_in, p["wq"].astype(h_in.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h_in, p["wk"].astype(h_in.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h_in, p["wv"].astype(h_in.dtype))
+def _project_qkv(p, h_in, cfg: ModelConfig, positions,
+                 heads_first: bool = False):
+    """q (B, S, H, Dh), k/v (B, S, Kh, Dh); (B, H|Kh, S, Dh) when
+    ``heads_first``."""
+    spec = "bsd,dhk->bhsk" if heads_first else "bsd,dhk->bshk"
+    q = jnp.einsum(spec, h_in, p["wq"].astype(h_in.dtype))
+    k = jnp.einsum(spec, h_in, p["wk"].astype(h_in.dtype))
+    v = jnp.einsum(spec, h_in, p["wv"].astype(h_in.dtype))
     if cfg.use_qk_norm:
         q = common.apply_rmsnorm(p["q_norm"], q, cfg.norm_eps)
         k = common.apply_rmsnorm(p["k_norm"], k, cfg.norm_eps)
     if cfg.positions == "rope":
-        q = common.apply_rope(q, positions, cfg.rope_theta)
-        k = common.apply_rope(k, positions, cfg.rope_theta)
+        seq_axis = 2 if heads_first else 1
+        q = common.apply_rope(q, positions, cfg.rope_theta, seq_axis=seq_axis)
+        k = common.apply_rope(k, positions, cfg.rope_theta, seq_axis=seq_axis)
     return q, k, v
+
+
+def _flash_block(s: int, window: int, softcap_val: float,
+                 policy: Policy) -> int:
+    """The flash kernel's block for a train/prefill call of length ``s``,
+    or 0 when the call keeps the XLA path: off a TPU, under a sliding
+    window or a softcap (the kernel has neither), on a mesh (SPMD does not
+    partition a Pallas call), or where no block divides ``s``."""
+    if (window or softcap_val or getattr(policy, "mesh", None) is not None
+            or not flash_ops.use_pallas()):
+        return 0
+    return flash_ops.block_for(s)
 
 
 def apply_attention(p: dict, h_in: jax.Array, cfg: ModelConfig, *,
@@ -243,6 +267,14 @@ def apply_attention(p: dict, h_in: jax.Array, cfg: ModelConfig, *,
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.int32)
     h_in = policy.constrain(h_in, ("batch", "seq", None))
+    block = _flash_block(s, window, cfg.attn_logit_softcap, policy)
+    if block:
+        q, k, v = _project_qkv(p, h_in, cfg, positions, heads_first=True)
+        out = flash_ops.flash_attention(q, k, v, block=block)
+        out = jnp.einsum("bhsk,hkd->bsd", out, p["wo"].astype(out.dtype))
+        if return_kv:
+            return out, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        return out
     q, k, v = _project_qkv(p, h_in, cfg, positions)
     if getattr(policy, "seq2d", False):
         # 2D token sharding: q-chunks sharded over `model`; k/v consumed

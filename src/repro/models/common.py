@@ -141,15 +141,18 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** exponent)                       # (head_dim // 2,)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, N, Dh); positions: (B, S) or (S,) int32."""
-    b, s, n, dh = x.shape
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float, *,
+               seq_axis: int = 1) -> jax.Array:
+    """x: (B, S, N, Dh), or (B, N, S, Dh) with ``seq_axis`` 2;
+    positions: (B, S) or (S,) int32."""
+    dh = x.shape[-1]
     freqs = rope_frequencies(dh, theta)                    # (dh/2,)
     if positions.ndim == 1:
         positions = positions[None, :]
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, dh/2)
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
+    heads_axis = 3 - seq_axis
+    cos = jnp.expand_dims(jnp.cos(angles), heads_axis)
+    sin = jnp.expand_dims(jnp.sin(angles), heads_axis)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -11,7 +11,8 @@ A tiny round of the paper's model compiled for the same chip keeps the
 round's stage tags (``obs/scopes.py``) on the ops the TPU profiler
 reports, and its convolutions plain: one client's batch, two spatial
 window dimensions.  A tiny round of MusicGen's block keeps the tags of
-the client model's parts on forward and backward ops alike.
+the client model's parts on forward and backward ops alike, and at a
+sequence length the flash attention kernel takes, runs it, tagged.
 
 The topology is described inside a module fixture, never while a module
 is imported: only one process at a time may load the TPU library.
@@ -188,20 +189,22 @@ def test_round_convolutions_are_plain_on_v5e(tiny_round_hlo):
     assert all(len(w.split("x")) == 2 for w in windows), sorted(set(windows))
 
 
-@pytest.fixture(scope="module")
-def tiny_musicgen_round_hlo(one_chip):
+def _musicgen_round_hlo(sharding, seq_len: int) -> str:
     """A tiny round of MusicGen's block (the registry's reduced config:
     cross-attention, four codebooks in the delay pattern), two clients of
-    one step, compiled for the chip with the Pallas fold: its HLO."""
+    one step at ``seq_len`` steps, compiled for the chip with the Pallas
+    fold and, where it takes the call, the flash attention kernel: its
+    HLO."""
     from repro import configs
     from repro.configs.base import FedConfig
     from repro.core.adapters import LMAdapter
     from repro.core.federated import FederatedTrainer
     from repro.data.synthetic import synthetic_conditioning, synthetic_lm
+    from repro.kernels.flash_attention import ops as flash_ops
     from repro.kernels.masked_agg import ops as agg_ops
     cfg = configs.get_reduced("musicgen-large").with_overrides(n_codebooks=4)
     fe = cfg.frontend
-    data = synthetic_lm(4, 16, cfg.vocab_size, n_codebooks=4)
+    data = synthetic_lm(4, seq_len, cfg.vocab_size, n_codebooks=4)
     data.update(synthetic_conditioning(4, fe.n_tokens, fe.d_in))
     shards = [{k: jnp.asarray(v[2 * i:2 * i + 2]) for k, v in data.items()
                if k != "labels"} for i in range(2)]
@@ -214,11 +217,24 @@ def tiny_musicgen_round_hlo(one_chip):
                           tr._gather(plan.complex_ids),
                           jax.random.PRNGKey(0))
     shapes = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
         args)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(agg_ops, "use_pallas", lambda: True)
+        mp.setattr(flash_ops, "use_pallas", lambda: True)
         return jax.jit(tr._make_round_fn()).lower(*shapes).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def tiny_musicgen_round_hlo(one_chip):
+    """16 steps: no flash block divides it, so self-attention is XLA's."""
+    return _musicgen_round_hlo(one_chip, 16)
+
+
+@pytest.fixture(scope="module")
+def tiny_musicgen_flash_round_hlo(one_chip):
+    """256 steps: self-attention runs the flash kernel."""
+    return _musicgen_round_hlo(one_chip, 256)
 
 
 @pytest.mark.parametrize("part", ["self_attn", "cross_attn", "ffn",
@@ -238,6 +254,30 @@ def test_round_part_tags_survive_the_v5e_compile(tiny_musicgen_round_hlo,
     forward = [ln for ln in tagged if "jvp(" in ln and ln not in backward]
     assert backward and forward, (len(backward), len(forward))
     assert not re.search(r'fedhen_part="\w+"[^\n]*fedhen_part=', hlo)
+
+
+@pytest.mark.parametrize("kernel", ["flash_attn_fwd", "flash_attn_dkv",
+                                    "flash_attn_dq"])
+def test_flash_attention_in_the_v5e_round(tiny_musicgen_flash_round_hlo,
+                                          kernel):
+    """At a sequence length the kernel takes, the round compiled for the
+    chip runs self-attention through the three flash kernels, and each
+    of them (forward, and the backward ones the custom VJP adds) carries
+    the ``self_attn`` part inside the ``local_sgd`` stage, so the part's
+    device time counts them.  No op of the part has an S x S result (the
+    XLA path's score chunks, masks and probabilities)."""
+    hlo = tiny_musicgen_flash_round_hlo
+    calls = [ln for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and re.match(rf"\s*(ROOT )?%[\w.]*{kernel}", ln)]
+    assert calls
+    assert all('fedhen_part="self_attn"' in ln
+               and 'fedhen_scope="local_sgd"' in ln for ln in calls)
+    results = [re.match(r"\s*(?:ROOT )?%\S+ = (\([^)]*\)|\S+)", ln)
+               for ln in hlo.splitlines()
+               if 'fedhen_part="self_attn"' in ln and " = " in ln]
+    assert not [m.group(1) for m in results
+                if m and "256,256]" in m.group(1)]
 
 
 def test_chip_smoke_refuses_cpu(capsys, monkeypatch):
