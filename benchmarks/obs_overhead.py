@@ -86,7 +86,7 @@ def raw_round(tr: FederatedTrainer) -> Dict[str, float]:
     # no SCAFFOLD and no error feedback here: their outputs are None
     new_complex, new_simple_host, metrics, _, _ = tr._round_fn(
         tr.server.complex, tr.server.simple_host, data_s, data_c, key,
-        tr._flat_mask_arg())
+        tr.flat_mask)
     tr.server = ServerState(complex=new_complex,
                             simple_host=new_simple_host,
                             round=tr.server.round + 1)

@@ -126,19 +126,17 @@ def bench_aggsrv():
 
 
 def bench_streamscale():
-    """Cohort x chunk x engine sweep: the streaming engine's memory/latency
-    story plus the flat-vs-tree fold comparison."""
+    """Cohort x chunk sweep: the streaming engine's memory/latency
+    story."""
     from benchmarks.streaming_cohort import sweep
     rounds = 1 if os.environ.get("BENCH_FAST") else 3
     for r in sweep(timed_rounds=rounds):
-        derived = (f"k={r['k']};chunk={r['chunk']};engine={r['engine']};"
+        derived = (f"k={r['k']};chunk={r['chunk']};"
                    f"temp_mib={r['temp_bytes'] / 2**20:.2f};"
                    f"fold_kib={r['fold_temp_bytes'] / 2**10:.0f};"
                    f"reduces={r['hlo_reduce_ops']}")
-        for key in ("fits_under_seed_peak", "flat_fits_under_tree",
-                    "flat_fewer_reduces"):
-            if key in r:
-                derived += f";{key}={r[key]}"
+        if "fits_under_seed_peak" in r:
+            derived += f";fits_under_seed_peak={r['fits_under_seed_peak']}"
         _row(f"streamscale_{r['label']}", r["us_per_round"], derived)
 
 

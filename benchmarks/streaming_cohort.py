@@ -1,32 +1,16 @@
-"""Streaming cohort engine: cohort size x chunk size x engine sweep.
+"""Streaming cohort engine: cohort size x chunk size sweep.
 
-Measures, for each (cohort k, cohort_chunk, agg_engine) point, the
-compiled round's peak temp memory (``memory_analysis().temp_size_in_bytes``
-of the AOT round — XLA's scheduled scratch high-water mark, the quantity
-the streaming engine bounds), the HLO op / reduce counts (the flat engine
-collapses one masked-agg reduction per leaf into ONE per fold), and the
-wall-clock round latency.
+Measures, for each (cohort k, cohort_chunk) point, the compiled round's
+peak temp memory (``memory_analysis().temp_size_in_bytes`` of the AOT
+round — XLA's scheduled scratch high-water mark, the quantity the
+streaming engine bounds), the HLO op / reduce counts, the same for ONE
+fold lowered alone (``fold_temp_bytes`` / ``fold_reduce_ops`` — what the
+fold owns, isolated from training scratch), and the wall-clock round
+latency.
 
-Headline rows:
-
-* ``k40_chunk5`` — a cohort 4x the seed default (k=40 vs k=10) streamed
-  with ``cohort_chunk=5`` must fit under the one-shot k=10 round's peak
-  temp memory (ISSUE 2 acceptance).
-* ``k40_chunk5`` (flat) vs ``k40_chunk5_tree`` — the flat-buffer fold must
-  use no more temp memory than the per-leaf tree fold
-  (``fold_temp_bytes``, the aggregation program lowered alone — flat
-  compiles to ZERO scratch on CPU, in-place accumulation, vs the tree
-  fold's per-leaf temps) and the compiled round must carry fewer reduce
-  ops (ISSUE 3 acceptance).
-
-Round-level ``temp_bytes`` is reported for both engines too.  Note its
-flat-vs-tree delta on CPU is allocator noise, not engine cost: the round
-arena is dominated by identical client-training scratch, and XLA's buffer
-assignment tucks the tree engine's 28 small accumulators into arena holes
-where the flat engine's one contiguous accumulator cannot go (measured
-+0.46% here; the fold-scoped numbers above isolate what the engine owns,
-and on TPU the ``input_output_aliases`` accumulator removes the second
-copy entirely).
+Headline row: ``k40_chunk5`` — a cohort 4x the seed default (k=40 vs
+k=10) streamed with ``cohort_chunk=5`` must fit under the one-shot k=10
+round's peak temp memory.
 
 Run as a script to emit ``BENCH_streaming.json`` and exit nonzero on a
 regression (the CI smoke): ``python benchmarks/streaming_cohort.py --fast``.
@@ -53,25 +37,24 @@ STREAM_CFG = ModelConfig(n_layers=4, d_model=64, n_heads=4, n_kv_heads=2,
                          pattern=(LayerSpec("attn"),), exit_layer=2,
                          compute_dtype="float32")
 
-# (label, total clients, cohort_chunk, agg_engine); participation 0.5 ->
-# k = clients/2.  k=10 matches the seed FedConfig default cohort
-# (100 devices x 10%).
-SWEEP: Tuple[Tuple[str, int, int, str], ...] = (
-    ("k10_chunk0", 20, 0, "flat"),      # seed-default cohort, one-shot
-    ("k10_chunk5", 20, 5, "flat"),
-    ("k40_chunk0", 80, 0, "flat"),      # 4x cohort, one-shot: memory blow-up
-    ("k40_chunk10", 80, 10, "flat"),
-    ("k40_chunk5", 80, 5, "flat"),      # 4x cohort streamed: acceptance row
-    ("k40_chunk5_tree", 80, 5, "tree"),  # per-leaf fold: the flat-vs-tree row
+# (label, total clients, cohort_chunk); participation 0.5 -> k =
+# clients/2.  k=10 matches the seed FedConfig default cohort (100 devices
+# x 10%).
+SWEEP: Tuple[Tuple[str, int, int], ...] = (
+    ("k10_chunk0", 20, 0),      # seed-default cohort, one-shot
+    ("k10_chunk5", 20, 5),
+    ("k40_chunk0", 80, 0),      # 4x cohort, one-shot: memory blow-up
+    ("k40_chunk10", 80, 10),
+    ("k40_chunk5", 80, 5),      # 4x cohort streamed: acceptance row
 )
 
 
-def build_trainer(n_devices: int, chunk: int, *, engine: str = "flat",
+def build_trainer(n_devices: int, chunk: int, *,
                   timed_rounds: int) -> FederatedTrainer:
     fed = FedConfig(n_devices=n_devices, n_simple=n_devices // 2,
                     participation=0.5, rounds=timed_rounds, local_epochs=1,
                     lr=0.1, batch_size=8, algorithm="fedhen", seed=0,
-                    cohort_chunk=chunk, agg_engine=engine)
+                    cohort_chunk=chunk)
     data = synthetic_lm(n_devices * 16, 32, STREAM_CFG.vocab_size, seed=1)
     shards = iid_split(data, fed.n_devices, seed=2)
     shards = [{"tokens": jnp.asarray(s["tokens"])} for s in shards]
@@ -82,39 +65,31 @@ def measure_fold(trainer, z: int) -> Dict:
     """Lower ONE aggregation fold (z stacked clients) by itself: the temp
     bytes and op counts the engine owns, isolated from training scratch."""
     from repro.core import aggregate
-    engine = trainer.fed.agg_engine
     template = trainer.server.complex
-    mask = trainer.mask
     chunk = jax.tree.map(
         lambda x: jnp.broadcast_to(x[None], (z,) + x.shape), template)
     is_simple = jnp.zeros(z, bool)
     valid = jnp.ones(z, bool)
+    spec = aggregate.EngineSpec(algorithm="fedhen", mask=trainer.mask,
+                                layout=trainer.layout,
+                                block_n=trainer.fed.agg_block_n)
 
-    def bind(flat_mask):
+    def fold(state, chunk, is_simple, valid, flat_mask):
         """flat_mask enters as a traced argument (mirroring the round jit)"""
-        return aggregate.make_engine(
-            engine, algorithm="fedhen", mask=mask,
-            layout=trainer.layout if engine == "flat" else None,
-            flat_mask=flat_mask, block_n=trainer.fed.agg_block_n)
+        return aggregate.streaming_fold(state, chunk, is_simple, valid,
+                                        spec.bind(flat_mask=flat_mask))
 
-    state = bind(None)[0](template)
-    if engine == "flat":
-        fold = lambda s, c, i, v, fm: bind(fm)[1](s, c, i, v)
-        args = (state, chunk, is_simple, valid, trainer.flat_mask)
-    else:
-        fold = lambda s, c, i, v: bind(None)[1](s, c, i, v)
-        args = (state, chunk, is_simple, valid)
-    compiled = jax.jit(fold).lower(*args).compile()
+    state = aggregate.streaming_init(template, spec)
+    compiled = jax.jit(fold).lower(state, chunk, is_simple, valid,
+                                   trainer.flat_mask).compile()
     hlo = compiled.as_text()
     return {"fold_temp_bytes":
             int(compiled.memory_analysis().temp_size_in_bytes),
             "fold_reduce_ops": hlo.count(" reduce(")}
 
 
-def measure(n_devices: int, chunk: int, *, engine: str = "flat",
-            timed_rounds: int = 3) -> Dict:
-    trainer = build_trainer(n_devices, chunk, engine=engine,
-                            timed_rounds=timed_rounds)
+def measure(n_devices: int, chunk: int, *, timed_rounds: int = 3) -> Dict:
+    trainer = build_trainer(n_devices, chunk, timed_rounds=timed_rounds)
     compiled = trainer.lower_round().compile()
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
@@ -124,7 +99,6 @@ def measure(n_devices: int, chunk: int, *, engine: str = "flat",
         trainer.run_round()
     us = (time.time() - t0) / timed_rounds * 1e6
     row = {"k": trainer.k_simple + trainer.k_complex, "chunk": chunk,
-           "engine": engine,
            "us_per_round": us,
            "temp_bytes": int(mem.temp_size_in_bytes),
            "arg_bytes": int(mem.argument_size_in_bytes),
@@ -138,9 +112,8 @@ def measure(n_devices: int, chunk: int, *, engine: str = "flat",
 
 def sweep(timed_rounds: int = 3) -> List[Dict]:
     rows = []
-    for label, n_devices, chunk, engine in SWEEP:
-        r = measure(n_devices, chunk, engine=engine,
-                    timed_rounds=timed_rounds)
+    for label, n_devices, chunk in SWEEP:
+        r = measure(n_devices, chunk, timed_rounds=timed_rounds)
         r["label"] = label
         rows.append(r)
     by = {r["label"]: r for r in rows}
@@ -154,12 +127,6 @@ def sweep(timed_rounds: int = 3) -> List[Dict]:
     # the two without making CI track XLA's buffer assignment exactly
     flat["stream_memory_ok"] = (
         flat["temp_bytes"] <= 1.5 * by["k10_chunk0"]["temp_bytes"])
-    # the PR 3 acceptance comparison: flat fold vs per-leaf tree fold
-    tree = by["k40_chunk5_tree"]
-    flat["flat_fits_under_tree"] = (flat["fold_temp_bytes"]
-                                    <= tree["fold_temp_bytes"])
-    flat["flat_fewer_reduces"] = (flat["hlo_reduce_ops"]
-                                  < tree["hlo_reduce_ops"])
     return rows
 
 
@@ -187,13 +154,11 @@ def main(argv=None) -> int:
         print(f"{r['label']:>16}: {r['us_per_round']:.0f} us/round, "
               f"round temp {r['temp_bytes'] / 2**20:.2f} MiB, "
               f"fold temp {r['fold_temp_bytes'] / 2**10:.0f} KiB, "
-              f"{r['hlo_reduce_ops']} reduce ops ({r['engine']})")
+              f"{r['hlo_reduce_ops']} reduce ops")
 
     flat = next(r for r in rows if r["label"] == "k40_chunk5")
-    failures = [k for k in ("stream_memory_ok", "flat_fits_under_tree",
-                            "flat_fewer_reduces") if not flat[k]]
-    if failures:
-        print(f"REGRESSION: {failures} (see {args.out})")
+    if not flat["stream_memory_ok"]:
+        print(f"REGRESSION: ['stream_memory_ok'] (see {args.out})")
         return 1
     print(f"ok — wrote {args.out}")
     return 0

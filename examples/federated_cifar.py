@@ -23,5 +23,5 @@ if __name__ == "__main__":
           "--rounds", rounds, "--clients", "8", "--participation", "0.25",
           "--local-epochs", "1", "--batch-size", "32",
           "--data-points", "1024", "--non-iid", "--eval-every", "1",
-          "--cohort-chunk", "2", "--agg-engine", "flat",
-          "--comm-dtype", "float32", "--async-lag", "0"])
+          "--cohort-chunk", "2", "--comm-dtype", "float32",
+          "--async-lag", "0"])

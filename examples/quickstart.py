@@ -34,8 +34,7 @@ TARGET = 0.15   # held-out token accuracy (chain optimum ~0.75)
 # the paper-accounting f32 wire, fully synchronous rounds.  Try
 # comm_dtype="int8" for ~3.9x smaller payloads, or async_lag=1 to let
 # the first chunk overlap the previous round's server fold.
-ENGINE = dict(cohort_chunk=2, agg_engine="flat", comm_dtype="float32",
-              async_lag=0)
+ENGINE = dict(cohort_chunk=2, comm_dtype="float32", async_lag=0)
 
 
 def run(algorithm: str, rounds: int = ROUNDS, telemetry=None):

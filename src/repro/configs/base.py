@@ -376,11 +376,6 @@ class FedConfig:
     # whose size the chunk does not divide are padded with zero-validity
     # clients, so the aggregate is unchanged (see core/federated.py).
     cohort_chunk: Union[int, str] = 0
-    # Aggregation engine: "flat" packs each trained chunk into one
-    # contiguous (Z, n_flat) buffer (core/flatten.py) and folds it with a
-    # single in-place-accumulating masked_agg launch; "tree" is the
-    # per-leaf PR 2 engine (parity reference, one launch per leaf).
-    agg_engine: str = "flat"
     # masked_agg kernel lane-tile width (multiple of 128) — the ROADMAP
     # block-size sweep knob; the flat layout's total length is rounded up
     # to it so the fold needs no call-time padding.
@@ -460,8 +455,6 @@ class FedConfig:
         if self.algorithm not in ALGORITHMS:
             raise ValueError(f"unknown algorithm {self.algorithm!r} "
                              f"(expected one of {ALGORITHMS})")
-        if self.agg_engine not in ("flat", "tree"):
-            raise ValueError(f"unknown agg_engine {self.agg_engine!r}")
         if self.agg_block_n <= 0 or self.agg_block_n % 128:
             raise ValueError("agg_block_n must be a positive multiple of 128")
         if self.agg_stream_dtype not in ("float32", "bfloat16"):
@@ -474,18 +467,10 @@ class FedConfig:
         # dtype set, quant_block | lane-alignment rule and the v2 knob
         # rules: topk_frac range, stochastic-on-f32, EF-on-lossless)
         from repro.core.comm import WireSpec
-        spec = WireSpec(self.comm_dtype, self.quant_block,
-                        topk_frac=self.topk_frac,
-                        stochastic=self.stochastic_rounding,
-                        error_feedback=self.error_feedback)
-        if self.comm_dtype == "int8" and self.agg_engine != "flat":
-            raise ValueError("comm_dtype=int8 requires agg_engine='flat' "
-                             "(the dequantizing fold is a flat-buffer op)")
-        if spec.uses_deltas and self.agg_engine != "flat":
-            raise ValueError("compressed uploads (topk_frac < 1, "
-                             "stochastic_rounding or error_feedback) require "
-                             "agg_engine='flat' (the delta fold is a "
-                             "flat-buffer op)")
+        WireSpec(self.comm_dtype, self.quant_block,
+                 topk_frac=self.topk_frac,
+                 stochastic=self.stochastic_rounding,
+                 error_feedback=self.error_feedback)
         if self.async_lag < 0:
             raise ValueError("async_lag must be >= 0 (folds of broadcast "
                              f"staleness), got {self.async_lag}")

@@ -5,36 +5,31 @@ treedef with a leading cohort axis ``Z``.  Simple clients' complex-only
 slices are carried untouched (they are weighted out by the masks), so one
 stacked representation serves every algorithm.
 
-Three entry points:
+Two entry points:
 
 * One-shot (``fedhen_server_update`` / ``decouple_server_update``): the
   whole cohort is stacked and reduced at once.  Reference semantics — the
-  parity oracle every streaming engine is tested against.
-* Flat streaming (``streaming_init`` / ``streaming_fold`` /
-  ``streaming_finalize``) — THE production fold.  ``StreamState`` carries
-  one flat f32 accumulator vector (plus one more for decouple): each
-  trained chunk is packed into a single contiguous ``(Z, n_flat)`` buffer
-  by the trainer's static ``core.flatten.FlatLayout`` and folded with ONE
-  accumulating ``masked_agg`` launch (``input_output_aliases`` updates the
-  running sum in place on TPU), against one precomputed flat mask
-  bitvector.  Chunks may stream in bf16; accumulation is always f32.
-  Under a wire format (``core/comm.py``) the fold consumes the *encoded
-  uploads* — int8 payloads fold through the dequantizing accumulate
-  variant, never materializing an f32 copy of the chunk.
-  Unpacking back to the parameter tree happens once, at finalize.
+  parity oracle the streaming fold is tested against.
+* Streaming (``streaming_init`` / ``streaming_fold`` /
+  ``streaming_finalize``, bound together by ``make_engine``) — THE
+  production fold.  ``StreamState`` carries one flat f32 accumulator
+  vector (plus one more for decouple): each trained chunk is packed into
+  a single contiguous ``(Z, n_flat)`` buffer by the trainer's static
+  ``core.flatten.FlatLayout`` and folded with ONE accumulating
+  ``masked_agg_acc`` launch (``input_output_aliases`` updates the running
+  sum in place on TPU), against one precomputed flat mask bitvector.
+  Chunks may stream in bf16; accumulation is always f32.  Under a wire
+  format (``core/comm.py``) the fold consumes the *encoded uploads* —
+  int8 payloads fold through the dequantizing accumulate variant, never
+  materializing an f32 copy of the chunk.  Unpacking back to the
+  parameter tree happens once, at finalize.
 
   **Flat layout contract**: the layout's offsets are static per (treedef,
   leaf shapes, align, block_n) — built once per trainer and valid for
-  every round.  Per-element results match the tree path exactly up to
-  float summation order across kernel tile boundaries (the cohort axis is
-  reduced in the same order per lane).
-* Tree streaming (``tree_streaming_init`` / ``tree_streaming_fold`` /
-  ``tree_streaming_finalize``): the PR 2 per-leaf engine (one
-  ``masked_agg`` launch per leaf), kept as the streaming parity reference
-  and selectable via ``FedConfig.agg_engine="tree"``.
+  every round.
 
-Both streaming engines fold chunks into running *unnormalized* masked sums
-plus two scalar weight totals; the division happens once at finalize, so
+The fold accumulates chunks into running *unnormalized* masked sums plus
+two scalar weight totals; the division happens once at finalize, so
 server memory is O(chunk) and the result matches the one-shot path up to
 float summation order.
 
@@ -49,8 +44,8 @@ weight of 0 gates the client's values before the multiply on every path
 weights are bit-identical to bool validity.
 
 The hot path — a weighted masked sum over the cohort axis — is exactly the
-``masked_agg`` Pallas kernel's contract; the folds dispatch to it on TPU
-via ``kernels/masked_agg/ops.py``, with the XLA reference as the CPU
+``masked_agg`` kernels' contract; the fold dispatches to them on TPU via
+``kernels/masked_agg/ops.py``, with the XLA references as the CPU
 fallback (what the dry-run lowers, since Pallas cannot lower on the CPU
 backend).
 """
@@ -59,7 +54,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import warnings
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -74,33 +68,23 @@ ALGORITHMS = ("fedhen", "noside", "decouple")
 
 
 # ---------------------------------------------------------------------------
-# EngineSpec: the one object a fold engine is configured by
+# EngineSpec: the one object the fold is configured by
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class EngineSpec:
-    """Everything a fold engine needs, in one frozen value.
+    """Everything the fold needs, in one frozen value.
 
-    The engine kwargs used to thread loose through ``make_engine`` /
-    ``streaming_{init,fold,finalize}`` / ``launch/steps.py`` — seven
-    arguments per call site, drifting independently.  An ``EngineSpec``
-    is built ONCE (``from_config`` next to the ``FedConfig`` that owns
-    the knobs) and handed whole to every seam; trace-time values the
-    config cannot know (the mask tree, the trainer's layout, a
-    ``flat_mask`` that is a round *argument*) are attached with
-    :meth:`bind`.
+    Built ONCE (``from_config`` next to the ``FedConfig`` that owns the
+    knobs) and handed whole to every seam; trace-time values the config
+    cannot know (the mask tree, the trainer's layout, a ``flat_mask``
+    that is a round *argument*) are attached with :meth:`bind`.
 
     ``eq=False``: ``mask``/``flat_mask`` may hold (traced) arrays, so
     identity comparison is the only safe equality — specs are plumbing,
     never dict keys.
-
-    The legacy loose-kwarg signatures still work via shims that emit
-    ``DeprecationWarning`` and build the equivalent spec, so both paths
-    run literally the same code (jaxpr-identity-tested in
-    tests/test_aggregate.py).
     """
 
-    engine: str = "flat"
     algorithm: str = "fedhen"
     mask: Tree = None
     layout: Optional[flatten.FlatLayout] = None
@@ -111,28 +95,15 @@ class EngineSpec:
     variance_reduction: str = "none"
 
     def __post_init__(self):
-        if self.engine not in ("flat", "tree"):
-            raise ValueError(f"unknown agg engine {self.engine!r}")
         if self.algorithm not in ALGORITHMS:
             raise ValueError(self.algorithm)
-        if (self.engine == "tree" and self.wire is not None
-                and self.wire.is_quantized):
-            raise ValueError("int8 wire requires the flat engine "
-                             "(dequantizing fold is a flat-buffer op)")
-        if (self.engine == "tree" and self.wire is not None
-                and self.wire.uses_deltas):
-            raise ValueError("compressed uploads (topk/stochastic/"
-                             "error-feedback wire) require the flat engine "
-                             "(the delta fold is a flat-buffer op)")
 
     @classmethod
     def from_config(cls, fed, *, mask: Tree = None,
                     layout: Optional[flatten.FlatLayout] = None,
-                    flat_mask: Optional[jax.Array] = None,
                     wire: Optional[comm.WireSpec] = None) -> "EngineSpec":
         """Build the spec from a ``FedConfig`` (the knobs' one source)."""
-        return cls(engine=fed.agg_engine, algorithm=fed.algorithm,
-                   mask=mask, layout=layout, flat_mask=flat_mask,
+        return cls(algorithm=fed.algorithm, mask=mask, layout=layout,
                    block_n=fed.agg_block_n,
                    stream_dtype=jnp.dtype(fed.agg_stream_dtype),
                    wire=wire, variance_reduction=fed.variance_reduction)
@@ -141,12 +112,6 @@ class EngineSpec:
         """A copy with trace-time values attached (mask, layout,
         flat_mask, ...)."""
         return dataclasses.replace(self, **kw)
-
-
-def _legacy_spec(where: str, **kw) -> EngineSpec:
-    warnings.warn(f"{where} with loose engine kwargs is deprecated; "
-                  f"pass an EngineSpec", DeprecationWarning, stacklevel=3)
-    return EngineSpec(**kw)
 
 
 def _gated_wsum_leaf(x: jax.Array, weights: jax.Array) -> jax.Array:
@@ -208,19 +173,8 @@ def decouple_server_update(cohort: Tree, is_simple: jax.Array,
     return masking.where_mask(mask, mean_simple, mean_complex), mean_complex
 
 
-def masked_cohort_mean(cohort: Tree, weights_m: jax.Array,
-                       weights_rest: jax.Array, mask: Tree) -> Tree:
-    """General primitive: different cohort weights inside/outside M.
-
-    This is the op the ``masked_agg`` kernel implements on TPU.
-    """
-    mean_m = _wmean(cohort, weights_m)
-    mean_rest = _wmean(cohort, weights_rest)
-    return masking.where_mask(mask, mean_m, mean_rest)
-
-
 # ---------------------------------------------------------------------------
-# Shared streaming helpers
+# Streaming helpers
 # ---------------------------------------------------------------------------
 
 def _chunk_weights(is_simple: jax.Array, valid: jax.Array,
@@ -234,8 +188,6 @@ def _chunk_weights(is_simple: jax.Array, valid: jax.Array,
     simple devices only for decouple.  ``w_out`` weights outside M:
     complex devices only (ln. 22), for all three algorithms.
     """
-    if algorithm not in ALGORITHMS:
-        raise ValueError(algorithm)
     valid_f = valid.astype(jnp.float32)
     w_in = valid_f * is_simple if algorithm == "decouple" else valid_f
     w_out = valid_f * (~is_simple)
@@ -248,7 +200,7 @@ def _safe_inv(tot: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Flat streaming aggregation (the production fold)
+# Streaming aggregation (the production fold)
 # ---------------------------------------------------------------------------
 
 class StreamState(NamedTuple):
@@ -306,24 +258,17 @@ def _layout_for(tree: Tree, layout, block_n: int, *, stacked: bool = False):
     return flatten.layout_of(tree, total_multiple=block_n, stacked=stacked)
 
 
-def streaming_init(params_like: Tree, algorithm, *,
-                   layout: Optional[flatten.FlatLayout] = None,
-                   block_n: int = 2048) -> StreamState:
+def streaming_init(params_like: Tree, spec: EngineSpec) -> StreamState:
     """Zero flat accumulators for one round of streaming aggregation.
 
     Args:
       params_like: ONE (unstacked) complex model tree — only shapes are
         read, to size the flat accumulator.
-      algorithm: the :class:`EngineSpec` (preferred; decouple allocates
-        the second accumulator, SCAFFOLD the control-variate one), or a
-        legacy algorithm string (deprecated).
-      layout / block_n: legacy-only loose kwargs; a spec carries its own.
+      spec: the :class:`EngineSpec` (decouple allocates the second
+        accumulator, SCAFFOLD the control-variate one).
 
     Returns: a :class:`StreamState` of f32 zeros (``(n_flat,)`` acc(s) +
     two scalar weight totals)."""
-    spec = algorithm if isinstance(algorithm, EngineSpec) else _legacy_spec(
-        "streaming_init(params_like, algorithm, ...)", algorithm=algorithm,
-        layout=layout, block_n=block_n)
     layout = _layout_for(params_like, spec.layout, spec.block_n)
     zeros = jnp.zeros((layout.n_flat,), jnp.float32)
     acc_out = zeros if spec.algorithm == "decouple" else None
@@ -334,15 +279,10 @@ def streaming_init(params_like: Tree, algorithm, *,
 
 
 def streaming_fold(state: StreamState, chunk: Tree, is_simple: jax.Array,
-                   valid: jax.Array, mask, *, algorithm: str = None,
-                   layout: Optional[flatten.FlatLayout] = None,
-                   flat_mask: Optional[jax.Array] = None,
-                   block_n: int = 2048,
-                   stream_dtype=jnp.float32,
-                   wire: Optional[comm.WireSpec] = None,
-                   force_pallas_interpret: bool = False,
+                   valid: jax.Array, spec: EngineSpec, *,
                    cv_chunk: Optional[jax.Array] = None,
-                   sparse_chunk: Optional[SparseChunk] = None) -> StreamState:
+                   sparse_chunk: Optional[SparseChunk] = None,
+                   force_pallas_interpret: bool = False) -> StreamState:
     """Fold one stacked chunk of client models into the flat sums.
 
     Args:
@@ -353,10 +293,8 @@ def streaming_fold(state: StreamState, chunk: Tree, is_simple: jax.Array,
       valid: ``(Z,)`` bool validity, or f32 per-client weights (validity x
         staleness coefficient — the async engine's path; see the module
         weight contract).
-      mask: the :class:`EngineSpec` (preferred), or the legacy mask tree
-        with the engine configuration as loose kwargs (deprecated).
-      algorithm / layout / flat_mask / block_n / stream_dtype / wire:
-        legacy-only loose kwargs; a spec carries its own.
+      spec: the :class:`EngineSpec` (algorithm, mask, layout, flat mask,
+        block, stream dtype, wire).
       cv_chunk: optional ``(Z, n_flat)`` control-variate deltas (SCAFFOLD)
         folded into ``state.cv_acc`` with the same per-client weights and
         flat mask as the params — one extra accumulating launch, nothing
@@ -385,25 +323,19 @@ def streaming_fold(state: StreamState, chunk: Tree, is_simple: jax.Array,
     0 and are gated before the multiply on both paths, so they can never
     poison the accumulators.
 
-    ``wire`` switches the fold to the communication path (core/comm.py):
-    the uploads are what the fold consumes.  A bf16 wire overrides
-    ``stream_dtype``; an int8 wire quantizes the packed chunk (symmetric
-    per-group, ``wire.quant_block`` elements per f32 scale — the kernel
-    path packs the chunk to f32 first, the client-side encode, so the
-    fold's peak temp matches the unquantized path) and folds it with the
+    ``spec.wire`` switches the fold to the communication path
+    (core/comm.py): the uploads are what the fold consumes.  A bf16 wire
+    overrides ``stream_dtype``; an int8 wire quantizes the packed chunk
+    (symmetric per-group, ``wire.quant_block`` elements per f32 scale —
+    the kernel path packs the chunk to f32 first, the client-side encode,
+    so the fold's peak temp matches the unquantized path) and folds it
+    with the
     *dequantizing* accumulate — ``masked_agg_acc_deq`` on the kernel path,
     its XLA ref per leaf slice on CPU — so the *server side* never
     materializes a dequantized f32 copy of the uploads.  Quantization
     grouping is identical on both paths (groups never cross slots because
     ``quant_block`` divides the lane alignment).
     """
-    if isinstance(mask, EngineSpec):
-        spec = mask
-    else:
-        spec = _legacy_spec(
-            "streaming_fold(..., mask, algorithm=...)", algorithm=algorithm,
-            mask=mask, layout=layout, flat_mask=flat_mask, block_n=block_n,
-            stream_dtype=stream_dtype, wire=wire)
     mask, layout, flat_mask = spec.mask, spec.layout, spec.flat_mask
     block_n, stream_dtype, wire = spec.block_n, spec.stream_dtype, spec.wire
     w_in, w_out = _chunk_weights(is_simple, valid, spec.algorithm)
@@ -534,8 +466,7 @@ def _fold_cv(cv_acc: jax.Array, cv_chunk: jax.Array, flat_mask: jax.Array,
     """Fold a ``(Z, n_flat)`` control-variate delta chunk into the running
     cv sum — the identical masked accumulate launch the params take, so
     SCAFFOLD rides the kernel (and its weight-0 NaN gating) for free.
-    Control variates are born flat (they ARE FlatLayout vectors), so this
-    path is shared by the flat AND tree engines."""
+    Control variates are born flat (they ARE FlatLayout vectors)."""
     cv32 = cv_chunk.astype(jnp.float32)
     if force_pallas_interpret or agg_ops.use_pallas():
         return agg_ops.masked_agg_acc_pallas(
@@ -587,20 +518,15 @@ def _fold_leaves_into_flat_deq(acc: jax.Array, chunk: Tree, mask: Tree,
     return acc
 
 
-def streaming_finalize(state: StreamState, mask, template: Tree = None, *,
-                       algorithm: str = None,
-                       layout: Optional[flatten.FlatLayout] = None,
-                       flat_mask: Optional[jax.Array] = None,
-                       block_n: int = 2048) -> Tuple[Tree, Optional[Tree]]:
+def streaming_finalize(state: StreamState, spec: EngineSpec,
+                       template: Tree) -> Tuple[Tree, Optional[Tree]]:
     """Normalize the flat sums, unpack to trees, cast to ``template`` dtypes.
 
     Args:
       state: the fully folded :class:`StreamState`.
-      mask: the :class:`EngineSpec` (preferred) or the legacy mask tree
-        (deprecated, with the engine configuration as loose kwargs).
+      spec: the :class:`EngineSpec` the state was folded with.
       template: tree providing the output leaf dtypes (shapes come from
         the layout; ``ShapeDtypeStruct`` leaves are fine).
-      algorithm / layout / flat_mask / block_n: legacy-only loose kwargs.
 
     Returns: ``(new_complex, new_simple_host)``; the host is ``None`` except
     for decouple (matching ``ServerState``).  A group with zero total weight
@@ -609,13 +535,6 @@ def streaming_finalize(state: StreamState, mask, template: Tree = None, *,
     server update divides the raw delta sum by N_devices, not by the
     cohort weight totals (the round owns that step).
     """
-    if isinstance(mask, EngineSpec):
-        spec = mask
-    else:
-        spec = _legacy_spec(
-            "streaming_finalize(state, mask, template, algorithm=...)",
-            algorithm=algorithm, mask=mask, layout=layout,
-            flat_mask=flat_mask, block_n=block_n)
     mask, layout, flat_mask = spec.mask, spec.layout, spec.flat_mask
     layout = _layout_for(template, layout, spec.block_n)
     if flat_mask is None:
@@ -632,76 +551,34 @@ def streaming_finalize(state: StreamState, mask, template: Tree = None, *,
     return combined, None
 
 
-def make_engine(engine, *, algorithm: str = None, mask: Tree = None,
-                layout: Optional[flatten.FlatLayout] = None,
-                flat_mask: Optional[jax.Array] = None,
-                block_n: int = 2048, stream_dtype=jnp.float32,
-                wire: Optional[comm.WireSpec] = None
-                ) -> Tuple[Callable, Callable, Callable]:
-    """The ``(init, fold, finalize)`` triple for a fold engine.
+def make_engine(spec: EngineSpec) -> Tuple[Callable, Callable, Callable]:
+    """The ``(init, fold, finalize)`` triple for a configured fold.
 
-    The single dispatch point every consumer (the trainer's round, the
-    launch-side round step, benchmarks) binds its engine through, so the
-    flat/tree plumbing cannot drift between call sites:
+    The single binding point every consumer (the trainer's round, the
+    launch-side round step, benchmarks) takes its fold through:
 
     * ``init(params_like) -> state``
     * ``fold(state, chunk, is_simple, valid[, cv_chunk=...]) -> state``
     * ``finalize(state, template=...) -> (new_complex, simple_host)``
 
-    Args:
-      engine: an :class:`EngineSpec` (preferred) — the loose
-        ``engine-string + kwargs`` form is deprecated and shimmed through
-        the same spec, so both build literally identical programs.
-
     The spec's ``wire`` routes the fold through the communication path
     (the uploads are what the server folds): bf16 wires ride the stream
-    dtype, int8 wires use the dequantizing accumulate — flat engine only
-    (the tree engine predates the wire layer; FedConfig and the spec both
-    enforce the pairing).
+    dtype, int8 wires use the dequantizing accumulate.
     """
-    if isinstance(engine, EngineSpec):
-        spec = engine
-    else:
-        spec = _legacy_spec(
-            "make_engine(engine, algorithm=..., mask=...)", engine=engine,
-            algorithm=algorithm, mask=mask, layout=layout,
-            flat_mask=flat_mask, block_n=block_n, stream_dtype=stream_dtype,
-            wire=wire)
-    if spec.engine == "tree" and spec.wire is not None \
-            and not spec.wire.is_identity:
-        spec = spec.bind(stream_dtype=spec.wire.payload_dtype)
-    if spec.engine == "flat":
-        init = functools.partial(streaming_init, algorithm=spec)
-        fold = functools.partial(streaming_fold, mask=spec)
-        finalize = functools.partial(streaming_finalize, mask=spec)
-    else:
-        init = functools.partial(tree_streaming_init, algorithm=spec)
-        fold = functools.partial(tree_streaming_fold, mask=spec)
-        finalize = functools.partial(tree_streaming_finalize, mask=spec)
+    init = functools.partial(streaming_init, spec=spec)
+    fold = functools.partial(streaming_fold, spec=spec)
+    finalize = functools.partial(streaming_finalize, spec=spec)
     return init, fold, finalize
 
 
-def engine_attrs(engine, *, algorithm: str = None, block_n: int = None,
-                 stream_dtype=jnp.float32,
-                 wire: Optional[comm.WireSpec] = None) -> dict:
-    """Static description of a configured fold engine, as plain scalars.
+def engine_attrs(spec: EngineSpec) -> dict:
+    """Static description of a configured fold, as plain scalars.
 
     What the telemetry ``run_config`` ledger records about the
-    aggregation path — computed next to :func:`make_engine`'s dispatch so
-    the recorded configuration cannot drift from the one that runs.
-    Takes an :class:`EngineSpec` (preferred) or the deprecated loose
-    kwargs.
+    aggregation path — read off the same spec :func:`make_engine` binds,
+    so the recorded configuration cannot drift from the one that runs.
     """
-    if isinstance(engine, EngineSpec):
-        spec = engine
-    else:
-        spec = _legacy_spec(
-            "engine_attrs(engine, algorithm=..., block_n=...)",
-            engine=engine, algorithm=algorithm,
-            block_n=2048 if block_n is None else block_n)
-        spec = spec.bind(stream_dtype=stream_dtype, wire=wire)
     attrs = {
-        "agg_engine": spec.engine,
         "algorithm": spec.algorithm,
         "agg_block_n": int(spec.block_n),
         "agg_stream_dtype": str(jnp.dtype(spec.stream_dtype)),
@@ -718,117 +595,3 @@ def engine_attrs(engine, *, algorithm: str = None, block_n: int = None,
             "wire_error_feedback": bool(spec.wire.error_feedback),
         })
     return attrs
-
-
-# ---------------------------------------------------------------------------
-# Tree streaming aggregation (PR 2 per-leaf engine — parity reference)
-# ---------------------------------------------------------------------------
-
-class TreeStreamState(NamedTuple):
-    """Per-leaf analogue of ``StreamState``: ``acc``/``acc_out`` are f32
-    *trees* shaped like one complex model (one ``masked_agg`` launch per
-    leaf at fold time).  ``cv_acc`` stays FLAT even here — control
-    variates are FlatLayout vectors on every engine (that is the point
-    of the parity: flat-vs-tree must agree on the cv sum bit for bit)."""
-    acc: Tree
-    acc_out: Optional[Tree]
-    tot_in: jax.Array
-    tot_out: jax.Array
-    cv_acc: Optional[jax.Array] = None
-
-
-def tree_streaming_init(params_like: Tree, algorithm) -> TreeStreamState:
-    """Zero accumulators shaped like one (unstacked) complex model.
-    ``algorithm``: an :class:`EngineSpec` (preferred) or a legacy
-    algorithm string (deprecated)."""
-    spec = algorithm if isinstance(algorithm, EngineSpec) else _legacy_spec(
-        "tree_streaming_init(params_like, algorithm)", engine="tree",
-        algorithm=algorithm)
-    zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32),
-                         params_like)
-    acc_out = zeros if spec.algorithm == "decouple" else None
-    cv_acc = None
-    if spec.variance_reduction == "scaffold":
-        if spec.layout is None:
-            raise ValueError("SCAFFOLD on the tree engine needs the spec's "
-                             "layout (the cv accumulator is flat)")
-        cv_acc = jnp.zeros((spec.layout.n_flat,), jnp.float32)
-    return TreeStreamState(zeros, acc_out, jnp.zeros((), jnp.float32),
-                           jnp.zeros((), jnp.float32), cv_acc)
-
-
-def tree_streaming_fold(state: TreeStreamState, chunk: Tree,
-                        is_simple: jax.Array, valid: jax.Array, mask,
-                        *, algorithm: str = None, block_n: int = 2048,
-                        stream_dtype=jnp.float32,
-                        force_pallas_interpret: bool = False,
-                        cv_chunk: Optional[jax.Array] = None
-                        ) -> TreeStreamState:
-    """Fold one stacked chunk into per-leaf sums: one ``masked_agg`` kernel
-    call per leaf on TPU (the pre-flat engine, kept for parity).
-
-    ``mask``: the :class:`EngineSpec` (preferred) or the legacy mask tree
-    (deprecated).  ``stream_dtype`` mirrors the flat fold's streaming
-    precision: inputs are rounded to it before the f32 accumulation, so a
-    flat-vs-tree comparison at bf16 compares like with like.  ``cv_chunk``
-    (SCAFFOLD) folds through the same flat cv path as the flat engine —
-    see :func:`_fold_cv`."""
-    if isinstance(mask, EngineSpec):
-        spec = mask
-    else:
-        spec = _legacy_spec(
-            "tree_streaming_fold(..., mask, algorithm=...)", engine="tree",
-            algorithm=algorithm, mask=mask, block_n=block_n,
-            stream_dtype=stream_dtype)
-    w_in, w_out = _chunk_weights(is_simple, valid, spec.algorithm)
-    chunk32 = jax.tree.map(
-        lambda x: x.astype(spec.stream_dtype).astype(jnp.float32), chunk)
-    part = agg_ops.masked_agg_tree(
-        chunk32, spec.mask, w_in, w_out, block_n=spec.block_n,
-        force_pallas_interpret=force_pallas_interpret)
-    acc = jax.tree.map(jnp.add, state.acc, part)
-    acc_out = state.acc_out
-    if acc_out is not None:
-        acc_out = jax.tree.map(
-            lambda a, x: a + _gated_wsum_leaf(x, w_out), acc_out, chunk32)
-    cv_acc = state.cv_acc
-    if cv_chunk is not None:
-        if cv_acc is None:
-            raise ValueError("cv_chunk passed but the stream state has no "
-                             "cv accumulator (init with a SCAFFOLD spec)")
-        flat_mask = spec.flat_mask
-        if flat_mask is None:
-            flat_mask = flatten.pack_mask(spec.layout, spec.mask)
-        cv_acc = _fold_cv(cv_acc, cv_chunk, flat_mask, w_in, w_out,
-                          block_n=spec.block_n,
-                          force_pallas_interpret=force_pallas_interpret)
-    return TreeStreamState(acc, acc_out, state.tot_in + jnp.sum(w_in),
-                           state.tot_out + jnp.sum(w_out), cv_acc)
-
-
-def tree_streaming_finalize(state: TreeStreamState, mask,
-                            template: Tree = None, *, algorithm: str = None
-                            ) -> Tuple[Tree, Optional[Tree]]:
-    """Normalize the per-leaf sums into server models (tree engine).
-    ``mask``: the :class:`EngineSpec` (preferred) or the legacy mask
-    tree (deprecated)."""
-    if isinstance(mask, EngineSpec):
-        spec = mask
-    else:
-        spec = _legacy_spec(
-            "tree_streaming_finalize(state, mask, template, "
-            "algorithm=...)", engine="tree", algorithm=algorithm, mask=mask)
-    mask, algorithm = spec.mask, spec.algorithm
-    def safe_div(tree, tot):
-        inv = _safe_inv(tot)
-        return jax.tree.map(lambda a: a * inv, tree)
-
-    mean_in = safe_div(state.acc, state.tot_in)
-    mean_out = safe_div(state.acc, state.tot_out)
-    cast = lambda tree: jax.tree.map(
-        lambda a, t: a.astype(t.dtype), tree, template)
-    combined = cast(masking.where_mask(mask, mean_in, mean_out))
-    if algorithm == "decouple":
-        new_complex = cast(safe_div(state.acc_out, state.tot_out))
-        return new_complex, combined
-    return combined, None

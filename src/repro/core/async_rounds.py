@@ -34,8 +34,9 @@ is version-aware: each client's last-fetched version tag lives in the
 trainer's per-client state matrix (``core.client_state``, the
 ``version_tag`` column) and one vectorized tag-compare per round bills
 only the clients whose chunk trains on a version they do not hold —
-billing-identical to the retired per-client ``comm.VersionCache`` dict
-(parity-tested), but O(cohort) with no O(N_clients) host dict.  So
+billing-identical to a per-client dict of last-fetched tags (the oracle
+in ``tests/billing_oracle.py``), but O(cohort) with no O(N_clients) host
+dict.  So
 measured bytes stay truthful under stale-broadcast reuse at any
 population size.
 
@@ -53,7 +54,7 @@ why the ``L = 0`` parity is bit-exact rather than merely close.
 
 The engine SHARES the synchronous machinery rather than mirroring it:
 the same ``make_client_trainer``, the same ``aggregate.make_engine`` fold
-triple (flat or tree, any wire — int8 uploads still fold through the
+triple (any wire — int8 uploads still fold through the
 dequantizing ``masked_agg`` accumulate), and the ONE chunk-stream scan
 ``federated.stream_population`` (the async extras — per-chunk version
 index and staleness coefficient — are optional arguments of that shared
@@ -197,8 +198,8 @@ class AsyncRoundEngine:
             host = flatten.pack(self.layout, tr.server.simple_host)
             self.versions_host = jnp.tile(host[None], (self.n_versions, 1))
         tr.client_state.reset_version_tags()
-        # cumulative billing tallies (the retired VersionCache dict's
-        # counts, now engine-owned); telemetry emits per-round deltas, so
+        # cumulative billing tallies (hits = reused stale broadcasts,
+        # misses = downloads); telemetry emits per-round deltas, so
         # also remember where the last round left off
         self.cache_hits = 0
         self.cache_misses = 0
@@ -384,7 +385,7 @@ class AsyncRoundEngine:
         key = jax.random.PRNGKey(tr.fed.seed * 100003 + r)
         args = (self.versions, self.versions_host,
                 tr._gather(plan.simple_ids), tr._gather(plan.complex_ids),
-                key, tr._flat_mask_arg(), jnp.asarray(s_s, jnp.int32), w_s,
+                key, tr.flat_mask, jnp.asarray(s_s, jnp.int32), w_s,
                 jnp.asarray(s_c, jnp.int32), w_c)
         cv = tr._cv_args(plan)
         ef = tr._ef_args(plan)

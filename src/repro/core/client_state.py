@@ -1,8 +1,8 @@
 """Sharded per-client state: one flat ``(N_clients + 1, width)`` matrix.
 
-Per-client bookkeeping used to live in Python dicts
-(``comm.VersionCache._held`` most prominently) — fine at 100 clients,
-a rewrite at the ROADMAP's "millions of users".  This module moves every
+Per-client bookkeeping in Python dicts (one entry per client ever seen)
+is fine at 100 clients and a rewrite at the ROADMAP's "millions of
+users".  This module moves every
 per-client scalar the runtime tracks into ONE flat numpy matrix with a
 static column schema (the ``FlatLayout`` idea applied to the client
 axis): rounds touch it only through vectorized gather/scatter by the
@@ -19,9 +19,10 @@ integer counters up to 2^53):
 * ``last_round``    — last round index the client participated in
   (-1 = never).
 * ``version_tag``   — the server version tag this client last
-  downloaded (-1 = nothing cached).  Replaces the ``VersionCache`` dict
-  with one vectorized tag-compare per round (:meth:`bill_downloads`),
-  billing-identical to the dict (parity-tested).
+  downloaded (-1 = nothing cached).  One vectorized tag-compare per
+  round (:meth:`bill_downloads`) bills downloads, identically to a
+  per-client dict of tags (parity-tested against
+  ``tests/billing_oracle.py``).
 * ``cv_scale``     — L2 norm of the client's SCAFFOLD control-variate
   row, written on every state-store scatter
   (:meth:`set_cv_scale`; zero when ``variance_reduction="none"``).
@@ -120,7 +121,8 @@ class ClientStateMatrix:
         equals it is a cache *hit* (0 bytes — the stale-broadcast reuse
         the async engine's measured savings come from), anything else a
         *miss* billed ``nbytes`` and recorded.  Semantics are identical
-        to ``comm.VersionCache.bill`` called per client (parity-tested);
+        to the per-client dict oracle ``VersionCache.bill`` of
+        ``tests/billing_oracle.py`` called per client (parity-tested);
         cost is one compare + one scatter over O(cohort) rows.
 
         Returns ``(billed_bytes, hits, misses)``.

@@ -71,11 +71,12 @@ the real sparse encoder under ``jax.eval_shape`` (values + scale sidecar
 
 Under the asynchronous round engine (``core/async_rounds.py``) broadcasts
 are **version-tagged**: a chunk that trains on a stale version its clients
-already hold does not re-download it.  :class:`VersionCache` keeps that
-accounting truthful — one download is billed per (client, version), so the
-measured per-round download shrinks exactly when a cached stale broadcast
-is reused, and degenerates to the synchronous numbers at ``async_lag=0``
-(every round publishes a fresh version, so every client re-downloads).
+already hold does not re-download it.  The ``version_tag`` column of
+``core.client_state`` keeps that accounting truthful — one download is
+billed per (client, version), so the measured per-round download shrinks
+exactly when a cached stale broadcast is reused, and degenerates to the
+synchronous numbers at ``async_lag=0`` (every round publishes a fresh
+version, so every client re-downloads).
 """
 
 from __future__ import annotations
@@ -83,7 +84,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -454,57 +455,6 @@ def analytic_wire_bytes_up(spec: WireSpec, n_elements: int) -> int:
     if spec.is_quantized:
         n += (k // spec.quant_block) * 4
     return n
-
-
-class VersionCache:
-    """Version-tagged download accounting for the async broadcast.
-
-    The asynchronous engine lets a chunk train on a stale server version;
-    a client that already holds that version (it downloaded it in an
-    earlier round) must not be billed a second download, or the measured
-    savings of broadcast reuse would be fiction.  This host-side ledger
-    tracks which version tag each client last fetched:
-
-    * ``bill(client_id, tag, nbytes)`` — returns ``nbytes`` and records
-      the fetch if the client's cached tag differs, else returns 0;
-    * ``holds(client_id, tag)`` — query without billing.
-
-    Tags are opaque hashables (the engine uses the publishing round
-    index).  With ``async_lag=0`` every round publishes a fresh tag, so
-    every sampled client re-downloads and the accounting reproduces the
-    synchronous numbers exactly.
-
-    ``hits`` / ``misses`` count ``bill`` outcomes since construction —
-    a hit is a reused stale broadcast, the async engine's measured
-    savings.
-
-    **Retired from the round path.**  A per-client Python dict is
-    O(N_clients) host state; the runtime now keeps version tags in the
-    flat per-client state matrix (``core.client_state``, the
-    ``version_tag`` column) and bills one vectorized tag-compare per
-    round (``ClientStateMatrix.bill_downloads``).  This class stays as
-    the executable *reference semantics* the vectorized billing is
-    parity-tested against.
-    """
-
-    def __init__(self):
-        self._held: Dict[Any, Any] = {}
-        self.hits = 0
-        self.misses = 0
-
-    def holds(self, client_id, tag) -> bool:
-        """True when ``client_id`` already fetched version ``tag``."""
-        return self._held.get(client_id) == tag
-
-    def bill(self, client_id, tag, nbytes: int) -> int:
-        """Bytes this client's download of version ``tag`` costs now:
-        ``nbytes`` on a cache miss (recorded), 0 on a hit."""
-        if self.holds(client_id, tag):
-            self.hits += 1
-            return 0
-        self.misses += 1
-        self._held[client_id] = tag
-        return int(nbytes)
 
 
 # ---------------------------------------------------------------------------

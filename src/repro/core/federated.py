@@ -32,18 +32,15 @@ On the production mesh the chunk axis is sharded over ``data``/``pod``
 (see launch/), making the per-chunk fold an all-reduce: the communication
 the paper saves.
 
-**Flat layout contract.**  With ``FedConfig.agg_engine="flat"`` (default)
-the trainer builds ONE static ``core.flatten.FlatLayout`` for the complex
-treedef at ``__init__`` (offsets are a pure function of treedef + leaf
-shapes + ``agg_block_n``, so the layout is valid for every round and
-checkpoint restore) and precomputes the index-set-M mask as one flat
-bitvector.  Each trained chunk is packed into a single contiguous
-``(Z, n_flat)`` buffer — in ``agg_stream_dtype`` (bf16 halves fold read
-traffic; accumulation is always f32) — and the whole fold is ONE
-accumulating ``masked_agg`` launch updating the flat running sum in place,
-instead of one launch per leaf.  ``agg_engine="tree"`` keeps the per-leaf
-PR 2 fold as the parity engine; the two differ only by float summation
-order across kernel tile boundaries.
+**Flat layout contract.**  The trainer builds ONE static
+``core.flatten.FlatLayout`` for the complex treedef at ``__init__``
+(offsets are a pure function of treedef + leaf shapes + ``agg_block_n``,
+so the layout is valid for every round and checkpoint restore) and
+precomputes the index-set-M mask as one flat bitvector.  Each trained
+chunk is packed into a single contiguous ``(Z, n_flat)`` buffer — in
+``agg_stream_dtype`` (bf16 halves fold read traffic; accumulation is
+always f32) — and the whole fold is ONE accumulating ``masked_agg_acc``
+launch updating the flat running sum in place.
 
 **Wire contract.**  ``FedConfig.comm_dtype`` selects the round's wire
 format (``core/comm.py``): the server broadcast is encoded/decoded through
@@ -80,9 +77,10 @@ and stale uploads fold with the polynomial staleness decay
 ``1/(1+s)^async_decay`` multiplied into the same validity-weight path the
 NaN/padding exclusion uses.  ``async_lag=0`` IS this module's synchronous
 engine, bit-for-bit (test-enforced).  Download accounting becomes
-version-aware under async (``comm.VersionCache``): reused stale
-broadcasts are not re-billed, so ``total_bytes_down`` is measured per
-round instead of a static per-round constant.
+version-aware under async (the ``version_tag`` column of
+``core.client_state``): reused stale broadcasts are not re-billed, so
+``total_bytes_down`` is measured per round instead of a static per-round
+constant.
 
 Cohort composition is stratified (k_s simple + k_c complex per round, the
 expectation of the paper's uniform 10% sampling) so shapes stay static;
@@ -871,7 +869,7 @@ class FederatedTrainer:
         spec = self.engine_spec
 
         def make_agg(flat_mask):
-            """Engine dispatch.  ``flat_mask`` is a round *argument* (not a
+            """Bind the fold.  ``flat_mask`` is a round *argument* (not a
             closed-over constant) so the precomputed bitvector lives in
             argument memory, shared across rounds, instead of being baked
             into the executable's temp allocation."""
@@ -888,7 +886,7 @@ class FederatedTrainer:
 
         def round_fn(complex_params: Tree, simple_host: Optional[Tree],
                      data_s: Batch, data_c: Batch, rng: jax.Array,
-                     flat_mask: Optional[jax.Array],
+                     flat_mask: jax.Array,
                      real_s: Optional[jax.Array] = None,
                      real_c: Optional[jax.Array] = None,
                      cv_global: Optional[jax.Array] = None,
@@ -918,8 +916,7 @@ class FederatedTrainer:
             sc_s = sc_c = None
             if scaffold_on:
                 # simple clients train (and correct) only the M slice:
-                # their c_i lives on M alone.  flat_mask is a round arg
-                # whenever scaffold is on (_flat_mask_arg).
+                # their c_i lives on M alone
                 sc_s = ScaffoldCtx(
                     rows=cv_s, c_global=cv_global, pop_mask=flat_mask,
                     layout=layout,
@@ -988,16 +985,6 @@ class FederatedTrainer:
 
     # -- public API ----------------------------------------------------------
 
-    def _flat_mask_arg(self) -> Optional[jax.Array]:
-        """The precomputed flat bitvector, passed into the round jit as an
-        argument (a resident buffer shared by every round) rather than
-        closed over as an executable constant.  SCAFFOLD needs it on every
-        engine (the cv fold and the simple population's slice mask are
-        flat ops even under the tree engine)."""
-        if self.fed.agg_engine == "flat" or self.cv_store is not None:
-            return self.flat_mask
-        return None
-
     def _cv_args(self, plan: sampling.CohortPlan) -> tuple:
         """The SCAFFOLD round arguments: ``(c_global, rows_s, rows_c)``
         gathered O(cohort) from the state store — empty when off (the
@@ -1020,7 +1007,7 @@ class FederatedTrainer:
     def _round_args(self, plan: sampling.CohortPlan, data_s: Batch,
                     data_c: Batch, key: jax.Array) -> tuple:
         args = (self.server.complex, self.server.simple_host, data_s,
-                data_c, key, self._flat_mask_arg())
+                data_c, key, self.flat_mask)
         cv = self._cv_args(plan)
         ef = self._ef_args(plan)
         if self.fed.sample_uniform:

@@ -9,12 +9,11 @@ cohort through VMEM exactly once with lane-aligned tiles:
 * the whole cohort axis Z (<= ~32 active devices) rides along inside the
   tile: block (Z, block_n) -> VMEM,
 * weights are selected per element from (w_m, w_rest) by the mask tile and
-  reduced over Z in one fused multiply-add in f32, written back in the
-  storage dtype.
+  reduced over Z in one fused multiply-add in f32, added to the f32
+  running sum.
 
-Four variants:
+Three variants:
 
-* ``masked_agg_pallas`` — the one-shot reduction (out = masked sum).
 * ``masked_agg_acc_pallas`` — the streaming fold's accumulating form:
   ``out = acc + masked sum`` with ``input_output_aliases`` so the running
   f32 accumulator is updated **in place** — the fold writes N floats
@@ -51,46 +50,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-
-def _agg_kernel(x_ref, mask_ref, wm_ref, wr_ref, out_ref):
-    x = x_ref[...].astype(jnp.float32)              # (Z, block_n)
-    mask = mask_ref[...]                            # (1, block_n) bool
-    wm = wm_ref[...].astype(jnp.float32)            # (Z, 1)
-    wr = wr_ref[...].astype(jnp.float32)            # (Z, 1)
-    w = jnp.where(mask, wm, wr)                     # (Z, block_n)
-    x = jnp.where(w > 0, x, 0.0)                    # NaN-device gating
-    out_ref[...] = jnp.sum(x * w, axis=0,
-                           keepdims=True).astype(out_ref.dtype)
-
-
-def masked_agg_pallas(x: jax.Array, mask: jax.Array, w_m: jax.Array,
-                      w_rest: jax.Array, *, block_n: int = 2048,
-                      interpret: bool = False) -> jax.Array:
-    """x: (Z, N); mask: (N,) bool; w_m/w_rest: (Z,) -> (N,) in x.dtype."""
-    z, n = x.shape
-    pad = (-n) % block_n
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-        mask = jnp.pad(mask, (0, pad))
-    np_ = x.shape[1]
-    grid = (np_ // block_n,)
-
-    out = pl.pallas_call(
-        _agg_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((z, block_n), lambda i: (0, i)),
-            pl.BlockSpec((1, block_n), lambda i: (0, i)),
-            pl.BlockSpec((z, 1), lambda i: (0, 0)),
-            pl.BlockSpec((z, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, np_), x.dtype),
-        name="masked_agg",
-        interpret=interpret,
-    )(x, mask[None, :], w_m[:, None], w_rest[:, None])
-    return out[0, :n]
 
 
 def _agg_acc_kernel(acc_ref, x_ref, mask_ref, wm_ref, wr_ref, out_ref):

@@ -17,8 +17,6 @@
 
 from __future__ import annotations
 
-import functools
-import warnings
 from typing import Any, Dict, Optional
 
 import jax
@@ -55,11 +53,7 @@ def make_fed_round_step(cfg: ModelConfig, policy: Policy = NO_POLICY, *,
                         engine: Optional[aggregate.EngineSpec] = None,
                         staleness_scheme: str = "poly",
                         staleness_decay: float = 0.5,
-                        telemetry: Optional[obslib.Telemetry] = None,
-                        agg_engine: Optional[str] = None,
-                        agg_block_n: Optional[int] = None,
-                        comm_dtype: Optional[str] = None,
-                        quant_block: Optional[int] = None):
+                        telemetry: Optional[obslib.Telemetry] = None):
     """One FedHeN round over a stacked cohort, streaming in chunks.
 
     Returns ``round_step(cohort, data, is_simple, flat_mask=None,
@@ -71,20 +65,17 @@ def make_fed_round_step(cfg: ModelConfig, policy: Policy = NO_POLICY, *,
     launch-side mirror of core/federated.py's round, operating on an
     externally sharded cohort instead of tiling server params.
     ``engine`` is an :class:`repro.core.aggregate.EngineSpec` carrying
-    the whole aggregation configuration — engine kind (``"flat"`` packs
-    each trained chunk through the static ``core.flatten`` layout and
-    folds the whole model with one accumulating ``masked_agg`` launch per
-    chunk, ``block_n`` tiles; ``"tree"`` keeps the per-leaf parity fold),
-    the upload wire (``spec.wire``, core/comm.py: the externally sharded
-    cohort arrives already broadcast, so only the client->server
-    direction crosses this step — the fold consumes the encoded uploads,
-    int8 via the dequantizing masked_agg accumulate), and the stream
-    dtype.  The spec's mask/layout/flat_mask fields are bound HERE at
-    trace time (they depend on the cohort template), so pass a spec
-    without them — ``EngineSpec(engine="tree", wire=...)`` — or ``None``
-    for the all-defaults flat/f32 engine.  The legacy loose kwargs
-    (``agg_engine``/``agg_block_n``/``comm_dtype``/``quant_block``) still
-    work but warn: they are folded into an equivalent spec.
+    the whole aggregation configuration — the fold packs each trained
+    chunk through the static ``core.flatten`` layout and folds the whole
+    model with one accumulating ``masked_agg_acc`` launch per chunk in
+    ``block_n`` tiles; the upload wire (``spec.wire``, core/comm.py: the
+    externally sharded cohort arrives already broadcast, so only the
+    client->server direction crosses this step — the fold consumes the
+    encoded uploads, int8 via the dequantizing masked_agg accumulate);
+    and the stream dtype.  The spec's mask/layout/flat_mask fields are
+    bound HERE at trace time (they depend on the cohort template), so
+    pass a spec without them — ``EngineSpec(wire=...)`` — or ``None``
+    for the all-defaults f32 fold.
 
     Pass the precomputed flat bitvector (``flatten.pack_mask`` over the
     same layout) as ``flat_mask`` so it enters the jit as a replicated
@@ -118,23 +109,6 @@ def make_fed_round_step(cfg: ModelConfig, policy: Policy = NO_POLICY, *,
     loop, not inside the traced function.
     """
     adapter = LMAdapter(cfg, policy=policy, remat=True)
-    legacy = {"agg_engine": agg_engine, "agg_block_n": agg_block_n,
-              "comm_dtype": comm_dtype, "quant_block": quant_block}
-    if any(v is not None for v in legacy.values()):
-        if engine is not None:
-            raise ValueError(
-                "pass either engine= (an EngineSpec) or the legacy "
-                f"agg kwargs, not both (got both engine and "
-                f"{[k for k, v in legacy.items() if v is not None]})")
-        warnings.warn(
-            "make_fed_round_step(agg_engine=..., comm_dtype=...) loose "
-            "kwargs are deprecated; pass engine=EngineSpec(...)",
-            DeprecationWarning, stacklevel=2)
-        engine = aggregate.EngineSpec(
-            engine=agg_engine or "flat", algorithm="fedhen",
-            block_n=2048 if agg_block_n is None else agg_block_n,
-            wire=comm.WireSpec(comm_dtype or "float32",
-                               128 if quant_block is None else quant_block))
     spec = engine if engine is not None else aggregate.EngineSpec(
         algorithm="fedhen", wire=comm.WireSpec("float32", 128))
     if spec.wire is None:
@@ -181,12 +155,9 @@ def make_fed_round_step(cfg: ModelConfig, policy: Policy = NO_POLICY, *,
         n_chunks = k // chunk
         template = jax.tree.map(lambda x: x[0], cohort)
         mask = masking.transformer_subnet_mask(template, cfg)
-        layout = None
-        if spec.engine == "flat":
-            layout = flatten.layout_of(template,
-                                       total_multiple=spec.block_n)
-            if flat_mask is None:  # trace-time fallback; see docstring
-                flat_mask = flatten.pack_mask(layout, mask)
+        layout = flatten.layout_of(template, total_multiple=spec.block_n)
+        if flat_mask is None:  # trace-time fallback; see docstring
+            flat_mask = flatten.pack_mask(layout, mask)
         agg_init, agg_fold, agg_finalize = aggregate.make_engine(
             spec.bind(mask=mask, layout=layout, flat_mask=flat_mask))
 

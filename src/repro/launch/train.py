@@ -63,7 +63,7 @@ def build_trainer(args, telemetry=None) -> tuple:
         dirichlet_alpha=args.alpha, algorithm=args.algorithm,
         seed=args.seed, cohort_chunk=args.cohort_chunk,
         sample_uniform=args.sample_uniform,
-        agg_engine=args.agg_engine, agg_block_n=args.agg_block_n,
+        agg_block_n=args.agg_block_n,
         agg_stream_dtype=args.agg_stream_dtype,
         agg_memory_budget_mb=args.agg_memory_budget_mb,
         comm_dtype=args.comm_dtype, quant_block=args.quant_block,
@@ -140,10 +140,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "(0 = whole cohort at once; 'auto' = derive from "
                          "--agg-memory-budget-mb and the flat layout's "
                          "per-client footprint); memory is O(chunk)")
-    ap.add_argument("--agg-engine", choices=("flat", "tree"), default="flat",
-                    help="aggregation fold: one fused masked_agg launch "
-                         "over the flat-packed model (flat) or one per "
-                         "leaf (tree, parity reference)")
     ap.add_argument("--agg-block-n", type=int, default=2048,
                     help="masked_agg kernel tile width (multiple of 128)")
     ap.add_argument("--agg-stream-dtype", default="float32",

@@ -1,6 +1,6 @@
 """Server aggregation unit tests against numpy oracles (Alg. 1 ln. 16-22),
-one-shot AND streaming paths (flat + tree engines).  Referenced by the
-``fedhen_server_update`` docstring."""
+one-shot AND streaming paths (the fold's CPU and kernel lowerings).
+Referenced by the ``fedhen_server_update`` docstring."""
 
 import jax
 import jax.numpy as jnp
@@ -79,17 +79,28 @@ def test_decouple_group_means():
 # Streaming path == one-shot path
 # ---------------------------------------------------------------------------
 
-def _stream(cohort, mask, is_simple, valid, algo, chunk, **fold_kw):
+def _stream(cohort, mask, is_simple, valid, algo, chunk,
+            stream_dtype=jnp.float32, **fold_kw):
     z = jax.tree.leaves(cohort)[0].shape[0]
     template = jax.tree.map(lambda x: x[0], cohort)
-    state = aggregate.streaming_init(template, algo)
+    spec = aggregate.EngineSpec(algorithm=algo, mask=mask,
+                                stream_dtype=stream_dtype)
+    state = aggregate.streaming_init(template, spec)
     for lo in range(0, z, chunk):
         sl = slice(lo, min(lo + chunk, z))
         state = aggregate.streaming_fold(
             state, jax.tree.map(lambda x: x[sl], cohort),
-            is_simple[sl], valid[sl], mask, algorithm=algo, **fold_kw)
-    return aggregate.streaming_finalize(state, mask, template,
-                                        algorithm=algo)
+            is_simple[sl], valid[sl], spec, **fold_kw)
+    return aggregate.streaming_finalize(state, spec, template)
+
+
+def _assert_tree_allclose(got, want, rtol=2e-5, atol=2e-6):
+    if want is None:
+        assert got is None
+        return
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=rtol, atol=atol)
 
 
 @pytest.mark.parametrize("algo", ["fedhen", "noside", "decouple"])
@@ -115,14 +126,21 @@ def test_streaming_matches_one_shot(algo, chunk):
             np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-6)
 
 
-def test_streaming_fold_pallas_interpret():
-    """The fold's kernel dispatch (interpret mode) matches the XLA path."""
+@pytest.mark.parametrize("algo", ["fedhen", "noside", "decouple"])
+@pytest.mark.parametrize("stream_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_streaming_fold_pallas_interpret(algo, stream_dtype):
+    """The fold's kernel dispatch (interpret mode: one packed buffer, one
+    launch — two for decouple) matches the CPU lowering (per-leaf slices
+    of the same flat accumulator), both accumulators, at either stream
+    dtype."""
     cohort, mask, is_simple, valid = _random_case(4)
-    ref_c, _ = _stream(cohort, mask, is_simple, valid, "fedhen", 3)
-    ker_c, _ = _stream(cohort, mask, is_simple, valid, "fedhen", 3,
-                       force_pallas_interpret=True)
-    for g, w in zip(jax.tree.leaves(ker_c), jax.tree.leaves(ref_c)):
-        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+    ref_c, ref_host = _stream(cohort, mask, is_simple, valid, algo, 3,
+                              stream_dtype)
+    ker_c, ker_host = _stream(cohort, mask, is_simple, valid, algo, 3,
+                              stream_dtype, force_pallas_interpret=True)
+    _assert_tree_allclose(ker_c, ref_c, rtol=1e-5, atol=1e-6)
+    _assert_tree_allclose(ker_host, ref_host, rtol=1e-5, atol=1e-6)
 
 
 def test_streaming_zero_weight_group_is_zero():
@@ -138,37 +156,41 @@ def test_streaming_zero_weight_group_is_zero():
 
 
 def test_streaming_rejects_unknown_algorithm():
-    cohort, mask, is_simple, valid = _random_case(6)
+    """The spec is the one place an algorithm name is checked: at
+    construction and again when a copy is bound."""
     with pytest.raises(ValueError):
-        aggregate.streaming_init(jax.tree.map(lambda x: x[0], cohort),
-                                 "fedavg")
+        aggregate.EngineSpec(algorithm="fedavg")
     with pytest.raises(ValueError):
-        aggregate.streaming_fold(
-            aggregate.streaming_init(jax.tree.map(lambda x: x[0], cohort),
-                                     "fedhen"),
-            cohort, is_simple, valid, mask, algorithm="fedavg")
-    with pytest.raises(ValueError):
-        aggregate.tree_streaming_init(jax.tree.map(lambda x: x[0], cohort),
-                                      "fedavg")
+        aggregate.EngineSpec().bind(algorithm="fedavg")
 
 
-# ---------------------------------------------------------------------------
-# Flat engine == tree engine == one-shot oracle
-# ---------------------------------------------------------------------------
-
-def _stream_tree(cohort, mask, is_simple, valid, algo, chunk):
-    """The PR 2 per-leaf streaming engine (parity reference)."""
-    z = jax.tree.leaves(cohort)[0].shape[0]
+@pytest.mark.parametrize("payload", ["cv_chunk", "sparse_chunk"])
+def test_streaming_fold_rejects_misrouted_payloads(payload):
+    """A control-variate chunk needs the SCAFFOLD accumulator, and a
+    delta-mode chunk needs a delta wire: each is refused with its own
+    message instead of being folded into the wrong sums."""
+    from repro.core import comm
+    cohort, mask, is_simple, valid = _random_case(15)
     template = jax.tree.map(lambda x: x[0], cohort)
-    state = aggregate.tree_streaming_init(template, algo)
-    for lo in range(0, z, chunk):
-        sl = slice(lo, min(lo + chunk, z))
-        state = aggregate.tree_streaming_fold(
-            state, jax.tree.map(lambda x: x[sl], cohort),
-            is_simple[sl], valid[sl], mask, algorithm=algo)
-    return aggregate.tree_streaming_finalize(state, mask, template,
-                                             algorithm=algo)
+    layout = flatten.layout_of(template, total_multiple=512)
+    spec = aggregate.EngineSpec(mask=mask, layout=layout, block_n=512,
+                                wire=comm.WireSpec("int8", 128))
+    state = aggregate.streaming_init(template, spec)
+    z, n_flat = is_simple.shape[0], layout.n_flat
+    if payload == "cv_chunk":
+        kw, match = {"cv_chunk": jnp.zeros((z, n_flat))}, "cv accumulator"
+        chunk = cohort
+    else:
+        kw = {"sparse_chunk": aggregate.SparseChunk(
+            jnp.zeros((n_flat,)), jnp.zeros((z, n_flat)), None, None)}
+        match, chunk = "delta-mode wire", None
+    with pytest.raises(ValueError, match=match):
+        aggregate.streaming_fold(state, chunk, is_simple, valid, spec, **kw)
 
+
+# ---------------------------------------------------------------------------
+# Streaming fold == one-shot oracle on the hard cases
+# ---------------------------------------------------------------------------
 
 def _hard_case(seed, z=9):
     """NaN device + zero-weight padding device crossing chunk boundaries."""
@@ -179,20 +201,11 @@ def _hard_case(seed, z=9):
     return cohort, mask, is_simple, valid
 
 
-def _assert_tree_allclose(got, want, rtol=2e-5, atol=2e-6):
-    if want is None:
-        assert got is None
-        return
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=rtol, atol=atol)
-
-
 @pytest.mark.parametrize("algo", ["fedhen", "noside", "decouple"])
 @pytest.mark.parametrize("chunk", [2, 4])
-def test_flat_vs_tree_vs_oracle(algo, chunk):
-    """The three paths agree — with a NaN device and a zero-weight padding
-    device in the cohort (both must be invisible to every path)."""
+def test_streaming_fold_vs_oracle(algo, chunk):
+    """The fold agrees with the one-shot oracle with a NaN device and a
+    zero-weight padding device in the cohort (both must be invisible)."""
     cohort, mask, is_simple, valid = _hard_case(7)
     if algo == "decouple":
         want_host, want_c = aggregate.decouple_server_update(
@@ -201,16 +214,10 @@ def test_flat_vs_tree_vs_oracle(algo, chunk):
         want_c = aggregate.fedhen_server_update(cohort, is_simple, valid,
                                                 mask)
         want_host = None
-    flat_c, flat_host = _stream(cohort, mask, is_simple, valid, algo, chunk)
-    tree_c, tree_host = _stream_tree(cohort, mask, is_simple, valid, algo,
-                                     chunk)
-    for got_c, got_host in ((flat_c, flat_host), (tree_c, tree_host)):
-        _assert_tree_allclose(got_c, want_c)
-        _assert_tree_allclose(got_host, want_host)
-    # flat vs tree directly: identical summation order per element
-    _assert_tree_allclose(flat_c, tree_c, rtol=1e-6, atol=1e-7)
-    _assert_tree_allclose(flat_host, tree_host, rtol=1e-6, atol=1e-7)
-    for leaf in jax.tree.leaves(flat_c):
+    got_c, got_host = _stream(cohort, mask, is_simple, valid, algo, chunk)
+    _assert_tree_allclose(got_c, want_c)
+    _assert_tree_allclose(got_host, want_host)
+    for leaf in jax.tree.leaves(got_c):
         assert np.isfinite(np.asarray(leaf)).all()
 
 
@@ -221,16 +228,16 @@ def test_bf16_stream_f32_accumulation(algo):
     beats accumulating in bf16 outright."""
     cohort, mask, is_simple, valid = _random_case(8)
     template = jax.tree.map(lambda x: x[0], cohort)
-    state = aggregate.streaming_init(template, algo)
+    spec = aggregate.EngineSpec(algorithm=algo, mask=mask,
+                                stream_dtype=jnp.bfloat16)
+    state = aggregate.streaming_init(template, spec)
     for lo in range(0, 9, 3):
         sl = slice(lo, lo + 3)
         state = aggregate.streaming_fold(
             state, jax.tree.map(lambda x: x[sl], cohort),
-            is_simple[sl], valid[sl], mask, algorithm=algo,
-            stream_dtype=jnp.bfloat16)
+            is_simple[sl], valid[sl], spec)
     assert state.acc.dtype == jnp.float32
-    got_c, got_host = aggregate.streaming_finalize(state, mask, template,
-                                                   algorithm=algo)
+    got_c, got_host = aggregate.streaming_finalize(state, spec, template)
     want_c, want_host = _stream(cohort, mask, is_simple, valid, algo, 3)
     _assert_tree_allclose(got_c, want_c, rtol=2e-2, atol=2e-2)
     if algo == "decouple":
@@ -246,22 +253,16 @@ def _count_pallas_calls(fn, *args, **kw):
 @pytest.mark.parametrize("algo,n_launches", [("fedhen", 1), ("noside", 1),
                                              ("decouple", 2)])
 def test_flat_fold_is_one_kernel_launch(algo, n_launches):
-    """The tentpole claim: ONE masked-agg launch per fold for the whole
-    model (two for decouple's extra accumulator), vs one per leaf in the
-    tree engine."""
+    """ONE masked-agg launch per fold for the whole model (two for
+    decouple's extra accumulator), however many leaves the model has."""
     cohort, mask, is_simple, valid = _random_case(9)
     template = jax.tree.map(lambda x: x[0], cohort)
-    state = aggregate.streaming_init(template, algo)
+    spec = aggregate.EngineSpec(algorithm=algo, mask=mask)
+    state = aggregate.streaming_init(template, spec)
     n_flat = _count_pallas_calls(
-        aggregate.streaming_fold, state, cohort, is_simple, valid, mask,
-        algorithm=algo, force_pallas_interpret=True)
+        aggregate.streaming_fold, state, cohort, is_simple, valid,
+        spec=spec, force_pallas_interpret=True)
     assert n_flat == n_launches
-    tstate = aggregate.tree_streaming_init(template, algo)
-    n_tree = _count_pallas_calls(
-        aggregate.tree_streaming_fold, tstate, cohort, is_simple, valid,
-        mask, algorithm=algo, force_pallas_interpret=True)
-    # tree engine: one launch per leaf — grows with the tree; flat doesn't
-    assert n_tree == len(jax.tree.leaves(cohort))
 
 
 # ---------------------------------------------------------------------------
@@ -279,19 +280,21 @@ def _np_weighted_mean(x, w):
 
 
 @pytest.mark.parametrize("algo", ["fedhen", "noside", "decouple"])
-@pytest.mark.parametrize("engine", ["flat", "tree"])
-def test_float_staleness_weights_match_oracle(algo, engine):
+@pytest.mark.parametrize("lowering", ["cpu", "interpret"])
+def test_float_staleness_weights_match_oracle(algo, lowering):
     """``valid`` as f32 per-client weights (validity x staleness decay):
-    both streaming engines implement the weighted mean, with a NaN device
-    and a zero-weight device gated out — the async engine's whole fold
-    contract in one case."""
+    the fold implements the weighted mean on both of its lowerings (the
+    CPU per-leaf path and the Pallas kernel in interpret mode), with a
+    NaN device and a zero-weight device gated out — the async engine's
+    whole fold contract in one case."""
     cohort, mask, is_simple, _ = _random_case(11)
     cohort["a"] = cohort["a"].at[2].set(jnp.nan)     # NaN device
     # fractional staleness weights; device 2 (NaN) and 5 at weight 0
     w = jnp.asarray([1.0, 0.5, 0.0, 0.25, 1.0, 0.0, 0.5, 1.0, 0.25],
                     jnp.float32)
-    stream = _stream if engine == "flat" else _stream_tree
-    got_c, got_host = stream(cohort, mask, is_simple, w, algo, 3)
+    got_c, got_host = _stream(
+        cohort, mask, is_simple, w, algo, 3,
+        force_pallas_interpret=lowering == "interpret")
     s = np.asarray(is_simple)
     w_np = np.asarray(w)
     w_in = w_np * s if algo == "decouple" else w_np
@@ -336,148 +339,32 @@ def test_flat_fold_uses_prebuilt_layout_and_mask():
     cohort, mask, is_simple, valid = _random_case(10)
     template = jax.tree.map(lambda x: x[0], cohort)
     layout = flatten.layout_of(template, total_multiple=512)
-    flat_mask = flatten.pack_mask(layout, mask)
-    state = aggregate.streaming_init(template, "fedhen", layout=layout)
-    state = aggregate.streaming_fold(
-        state, cohort, is_simple, valid, mask, algorithm="fedhen",
-        layout=layout, flat_mask=flat_mask, block_n=512)
-    got_c, _ = aggregate.streaming_finalize(
-        state, mask, template, algorithm="fedhen", layout=layout,
-        flat_mask=flat_mask)
+    spec = aggregate.EngineSpec(mask=mask, layout=layout,
+                                flat_mask=flatten.pack_mask(layout, mask),
+                                block_n=512)
+    init, fold, finalize = aggregate.make_engine(spec)
+    state = fold(init(template), cohort, is_simple, valid)
+    got_c, _ = finalize(state, template=template)
     want_c, _ = _stream(cohort, mask, is_simple, valid, "fedhen", 9)
     _assert_tree_allclose(got_c, want_c, rtol=1e-6, atol=1e-7)
 
 
-# ---------------------------------------------------------------------------
-# EngineSpec consolidation: the legacy loose-kwarg shims warn, the spec
-# path is warning-free, and both build literally the same program
-# ---------------------------------------------------------------------------
-
-import warnings
-
-
-def _spec_for(cohort, mask, algo="fedhen", engine="flat"):
+def test_engine_attrs_records_the_full_spec():
+    from repro.core import comm
+    cohort, mask, _, _ = _random_case(14)
     template = jax.tree.map(lambda x: x[0], cohort)
     layout = flatten.layout_of(template, total_multiple=512)
-    return template, aggregate.EngineSpec(
-        engine=engine, algorithm=algo, mask=mask, layout=layout,
-        flat_mask=flatten.pack_mask(layout, mask), block_n=512)
-
-
-def test_engine_spec_jaxpr_identity_with_legacy_kwargs():
-    """The refactor is pure plumbing: the spec-driven fold traces to the
-    IDENTICAL jaxpr as the deprecated loose-kwarg calls."""
-    cohort, mask, is_simple, valid = _random_case(11)
-    template, spec = _spec_for(cohort, mask)
-
-    def via_spec(cohort, is_simple, valid):
-        init, fold, finalize = aggregate.make_engine(spec)
-        state = init(template)
-        state = fold(state, cohort, is_simple, valid)
-        return finalize(state, template=template)
-
-    def via_legacy(cohort, is_simple, valid):
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            state = aggregate.streaming_init(
-                template, "fedhen", layout=spec.layout, block_n=512)
-            state = aggregate.streaming_fold(
-                state, cohort, is_simple, valid, mask, algorithm="fedhen",
-                layout=spec.layout, flat_mask=spec.flat_mask, block_n=512)
-            return aggregate.streaming_finalize(
-                state, mask, template, algorithm="fedhen",
-                layout=spec.layout, flat_mask=spec.flat_mask, block_n=512)
-
-    a = str(jax.make_jaxpr(via_spec)(cohort, is_simple, valid))
-    b = str(jax.make_jaxpr(via_legacy)(cohort, is_simple, valid))
-    assert a == b
-
-
-def test_legacy_entry_points_warn_and_match_spec():
-    """Every legacy signature emits DeprecationWarning naming its call
-    site — and still returns the spec path's exact result."""
-    cohort, mask, is_simple, valid = _random_case(12)
-    template, spec = _spec_for(cohort, mask)
-
-    with pytest.warns(DeprecationWarning, match="streaming_init"):
-        state = aggregate.streaming_init(template, "fedhen",
-                                         layout=spec.layout, block_n=512)
-    with pytest.warns(DeprecationWarning, match="streaming_fold"):
-        state = aggregate.streaming_fold(
-            state, cohort, is_simple, valid, mask, algorithm="fedhen",
-            layout=spec.layout, flat_mask=spec.flat_mask, block_n=512)
-    with pytest.warns(DeprecationWarning, match="streaming_finalize"):
-        legacy_c, _ = aggregate.streaming_finalize(
-            state, mask, template, algorithm="fedhen", layout=spec.layout,
-            flat_mask=spec.flat_mask, block_n=512)
-
-    init, fold, finalize = aggregate.make_engine(spec)
-    spec_c, _ = finalize(fold(init(template), cohort, is_simple, valid),
-                         template=template)
-    _assert_tree_allclose(legacy_c, spec_c, rtol=0, atol=0)
-
-    with pytest.warns(DeprecationWarning, match="make_engine"):
-        aggregate.make_engine("flat", algorithm="fedhen", mask=mask)
-    with pytest.warns(DeprecationWarning, match="engine_attrs"):
-        attrs = aggregate.engine_attrs("flat", algorithm="fedhen")
-    assert attrs["agg_engine"] == "flat" and attrs["agg_block_n"] == 2048
-
-    with pytest.warns(DeprecationWarning, match="tree_streaming_init"):
-        ts = aggregate.tree_streaming_init(template, "fedhen")
-    with pytest.warns(DeprecationWarning, match="tree_streaming_fold"):
-        ts = aggregate.tree_streaming_fold(ts, cohort, is_simple, valid,
-                                           mask, algorithm="fedhen")
-    with pytest.warns(DeprecationWarning, match="tree_streaming_finalize"):
-        aggregate.tree_streaming_finalize(ts, mask, template,
-                                          algorithm="fedhen")
-
-
-def test_spec_path_emits_no_deprecation():
-    """The modern path (what the trainer and launch/steps.py run) must
-    never trip the shims."""
-    cohort, mask, is_simple, valid = _random_case(13)
-    template, spec = _spec_for(cohort, mask)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        init, fold, finalize = aggregate.make_engine(spec)
-        state = init(template)
-        state = fold(state, cohort, is_simple, valid)
-        finalize(state, template=template)
-        aggregate.engine_attrs(spec)
-        tspec = spec.bind(engine="tree")
-        tinit, tfold, tfin = aggregate.make_engine(tspec)
-        tfin(tfold(tinit(template), cohort, is_simple, valid),
-             template=template)
-    ours = [w for w in caught
-            if issubclass(w.category, DeprecationWarning)
-            and "EngineSpec" in str(w.message)]
-    assert not ours, [str(w.message) for w in ours]
-
-
-def test_engine_attrs_records_the_full_spec():
-    cohort, mask, _, _ = _random_case(14)
-    template, spec = _spec_for(cohort, mask)
-    from repro.core import comm
-    spec = spec.bind(wire=comm.WireSpec("int8", 128),
-                     variance_reduction="scaffold")
+    spec = aggregate.EngineSpec(mask=mask, layout=layout, block_n=512,
+                                wire=comm.WireSpec("int8", 128),
+                                variance_reduction="scaffold")
     attrs = aggregate.engine_attrs(spec)
     assert attrs == {
-        "agg_engine": "flat", "algorithm": "fedhen", "agg_block_n": 512,
+        "algorithm": "fedhen", "agg_block_n": 512,
         "agg_stream_dtype": "float32", "variance_reduction": "scaffold",
         "wire_dtype": "int8", "wire_quantized": True,
         "wire_quant_block": 128, "wire_topk_frac": 1.0,
         "wire_stochastic": False, "wire_error_feedback": False,
     }
-
-
-def test_engine_spec_rejects_bad_combinations():
-    with pytest.raises(ValueError, match="unknown agg engine"):
-        aggregate.EngineSpec(engine="sparse")
-    with pytest.raises(ValueError):
-        aggregate.EngineSpec(algorithm="fedavg")
-    from repro.core import comm
-    with pytest.raises(ValueError, match="int8 wire requires the flat"):
-        aggregate.EngineSpec(engine="tree", wire=comm.WireSpec("int8", 128))
 
 
 # ---------------------------------------------------------------------------

@@ -7,8 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from billing_oracle import VersionCache
 from repro.configs.base import FedConfig, LayerSpec, ModelConfig
-from repro.core import async_rounds, comm, masking
+from repro.core import async_rounds
 from repro.core.adapters import LMAdapter, ResNetAdapter
 from repro.core.federated import FederatedTrainer
 from repro.data.federated import iid_split
@@ -268,7 +269,7 @@ def test_server_replacement_resets_version_stack():
 # ---------------------------------------------------------------------------
 
 def test_version_cache_bills_once_per_version():
-    cache = comm.VersionCache()
+    cache = VersionCache()
     assert cache.bill(7, 0, 100) == 100     # first fetch
     assert cache.bill(7, 0, 100) == 0       # cached
     assert cache.holds(7, 0) and not cache.holds(7, 1)

@@ -47,11 +47,9 @@ def test_fedconfig_wire_validation():
     with pytest.raises(ValueError):
         FedConfig(comm_dtype="float16")
     with pytest.raises(ValueError):
-        FedConfig(comm_dtype="int8", agg_engine="tree")
-    with pytest.raises(ValueError):
         FedConfig(quant_block=96)
-    FedConfig(comm_dtype="int8")        # flat engine default: fine
-    FedConfig(comm_dtype="bfloat16", agg_engine="tree")  # bf16+tree: fine
+    FedConfig(comm_dtype="int8")
+    FedConfig(comm_dtype="bfloat16")
 
 
 # ---------------------------------------------------------------------------
@@ -172,18 +170,18 @@ def _random_cohort(seed, z=8):
 
 
 def _stream_wire(cohort, mask, is_simple, valid, algo, chunk, wire,
-                 **fold_kw):
+                 stream_dtype=jnp.float32, **fold_kw):
     z = jax.tree.leaves(cohort)[0].shape[0]
     template = jax.tree.map(lambda x: x[0], cohort)
-    state = aggregate.streaming_init(template, algo)
+    spec = aggregate.EngineSpec(algorithm=algo, mask=mask, wire=wire,
+                                stream_dtype=stream_dtype)
+    state = aggregate.streaming_init(template, spec)
     for lo in range(0, z, chunk):
         sl = slice(lo, min(lo + chunk, z))
         state = aggregate.streaming_fold(
             state, jax.tree.map(lambda x: x[sl], cohort),
-            is_simple[sl], valid[sl], mask, algorithm=algo, wire=wire,
-            **fold_kw)
-    return aggregate.streaming_finalize(state, mask, template,
-                                        algorithm=algo)
+            is_simple[sl], valid[sl], spec, **fold_kw)
+    return aggregate.streaming_finalize(state, spec, template)
 
 
 def _assert_tree_allclose(got, want, rtol, atol):
@@ -235,10 +233,64 @@ def test_bf16_wire_fold_rides_stream_dtype():
     _assert_tree_allclose(got_c, want_c, rtol=1e-6, atol=1e-7)
 
 
-def test_int8_wire_rejects_tree_engine():
-    with pytest.raises(ValueError):
-        aggregate.make_engine("tree", algorithm="fedhen", mask={},
-                              wire=comm.WireSpec("int8"))
+_DELTA_WIRES = {
+    "topk-int8": comm.WireSpec("int8", 64, topk_frac=0.25),
+    "dense-int8": comm.WireSpec("int8", 64, stochastic=True),
+    "dense-bf16": comm.WireSpec("bfloat16", stochastic=True),
+}
+
+
+def _sparse_chunk(wire, base, deltas, k):
+    """Encode (Z, n_flat) deltas for ``wire`` as the fold's SparseChunk:
+    index+value rows under top-k, dense payload rows otherwise."""
+    if wire.is_sparse:
+        buf = jax.vmap(lambda v: comm.sparse_encode(wire, v, k))(deltas)
+        return aggregate.SparseChunk(base, buf.payload, buf.scales,
+                                     buf.indices)
+    buf = comm.encode(wire, deltas)
+    return aggregate.SparseChunk(base, buf.payload, buf.scales, None)
+
+
+@pytest.mark.parametrize("algo", ["fedhen", "decouple"])
+@pytest.mark.parametrize("payload", list(_DELTA_WIRES))
+def test_delta_fold_kernel_path_matches_cpu_path(algo, payload):
+    """Delta-mode uploads fold the same on the kernel path (interpret
+    mode) as on the CPU path, through each payload branch of the delta
+    fold: top-k int8 index+value rows (scatter fold), dense int8 rows
+    (dequantizing accumulate) and dense bf16 rows (plain accumulate) —
+    plus decouple's second accumulator — with a NaN device and a
+    zero-weight device in the cohort."""
+    cohort, mask, is_simple, valid = _random_cohort(6)
+    wire = _DELTA_WIRES[payload]
+    template = jax.tree.map(lambda x: x[0], cohort)
+    layout = flatten.layout_of(template, total_multiple=512)
+    spec = aggregate.EngineSpec(
+        algorithm=algo, mask=mask, layout=layout, block_n=512, wire=wire,
+        flat_mask=flatten.pack_mask(layout, mask))
+    base = flatten.pack(layout, template)  # client 0: a finite broadcast
+    deltas = flatten.pack_stacked(layout, cohort) - base[None]
+    sp = _sparse_chunk(wire, base, deltas,
+                       comm.topk_count(wire, layout.n_flat))
+
+    def fold(interpret):
+        state = aggregate.streaming_init(template, spec)
+        for lo in range(0, is_simple.shape[0], 3):
+            sl = slice(lo, lo + 3)
+            rows = aggregate.SparseChunk(
+                base, sp.values[sl],
+                None if sp.scales is None else sp.scales[sl],
+                None if sp.indices is None else sp.indices[sl])
+            state = aggregate.streaming_fold(
+                state, None, is_simple[sl], valid[sl], spec,
+                sparse_chunk=rows, force_pallas_interpret=interpret)
+        return aggregate.streaming_finalize(state, spec, template)
+
+    cpu_c, cpu_host = fold(False)
+    ker_c, ker_host = fold(True)
+    _assert_tree_allclose(ker_c, cpu_c, rtol=1e-5, atol=1e-5)
+    _assert_tree_allclose(ker_host, cpu_host, rtol=1e-5, atol=1e-5)
+    for leaf in jax.tree.leaves(ker_c):
+        assert np.isfinite(np.asarray(leaf)).all()
 
 
 # ---------------------------------------------------------------------------
@@ -369,15 +421,6 @@ def test_uses_deltas_gate():
     assert comm.WireSpec("float32", topk_frac=0.25).uses_deltas
     assert comm.WireSpec("int8", stochastic=True).uses_deltas
     assert comm.WireSpec("int8", error_feedback=True).uses_deltas
-
-
-def test_fedconfig_delta_mode_requires_flat_engine():
-    with pytest.raises(ValueError, match="require.*agg_engine='flat'"):
-        FedConfig(topk_frac=0.5, agg_engine="tree")
-    with pytest.raises(ValueError, match="require.*agg_engine='flat'"):
-        FedConfig(comm_dtype="bfloat16", stochastic_rounding=True,
-                  agg_engine="tree")
-    FedConfig(topk_frac=0.5)            # flat default: fine
 
 
 def test_stochastic_encode_is_seeded_and_reproducible():

@@ -31,11 +31,6 @@ def test_rejects_unknown_algorithm():
         _cfg(algorithm="fedavg")
 
 
-def test_rejects_unknown_agg_engine():
-    with pytest.raises(ValueError, match="unknown agg_engine 'sparse'"):
-        _cfg(agg_engine="sparse")
-
-
 @pytest.mark.parametrize("bad", [0, -128, 100])
 def test_rejects_bad_agg_block_n(bad):
     with pytest.raises(ValueError,
@@ -69,12 +64,6 @@ def test_rejects_bad_quant_block():
         _cfg(comm_dtype="int8", quant_block=96)
 
 
-def test_rejects_int8_on_tree_engine():
-    with pytest.raises(ValueError,
-                       match="comm_dtype=int8 requires agg_engine='flat'"):
-        _cfg(comm_dtype="int8", agg_engine="tree")
-
-
 @pytest.mark.parametrize("bad", [0.0, -0.25, 1.5])
 def test_rejects_bad_topk_frac(bad):
     # delegated to WireSpec — one source of truth for the sparsity knob
@@ -95,16 +84,6 @@ def test_rejects_error_feedback_on_lossless_wire():
         _cfg(error_feedback=True)
     _cfg(error_feedback=True, comm_dtype="int8")        # lossy: fine
     _cfg(error_feedback=True, topk_frac=0.5)            # sparse: fine
-
-
-def test_rejects_compressed_uploads_on_tree_engine():
-    with pytest.raises(ValueError,
-                       match="compressed uploads .* require.*flat"):
-        _cfg(topk_frac=0.5, agg_engine="tree")
-    with pytest.raises(ValueError,
-                       match="compressed uploads .* require.*flat"):
-        _cfg(comm_dtype="bfloat16", stochastic_rounding=True,
-             agg_engine="tree")
 
 
 def test_rejects_negative_async_lag():
@@ -145,8 +124,8 @@ def test_replace_reruns_validation():
     """dataclasses.replace re-triggers __post_init__ -> validate(), so a
     config mutated after construction hits the same wall as the CLI."""
     fed = _cfg()
-    with pytest.raises(ValueError, match="unknown agg_engine"):
-        dataclasses.replace(fed, agg_engine="sparse")
+    with pytest.raises(ValueError, match="unknown algorithm"):
+        dataclasses.replace(fed, algorithm="fedavg")
 
 
 # ---------------------------------------------------------------------------
@@ -212,7 +191,7 @@ def test_cli_flags_construct_a_valid_config():
         dirichlet_alpha=args.alpha, algorithm=args.algorithm,
         seed=args.seed, cohort_chunk=args.cohort_chunk,
         sample_uniform=args.sample_uniform,
-        agg_engine=args.agg_engine, agg_block_n=args.agg_block_n,
+        agg_block_n=args.agg_block_n,
         agg_stream_dtype=args.agg_stream_dtype,
         agg_memory_budget_mb=args.agg_memory_budget_mb,
         comm_dtype=args.comm_dtype, quant_block=args.quant_block,

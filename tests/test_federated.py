@@ -1,4 +1,5 @@
-"""FedHeN core: masking, aggregation (Alg. 1), algorithms end-to-end."""
+"""FedHeN core: masking, aggregation (Alg. 1), algorithms end-to-end
+(chunked rounds: tests/test_chunked_rounds.py)."""
 
 import re
 
@@ -239,128 +240,6 @@ def test_rounds_to_target_loss_direction():
 
 
 # ---------------------------------------------------------------------------
-# Streaming cohort engine: chunked rounds == one-shot rounds
-# ---------------------------------------------------------------------------
-
-def _make_chunked_trainer(algorithm, chunk, *, n_devices=12, **fed_kw):
-    """ks = kc = n_devices/4 active clients per population."""
-    fed = FedConfig(n_devices=n_devices, n_simple=n_devices // 2,
-                    participation=0.5, rounds=3, local_epochs=1, lr=0.1,
-                    clip_norm=10.0, batch_size=4, algorithm=algorithm,
-                    seed=0, cohort_chunk=chunk, **fed_kw)
-    data = synthetic_lm(n_devices * 4, 16, TINY.vocab_size, seed=1)
-    shards = iid_split(data, fed.n_devices, seed=2)
-    return FederatedTrainer(LMAdapter(TINY), fed, shards)
-
-
-def _assert_server_allclose(a, b, rtol=3e-5, atol=3e-6):
-    for x, y in zip(jax.tree.leaves(a.server.complex),
-                    jax.tree.leaves(b.server.complex)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                   rtol=rtol, atol=atol)
-    if a.server.simple_host is not None:
-        for x, y in zip(jax.tree.leaves(a.server.simple_host),
-                        jax.tree.leaves(b.server.simple_host)):
-            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                       rtol=rtol, atol=atol)
-
-
-@pytest.mark.parametrize("algorithm", ["fedhen", "noside", "decouple"])
-@pytest.mark.parametrize("chunk", [1, 3, 0])   # 0 = whole population (k)
-def test_chunked_round_matches_one_shot(algorithm, chunk):
-    """cohort_chunk only changes the execution schedule, never the round's
-    result: server state after a chunked round == the one-shot round."""
-    ref = _make_chunked_trainer(algorithm, 0)
-    tr = _make_chunked_trainer(algorithm, chunk)
-    m_ref = ref.run_round()
-    m = tr.run_round()
-    _assert_server_allclose(ref, tr)
-    assert m["n_valid"] == m_ref["n_valid"]
-    assert abs(m["loss_simple"] - m_ref["loss_simple"]) < 1e-4
-    assert abs(m["loss_complex"] - m_ref["loss_complex"]) < 1e-4
-
-
-@pytest.mark.parametrize("algorithm", ["fedhen", "noside", "decouple"])
-def test_chunk_not_dividing_k_is_padded(algorithm):
-    """ks = kc = 3 with chunk 2: populations are padded with zero-validity
-    clients; the padding must not change the aggregate or the metrics."""
-    ref = _make_chunked_trainer(algorithm, 0)
-    tr = _make_chunked_trainer(algorithm, 2)   # 2 does not divide 3
-    m_ref = ref.run_round()
-    m = tr.run_round()
-    _assert_server_allclose(ref, tr)
-    assert m["n_valid"] == m_ref["n_valid"] == tr.k_simple + tr.k_complex
-    assert abs(m["loss_simple"] - m_ref["loss_simple"]) < 1e-4
-
-
-def test_chunked_multi_round_stays_on_trajectory():
-    """Chunking composes over rounds (the carry is re-chunked each round)."""
-    ref = _make_chunked_trainer("fedhen", 0)
-    tr = _make_chunked_trainer("fedhen", 2)
-    for _ in range(3):
-        ref.run_round()
-        tr.run_round()
-    _assert_server_allclose(ref, tr, rtol=2e-4, atol=2e-5)
-
-
-# ---------------------------------------------------------------------------
-# Flat aggregation engine (layout threading, auto chunk, HLO claim)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("algorithm", ["fedhen", "decouple"])
-def test_flat_round_matches_tree_round(algorithm):
-    """agg_engine only changes the fold's execution layout, never the
-    round's result."""
-    ref = _make_chunked_trainer(algorithm, 2, agg_engine="tree")
-    tr = _make_chunked_trainer(algorithm, 2, agg_engine="flat")
-    m_ref = ref.run_round()
-    m = tr.run_round()
-    _assert_server_allclose(ref, tr)
-    assert m["n_valid"] == m_ref["n_valid"]
-    assert abs(m["loss_complex"] - m_ref["loss_complex"]) < 1e-4
-
-
-def test_auto_cohort_chunk_resolves_from_budget():
-    """cohort_chunk="auto": tiny budget floors at 1; huge budget covers the
-    whole population; the resolved chunk round still matches one-shot."""
-    small = _make_chunked_trainer("fedhen", "auto",
-                                  agg_memory_budget_mb=1e-6)
-    assert small.cohort_chunk == 1
-    big = _make_chunked_trainer("fedhen", "auto",
-                                agg_memory_budget_mb=1e9)
-    assert big.cohort_chunk == max(big.k_simple, big.k_complex)
-    ref = _make_chunked_trainer("fedhen", 0)
-    ref.run_round()
-    small.run_round()
-    _assert_server_allclose(ref, small)
-
-
-def test_trainer_layout_is_static_and_mask_flat():
-    tr = _make_chunked_trainer("fedhen", 2)
-    assert tr.layout.n_flat % tr.fed.agg_block_n == 0
-    assert tr.flat_mask.shape == (tr.layout.n_flat,)
-    assert tr.flat_mask.dtype == jnp.bool_
-    from repro.core import masking
-    n_in_m = masking.mask_size(tr.mask, tr.server.complex)
-    assert int(jnp.sum(tr.flat_mask)) == n_in_m == TINY.simple_param_count()
-
-
-def test_flat_round_hlo_has_fewer_masked_agg_reductions():
-    """Acceptance: the compiled flat round folds the whole model in one
-    masked-agg reduction per fold, so its HLO carries strictly fewer
-    reduce ops than the per-leaf tree round (one per leaf)."""
-    flat = _make_chunked_trainer("fedhen", 2, agg_engine="flat")
-    tree = _make_chunked_trainer("fedhen", 2, agg_engine="tree")
-    txt_flat = flat.lower_round().compile().as_text()
-    txt_tree = tree.lower_round().compile().as_text()
-    n_leaves = len(jax.tree.leaves(flat.server.complex))
-    n_flat, n_tree = txt_flat.count(" reduce("), txt_tree.count(" reduce(")
-    # the non-fold reduces (loss, clipping, validity) are identical in both
-    # programs; the fold's per-leaf launches are the difference
-    assert n_tree - n_flat >= n_leaves - 2, (n_flat, n_tree, n_leaves)
-
-
-# ---------------------------------------------------------------------------
 # A chunk's clients train one after another
 # ---------------------------------------------------------------------------
 
@@ -491,18 +370,6 @@ def test_bytes_per_round_hand_computed():
     tr = FederatedTrainer(_ToyAdapter(), fed, client_data=[])
     assert tr.k_simple == 1 and tr.k_complex == 1
     assert tr.bytes_per_round == 2.0 * (1 * 16 + 1 * 28) == 88.0
-
-
-def test_total_bytes_invariant_under_chunking():
-    """Chunking is an execution detail: what is *communicated* per round
-    (and in total) must not depend on cohort_chunk."""
-    ref = _make_chunked_trainer("fedhen", 0)
-    tr = _make_chunked_trainer("fedhen", 2)
-    assert tr.bytes_per_round == ref.bytes_per_round
-    for _ in range(2):
-        ref.run_round()
-        tr.run_round()
-    assert tr.total_bytes == ref.total_bytes > 0
 
 
 # ---------------------------------------------------------------------------

@@ -51,6 +51,7 @@ def test_round_step_tiny():
 def test_round_step_int8_wire_matches_f32():
     """The launch-side round folds encoded uploads: the int8 wire's
     dequantizing fold lands near the f32 round and stays finite."""
+    from repro.core import aggregate, comm
     from repro.launch.steps import make_fed_round_step
     from repro.models import transformer as tfm
     from repro.models.common import NO_POLICY
@@ -68,8 +69,9 @@ def test_round_step_int8_wire_matches_f32():
 
     ref_step = make_fed_round_step(cfg, NO_POLICY, local_steps=steps,
                                    cohort_chunk=2)
-    q_step = make_fed_round_step(cfg, NO_POLICY, local_steps=steps,
-                                 cohort_chunk=2, comm_dtype="int8")
+    q_step = make_fed_round_step(
+        cfg, NO_POLICY, local_steps=steps, cohort_chunk=2,
+        engine=aggregate.EngineSpec(wire=comm.WireSpec("int8", 128)))
     ref_c, ref_loss = jax.jit(ref_step)(cohort, data, is_simple)
     q_c, q_loss = jax.jit(q_step)(cohort, data, is_simple)
     assert np.isfinite(float(q_loss))
@@ -80,27 +82,33 @@ def test_round_step_int8_wire_matches_f32():
         assert float(jnp.max(jnp.abs(a - b))) <= amax / 100.0
 
 
-def test_make_fed_round_step_engine_spec_shim():
-    """The launch-side factory takes ONE EngineSpec; the old loose kwargs
-    still work behind a DeprecationWarning, and mixing both is an
-    error."""
-    import pytest
-
-    from repro.core import aggregate, comm
+def test_round_step_block_n_is_a_tiling_choice():
+    """The spec's kernel tile width changes how the flat layout is
+    padded, never the round: an ``EngineSpec(block_n=512)`` step lands on
+    the default (2048) step's model and loss."""
+    from repro.core import aggregate
     from repro.launch.steps import make_fed_round_step
+    from repro.models import transformer as tfm
     from repro.models.common import NO_POLICY
 
     cfg = ModelConfig(n_layers=2, d_model=32, n_heads=2, n_kv_heads=2,
                       d_ff=64, vocab_size=64, pattern=(LayerSpec("attn"),),
                       exit_layer=1, compute_dtype="float32")
-    spec = aggregate.EngineSpec(algorithm="fedhen", block_n=512,
-                                wire=comm.WireSpec("float32", 128))
-    make_fed_round_step(cfg, NO_POLICY, local_steps=1, engine=spec)
+    k_clients, batch, seq = 4, 2, 16
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    cohort = jax.tree.map(
+        lambda x: jnp.broadcast_to(x[None], (k_clients,) + x.shape), params)
+    data = jax.random.randint(jax.random.PRNGKey(2),
+                              (k_clients, batch, 1, seq + 1), 0, 64)
+    is_simple = jnp.array([True, False, True, False])
 
-    with pytest.warns(DeprecationWarning, match="make_fed_round_step"):
-        make_fed_round_step(cfg, NO_POLICY, local_steps=1,
-                            agg_engine="flat", agg_block_n=512)
-
-    with pytest.raises(ValueError, match="either"):
-        make_fed_round_step(cfg, NO_POLICY, local_steps=1, engine=spec,
-                            agg_engine="flat")
+    ref_c, ref_loss = jax.jit(make_fed_round_step(
+        cfg, NO_POLICY, local_steps=1, cohort_chunk=2))(
+            cohort, data, is_simple)
+    got_c, got_loss = jax.jit(make_fed_round_step(
+        cfg, NO_POLICY, local_steps=1, cohort_chunk=2,
+        engine=aggregate.EngineSpec(block_n=512)))(cohort, data, is_simple)
+    assert float(got_loss) == float(ref_loss)
+    for a, b in zip(jax.tree.leaves(got_c), jax.tree.leaves(ref_c)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-7)

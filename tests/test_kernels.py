@@ -12,9 +12,7 @@ from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.masked_agg.kernel import (masked_agg_acc_deq_pallas,
-                                             masked_agg_acc_pallas,
-                                             masked_agg_pallas)
-from repro.kernels.masked_agg.ops import masked_agg_leaf, masked_agg_tree
+                                             masked_agg_acc_pallas)
 from repro.kernels.masked_agg.ref import (masked_agg_acc_deq_ref,
                                           masked_agg_acc_ref,
                                           masked_agg_ref)
@@ -28,65 +26,27 @@ from repro.kernels.rglru_scan.ref import lru_scan_ref
 
 @pytest.mark.parametrize("z,n", [(4, 256), (10, 2048), (7, 5000), (32, 999)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_masked_agg_sweep(z, n, dtype):
-    key = jax.random.PRNGKey(z * 1000 + n)
-    ks = jax.random.split(key, 4)
-    x = jax.random.normal(ks[0], (z, n), dtype)
-    mask = jax.random.bernoulli(ks[1], 0.5, (n,))
-    w_m = jax.nn.softmax(jax.random.normal(ks[2], (z,)))
-    w_rest = jax.nn.softmax(jax.random.normal(ks[3], (z,)))
-    got = masked_agg_pallas(x, mask, w_m, w_rest, block_n=1024,
-                            interpret=True)
-    want = masked_agg_ref(x, mask, w_m, w_rest)
-    tol = 1e-6 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol)
-
-
-def test_masked_agg_nan_gating():
-    x = jnp.array([[jnp.nan, 1.0], [2.0, 3.0]])
-    mask = jnp.array([True, False])
-    got = masked_agg_pallas(x, mask, jnp.array([0.0, 1.0]),
-                            jnp.array([0.0, 1.0]), interpret=True)
-    np.testing.assert_allclose(got, [2.0, 3.0])
-
-
-def test_masked_agg_tree_matches_server_update():
-    """The kernel path must reproduce core.aggregate.fedhen_server_update."""
-    from repro.core import aggregate
-    key = jax.random.PRNGKey(0)
-    cohort = {"a": jax.random.normal(key, (6, 33)),
-              "b": jax.random.normal(jax.random.fold_in(key, 1), (6, 17))}
-    mask = {"a": jnp.asarray(True), "b": jnp.asarray(False)}
-    is_simple = jnp.array([1, 1, 1, 0, 0, 0], bool)
-    valid = jnp.ones(6, bool)
-    want = aggregate.fedhen_server_update(cohort, is_simple, valid, mask)
-    w_m = valid / 6.0
-    w_rest = (~is_simple) * valid / 3.0
-    got = masked_agg_tree(cohort, mask, w_m, w_rest,
-                          force_pallas_interpret=True)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
-
-
-@pytest.mark.parametrize("z,n", [(4, 256), (10, 2048), (7, 5000)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_masked_agg_acc_sweep(z, n, dtype):
-    """Accumulating variant (the flat fold's kernel): out = acc + masked
-    sum, f32 accumulation regardless of the streaming dtype."""
+@pytest.mark.parametrize("acc_init", ["zeros", "random"])
+def test_masked_agg_acc_sweep(z, n, dtype, acc_init):
+    """The fold's kernel: out = acc + masked sum, f32 accumulation
+    regardless of the streaming dtype, against the one-shot masked sum
+    (``masked_agg_ref``) and the row-streamed accumulating reference."""
     key = jax.random.PRNGKey(z * 7 + n)
     ks = jax.random.split(key, 5)
-    acc = jax.random.normal(ks[0], (n,), jnp.float32)
+    acc = (jnp.zeros((n,), jnp.float32) if acc_init == "zeros"
+           else jax.random.normal(ks[0], (n,), jnp.float32))
     x = jax.random.normal(ks[1], (z, n), dtype)
     mask = jax.random.bernoulli(ks[2], 0.5, (n,))
     w_m = jax.nn.softmax(jax.random.normal(ks[3], (z,)))
     w_rest = jax.nn.softmax(jax.random.normal(ks[4], (z,)))
     got = masked_agg_acc_pallas(acc, x, mask, w_m, w_rest, block_n=1024,
                                 interpret=True)
-    want = masked_agg_acc_ref(acc, x, mask, w_m, w_rest)
     assert got.dtype == jnp.float32
     tol = 1e-6 if dtype == jnp.float32 else 2e-2
+    want = acc + masked_agg_ref(x.astype(jnp.float32), mask, w_m, w_rest)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+    want = masked_agg_acc_ref(acc, x, mask, w_m, w_rest)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=tol, atol=tol)
 
@@ -110,13 +70,18 @@ def test_masked_agg_acc_folds_match_one_shot():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_masked_agg_acc_nan_gating():
-    acc = jnp.array([1.0, 2.0])
-    x = jnp.array([[jnp.nan, 1.0], [2.0, 3.0]])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("acc_init", ["zeros", "nonzero"])
+def test_masked_agg_acc_nan_gating(dtype, acc_init):
+    """A NaN client at weight 0 is gated before the multiply, whatever
+    the stream dtype and whatever the accumulator already holds."""
+    acc = (jnp.zeros((2,), jnp.float32) if acc_init == "zeros"
+           else jnp.array([1.0, 2.0], jnp.float32))
+    x = jnp.array([[jnp.nan, 1.0], [2.0, 3.0]], dtype)
     mask = jnp.array([True, False])
     got = masked_agg_acc_pallas(acc, x, mask, jnp.array([0.0, 1.0]),
                                 jnp.array([0.0, 1.0]), interpret=True)
-    np.testing.assert_allclose(got, [3.0, 5.0])
+    np.testing.assert_allclose(got, np.asarray(acc) + [2.0, 3.0])
 
 
 def test_masked_agg_acc_rejects_non_f32_accumulator():

@@ -168,7 +168,7 @@ def test_sync_two_round_span_tree_and_ledgers():
 
     # run_config ledger carries the engine dispatch's own attrs
     cfg = mem.named("run_config")[0]["values"]
-    assert cfg["engine"] == "sync" and cfg["agg_engine"] == "flat"
+    assert cfg["engine"] == "sync" and cfg["agg_block_n"] == 2048
     assert cfg["k_simple"] == 4 and cfg["n_chunks_complex"] == 2
 
 

@@ -1,10 +1,10 @@
 """Cohort sampler: purity, unbiasedness, uniform super-cohort routing,
-and the per-client state matrix (billing parity vs the retired
-VersionCache dict)."""
+and the per-client state matrix (billing parity vs the per-client
+VersionCache dict oracle)."""
 
 import numpy as np
 
-from repro.core import comm
+from billing_oracle import VersionCache
 from repro.core.client_state import ClientStateMatrix
 from repro.core.sampling import (CohortSampler, draw_without_replacement,
                                  round_rng)
@@ -150,10 +150,10 @@ def test_record_round_and_histogram():
 
 def test_billing_parity_vs_version_cache():
     """The vectorized tag-compare must bill byte-for-byte like the
-    retired per-client VersionCache dict on identical fetch sequences —
+    per-client VersionCache dict oracle on identical fetch sequences —
     including hit/miss tallies (the telemetry deltas)."""
     m = ClientStateMatrix(64)
-    vc = comm.VersionCache()
+    vc = VersionCache()
     rng = np.random.default_rng(3)
     hits = misses = 0
     for r in range(50):

@@ -1,8 +1,9 @@
 """SCAFFOLD variance reduction end-to-end (the state-store tentpole's
 first consumer): round-1 bit-equality with the plain protocol, a
-closed-form option-II oracle, flat/tree + chunked/unchunked parity
-across all three algorithms, NaN/pad-slot row hygiene, the async engine,
-wire dtypes, comm billing, and checkpoint resume."""
+closed-form option-II oracle, the cv fold's two lowerings against numpy,
+chunked/unchunked parity across all three algorithms, NaN/pad-slot row
+hygiene, the async engine, wire dtypes, comm billing, and checkpoint
+resume."""
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +12,7 @@ import pytest
 
 from repro.checkpoint.checkpoint import restore_trainer, save_trainer
 from repro.configs.base import FedConfig, LayerSpec, ModelConfig
-from repro.core import async_rounds, comm, flatten
+from repro.core import aggregate, async_rounds, comm, flatten
 from repro.core.adapters import LMAdapter
 from repro.core.federated import (FederatedTrainer, local_step_count,
                                   make_client_trainer)
@@ -128,26 +129,51 @@ def test_option_ii_oracle_single_client_populations():
 
 
 # ---------------------------------------------------------------------------
-# Engine parity: flat vs tree, chunked vs unchunked, all three algorithms
+# The cv fold on both lowerings; chunked vs unchunked rounds
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("algorithm", ALGOS)
-def test_flat_vs_tree_engine_parity(algorithm):
-    """The cv fold is a flat op on BOTH engines; after two rounds the
-    server models and control variates must agree up to summation
-    order."""
-    flat = _make_trainer(algorithm, agg_engine="flat")
-    tree = _make_trainer(algorithm, agg_engine="tree")
-    for _ in range(2):
-        flat.run_round()
-        tree.run_round()
-    _server_close(flat, tree, tol=2e-5)
-    np.testing.assert_allclose(np.asarray(flat.cv_global),
-                               np.asarray(tree.cv_global),
-                               rtol=1e-4, atol=1e-6)
-    np.testing.assert_allclose(flat.cv_store.to_array(),
-                               tree.cv_store.to_array(),
-                               rtol=1e-4, atol=1e-6)
+def test_cv_fold_lowerings_match_numpy(algorithm):
+    """The control-variate deltas fold into ``StreamState.cv_acc`` as the
+    masked weighted sum ``sum_z (M ? w_in : w_out)[z] * dc[z]`` — the
+    same weights as the params — on the kernel path (interpret mode) and
+    on the CPU path alike, with a NaN client gated out at weight 0."""
+    rng = np.random.default_rng(3)
+    z = 6
+    template = {"a": jnp.zeros((4, 3), jnp.float32),
+                "b": jnp.zeros((5,), jnp.float32)}
+    mask = {"a": jnp.asarray(True), "b": jnp.asarray(False)}
+    layout = flatten.layout_of(template, total_multiple=512)
+    flat_mask = flatten.pack_mask(layout, mask)
+    spec = aggregate.EngineSpec(
+        algorithm=algorithm, mask=mask, layout=layout, flat_mask=flat_mask,
+        block_n=512, variance_reduction="scaffold")
+    cohort = jax.tree.map(
+        lambda x: jnp.asarray(rng.normal(size=(z,) + x.shape), jnp.float32),
+        template)
+    dc = rng.normal(size=(z, layout.n_flat)).astype(np.float32)
+    dc[2] = np.nan
+    is_simple = np.arange(z) < z // 2
+    valid = np.array([1.0, 0.5, 0.0, 1.0, 0.25, 1.0], np.float32)
+
+    w = np.where(valid > 0, valid, 0.0)
+    w_in = w * is_simple if algorithm == "decouple" else w
+    w_out = w * ~is_simple
+    rows = np.where(np.asarray(flat_mask)[None], w_in[:, None],
+                    w_out[:, None])
+    want = np.where(rows > 0, np.nan_to_num(dc), 0.0) * rows
+
+    for interpret in (False, True):
+        state = aggregate.streaming_init(template, spec)
+        for lo in range(0, z, 3):
+            sl = slice(lo, lo + 3)
+            state = aggregate.streaming_fold(
+                state, jax.tree.map(lambda x: x[sl], cohort),
+                jnp.asarray(is_simple[sl]), jnp.asarray(valid[sl]), spec,
+                cv_chunk=jnp.asarray(dc[sl]),
+                force_pallas_interpret=interpret)
+        np.testing.assert_allclose(np.asarray(state.cv_acc), want.sum(0),
+                                   rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("algorithm", ALGOS)

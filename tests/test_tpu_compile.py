@@ -68,8 +68,6 @@ def _fold_case(name, spec):
     if name == "acc_bf16":
         return K.masked_agg_acc_pallas, (acc, spec((Z, N_FLAT),
                                                    jnp.bfloat16), mask, w, w)
-    if name == "oneshot":
-        return K.masked_agg_pallas, (spec((Z, N_FLAT), f32), mask, w, w)
     if name == "acc_deq":
         return (functools.partial(K.masked_agg_acc_deq_pallas,
                                   quant_block=QUANT_BLOCK),
@@ -83,7 +81,7 @@ def _fold_case(name, spec):
 
 
 KERNEL_NAMES = {"acc_f32": "masked_agg_acc", "acc_bf16": "masked_agg_acc",
-                "oneshot": "masked_agg", "acc_deq": "masked_agg_acc_deq",
+                "acc_deq": "masked_agg_acc_deq",
                 "scatter_int8": "masked_scatter_acc"}
 
 
@@ -93,8 +91,8 @@ def _custom_calls(hlo: str) -> list:
                       r'"tpu_custom_call"', hlo)
 
 
-@pytest.mark.parametrize("name", ["acc_f32", "acc_bf16", "oneshot",
-                                  "acc_deq", "scatter_int8"])
+@pytest.mark.parametrize("name", ["acc_f32", "acc_bf16", "acc_deq",
+                                  "scatter_int8"])
 def test_fold_kernel_compiles_for_v5e(one_chip, name):
     spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     fn, args = _fold_case(name, spec)
